@@ -14,15 +14,7 @@ if args.devices:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", args.devices)
-    except AttributeError:  # jax 0.4.x: only the XLA flag exists
-        import os as _os
-
-        _os.environ["XLA_FLAGS"] = (
-            _os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.devices}"
-        ).strip()
+    jax.config.update("jax_num_cpu_devices", args.devices)
 
 import os, sys
 

@@ -430,7 +430,6 @@ def check_axis_names(ctx: FileContext) -> Iterable[Finding]:
                 "jax" in dotted
                 or "lax" in dotted
                 or dotted == leaf
-                or "_jax_compat" in dotted
                 or "compressed" in dotted
             ):
                 continue

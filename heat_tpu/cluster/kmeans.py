@@ -427,7 +427,7 @@ def _kmeans_segment_q(arr, tol, stop, carry, *, comm, mode):
 
     from ..comm.compressed import ring_allreduce_q_ef
     from ..core._compile import jitted
-    from ..core._jax_compat import shard_map
+    from jax import shard_map
 
     n, f = int(arr.shape[0]), int(arr.shape[1])
     k = int(carry[1].shape[0])
@@ -485,7 +485,7 @@ def _kmeans_finalize_q(arr, centers, *, comm):
     from jax.sharding import PartitionSpec
 
     from ..core._compile import jitted
-    from ..core._jax_compat import shard_map
+    from jax import shard_map
 
     n, f = int(arr.shape[0]), int(arr.shape[1])
     k = int(centers.shape[0])

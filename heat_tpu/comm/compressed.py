@@ -69,10 +69,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec
 
 from ..core._compile import jitted, register_key_context
-from ..core._jax_compat import shape_dtype_struct, shard_map
 from ..core.communication import sanitize_comm
 from ..telemetry import _core as _tel
 from . import _costs
@@ -113,8 +113,14 @@ _AUTO_THRESHOLD = 1 << 16
 #: fused kernel only engages when the block-rows divide the sublane tile;
 #: other shapes take the identical jnp formulation (XLA fuses it anyway).
 _PALLAS_ROWS = 32
-#: ... and when the whole payload fits VMEM comfortably.
+#: ... and up to the largest payload the kernel is compiled for against the
+#: chip's compiler (tests/test_tpu_compile.py).
 _PALLAS_MAX_ELEMS = 1 << 22
+#: Row-grid tile of the fused kernels: one (tile, BLOCK) slab and its
+#: (tile, 1) scales — which VMEM pads to 128 lanes — live on chip at a time,
+#: never the whole payload.  Rows conform to ``_PALLAS_ROWS``, so a
+#: power-of-two tile between the two always divides them.
+_PALLAS_TILE_ROWS = 1024
 
 
 # --------------------------------------------------------------------- #
@@ -259,26 +265,48 @@ def _use_pallas(rows: int, block: int) -> bool:
     )
 
 
+def _row_tiled(kernel, operands, out_widths, out_dtypes):
+    """Run a row-independent kernel over a 1-D grid of row tiles: every
+    operand and output is ``(rows, width)``, blocked ``(tile, width)``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows = operands[0].shape[0]
+    tile = _PALLAS_TILE_ROWS
+    while rows % tile:
+        tile //= 2
+    spec = lambda width: pl.BlockSpec((tile, width), lambda i: (i, 0))
+    # x64 off: python-int literals in the index maps would otherwise trace
+    # as i64, which Mosaic rejects (same guard as parallel/flash_attention)
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            kernel,
+            grid=(rows // tile,),
+            in_specs=[spec(x.shape[1]) for x in operands],
+            out_specs=tuple(spec(w) for w in out_widths),
+            out_shape=tuple(
+                jax.ShapeDtypeStruct((rows, w), dt)
+                for w, dt in zip(out_widths, out_dtypes)
+            ),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)
+            ),
+            interpret=_interpret(),
+        )(*operands)
+
+
 def quantize_blocks(x, block: int = BLOCK):
     """Block-scale a flat f32 payload: ``(rows, block) int8`` +
     ``(rows, 1) float32`` scales, ``rows = len(x) / block`` (x must be
     1-D f32 with length a multiple of ``block``).  Dispatches the fused
     Pallas kernel when the shape conforms to the int8 tile grid, the
     identical jnp formulation otherwise."""
-    from jax.experimental import pallas as pl
-
     rows = x.shape[0] // block
     x2 = x.reshape(rows, block)
     if _use_pallas(rows, block):
-        q, s = pl.pallas_call(
-            _q_kernel,
-            out_shape=(
-                shape_dtype_struct((rows, block), jnp.int8),
-                shape_dtype_struct((rows, 1), jnp.float32),
-            ),
-            interpret=_interpret(),
-        )(x2)
-        return q, s
+        return _row_tiled(
+            _q_kernel, (x2,), (block, 1), (jnp.int8, jnp.float32)
+        )
     # identical formulation to _q_kernel, including the deterministic
     # non-finite propagation (Pallas/jnp bit-parity is load-bearing)
     absmax = jnp.max(jnp.abs(x2), axis=1, keepdims=True)
@@ -294,15 +322,9 @@ def quantize_blocks(x, block: int = BLOCK):
 def dequantize_blocks(q, scales):
     """Inverse of :func:`quantize_blocks`: flat f32 payload of length
     ``q.size``."""
-    from jax.experimental import pallas as pl
-
     rows, block = q.shape
     if _use_pallas(rows, block):
-        out = pl.pallas_call(
-            _dq_kernel,
-            out_shape=shape_dtype_struct((rows, block), jnp.float32),
-            interpret=_interpret(),
-        )(q, scales)
+        (out,) = _row_tiled(_dq_kernel, (q, scales), (block,), (jnp.float32,))
         return out.reshape(rows * block)
     return (q.astype(jnp.float32) * scales).reshape(rows * block)
 

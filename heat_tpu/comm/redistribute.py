@@ -83,10 +83,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec
 
 from ..core._compile import context_token, jitted, register_key_context
-from ..core._jax_compat import shard_map
 from ..telemetry import _core as _tel
 from . import _costs
 from . import compressed as _cq

@@ -50,10 +50,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from . import types
 from ._compile import jitted
-from ._jax_compat import shard_map
 from ._tracing import require_concrete
 from .communication import Communication, sanitize_comm
 from .devices import Device

@@ -24,12 +24,13 @@ from typing import List, Optional, Tuple, Union
 import numpy as np
 
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import pcast
 
 from jax.sharding import PartitionSpec
 
 from .. import factories, types
 from .._compile import jitted
-from .._jax_compat import pcast, shard_map
 from .._tracing import record_dispatch
 from ..communication import sanitize_comm
 from ..dndarray import DNDarray

@@ -41,11 +41,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec
 
 from .. import factories, types
 from .._compile import jitted
-from .._jax_compat import shard_map
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
 from .._split_semantics import split_semantics as _split_semantics

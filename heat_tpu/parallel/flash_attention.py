@@ -42,15 +42,12 @@ path shards the sequence before this kernel sees it).
 from __future__ import annotations
 
 import functools
-from contextlib import nullcontext as _nullcontext
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ..core._jax_compat import enable_x64, shape_dtype_struct, tpu_compiler_params
 
 __all__ = ["flash_attention", "flash_attention_partial"]
 
@@ -304,12 +301,8 @@ def flash_attention(
     # under the package's x64-on default, python-int literals in index
     # maps and grid arithmetic trace as i64, which Mosaic rejects; the
     # x64-off context makes them i32 (same guard as linalg/svd.py — the
-    # operands are already-typed tracers, so only index dtypes change).
-    # NOT under interpret: the 0.4.x interpreter builds its grid loop at
-    # LOWERING time with config-current index widths, so tracing x64-off
-    # while lowering x64-on mixes i32/i64 in one op; the interpreter is
-    # happy with i64 throughout, so it just skips the flip
-    with _nullcontext() if interpret else enable_x64(False):
+    # operands are already-typed tracers, so only index dtypes change)
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             kern,
             grid=(B * H, S // bq),
@@ -320,7 +313,7 @@ def flash_attention(
             ],
             out_specs=pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
             out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
                 vmem_limit_bytes=_VMEM_LIMIT,
             ),
@@ -373,8 +366,8 @@ def flash_attention_partial(
     )
     state_q = lambda bh, qi: (bh, qi, 0)
     whole_k = lambda bh, qi: (bh, 0, 0)
-    # x64 flip only for the Mosaic path — see flash_attention
-    with _nullcontext() if interpret else enable_x64(False):
+    # x64 off for index arithmetic — see flash_attention
+    with jax.enable_x64(False):
         m_o, l_o, acc = pl.pallas_call(
             kern,
             grid=(BH, Lq // bq),
@@ -393,11 +386,11 @@ def flash_attention_partial(
                 pl.BlockSpec((1, bq, D), state_q),
             ],
             out_shape=[
-                shape_dtype_struct((BH, Lq, 1), jnp.float32, vma=vma_axes),
-                shape_dtype_struct((BH, Lq, 1), jnp.float32, vma=vma_axes),
-                shape_dtype_struct((BH, Lq, D), jnp.float32, vma=vma_axes),
+                jax.ShapeDtypeStruct((BH, Lq, 1), jnp.float32, vma=frozenset(vma_axes)),
+                jax.ShapeDtypeStruct((BH, Lq, 1), jnp.float32, vma=frozenset(vma_axes)),
+                jax.ShapeDtypeStruct((BH, Lq, D), jnp.float32, vma=frozenset(vma_axes)),
             ],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
                 vmem_limit_bytes=_VMEM_LIMIT,
             ),

@@ -60,8 +60,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import pcast
 
-from ..core._jax_compat import pcast, shard_map
 from ..core.communication import XlaCommunication, get_comm
 
 __all__ = [

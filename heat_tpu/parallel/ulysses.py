@@ -21,10 +21,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..core._compile import jitted
-from ..core._jax_compat import pcast, shard_map
 from ..core.communication import XlaCommunication, get_comm
 from ..core.dndarray import DNDarray
 

@@ -582,7 +582,7 @@ def _gd_segment_q(arr, yv, lam, tol, stop, step, carry, *, comm, mode):
 
     from ..comm.compressed import ring_allreduce_q_ef
     from ..core._compile import jitted
-    from ..core._jax_compat import shard_map
+    from jax import shard_map
 
     n, m = int(arr.shape[0]), int(arr.shape[1])
     p = comm.size

@@ -36,6 +36,7 @@ from .batcher import MicroBatcher, Request, StagingPool, bucket_rows, pad_batch
 from .engine import Reply, ServeEngine
 from .errors import (
     IngressBootError,
+    ReplicaBootError,
     ServeClosedError,
     ServeDeadlineError,
     ServeOverloadError,
@@ -68,6 +69,7 @@ __all__ = [
     "ProcFleet",
     "RegistryError",
     "Reply",
+    "ReplicaBootError",
     "ReplicaProc",
     "Request",
     "ServeClosedError",

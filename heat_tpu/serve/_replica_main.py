@@ -118,12 +118,14 @@ def main(argv=None) -> int:
     import numpy as np
 
     from .. import telemetry
+    from ..core._compile_cache import place_compile_cache
     from ..net import wire
     from ..telemetry import flight as _flight
     from .engine import ServeEngine
     from .errors import ServeOverloadError
     from .registry import ModelRegistry
 
+    place_compile_cache()
     _apply_policy(cfg.get("policy"))
     telemetry.enable()
     registry = ModelRegistry(str(cfg["registry_root"]))

@@ -13,6 +13,7 @@ from typing import Optional
 
 __all__ = [
     "IngressBootError",
+    "ReplicaBootError",
     "ServeClosedError",
     "ServeDeadlineError",
     "ServeOverloadError",
@@ -82,3 +83,19 @@ class IngressBootError(RuntimeError):
                  cause: Optional[BaseException] = None):
         super().__init__(message)
         self.cause = cause
+
+
+class ReplicaBootError(RuntimeError):
+    """A replica process exited before it connected back to the fleet —
+    for one, because another process holds the accelerator.  Carries the
+    child's exit code and the end of its stderr, so the cause is in the
+    exception and not only in a log."""
+
+    def __init__(self, index: int, returncode: int, stderr_tail: str):
+        super().__init__(
+            f"replica {index} exited with code {returncode} before it "
+            f"connected; its stderr ends:\n{stderr_tail}"
+        )
+        self.index = index
+        self.returncode = returncode
+        self.stderr_tail = stderr_tail

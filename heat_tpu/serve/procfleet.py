@@ -56,6 +56,7 @@ foreign process can impersonate a replica.
 
 from __future__ import annotations
 
+import collections
 import os
 import queue
 import secrets
@@ -78,7 +79,12 @@ from ..resilience import incidents as _incidents
 from ..resilience import retry as _retry
 from ..telemetry import _core as _tel
 from ..telemetry import flight as _flight
-from .errors import ServeClosedError, ServeDeadlineError, ServeOverloadError
+from .errors import (
+    ReplicaBootError,
+    ServeClosedError,
+    ServeDeadlineError,
+    ServeOverloadError,
+)
 from .fleet import CanaryConfig
 from .health import ReplicaBreaker
 from .loadgen import chaos_seed
@@ -87,6 +93,33 @@ from .wfq import TenantPolicy, WeightedFairQueue
 __all__ = ["ProcFleet", "ReplicaProc"]
 
 _SPAWN_TIMEOUT_S = 120.0  # jax import + warm install on a loaded CI box
+_SPAWN_POLL_S = 0.2  # how often a booting replica's exit is checked
+_STDERR_TAIL_LINES = 40
+
+
+def _forward_stderr(proc: subprocess.Popen):
+    """Copy the child's piped stderr to ours on a daemon thread and keep
+    its last lines.  Returns a function that waits for the copy to reach
+    EOF (bounded) and gives those lines as one string."""
+    tail: collections.deque = collections.deque(maxlen=_STDERR_TAIL_LINES)
+
+    def pump():
+        for raw in proc.stderr:
+            line = raw.decode("utf-8", "replace")
+            tail.append(line)
+            sys.stderr.write(line)
+        proc.stderr.close()
+
+    thread = threading.Thread(
+        target=pump, name=f"replica-stderr-{proc.pid}", daemon=True
+    )
+    thread.start()
+
+    def read_tail() -> str:
+        thread.join(timeout=5.0)
+        return "".join(tail)
+
+    return read_tail
 
 
 def _policy_snapshot() -> dict:
@@ -205,15 +238,30 @@ class ReplicaProc:
                 [sys.executable, "-m", "heat_tpu.serve._replica_main",
                  _json.dumps(cfg)],
                 env=env,
+                stderr=subprocess.PIPE,
             )
-            try:
-                conn, _ = listener.accept()
-            except socket.timeout:
-                proc.kill()
-                raise TimeoutError(
-                    f"replica {index} did not connect within "
-                    f"{spawn_timeout_s}s (pid {proc.pid})"
-                )
+            stderr_tail = _forward_stderr(proc)
+            # the child connects only once it is warm, so a replica that
+            # dies at boot (say, because another process holds the chip)
+            # never connects: poll its exit between short accepts and
+            # raise with what it said, not after the whole timeout
+            deadline = time.monotonic() + spawn_timeout_s
+            listener.settimeout(_SPAWN_POLL_S)
+            while True:
+                try:
+                    conn, _ = listener.accept()
+                    break
+                except socket.timeout:
+                    pass
+                rc = proc.poll()
+                if rc is not None:
+                    raise ReplicaBootError(index, rc, stderr_tail())
+                if time.monotonic() >= deadline:
+                    proc.kill()
+                    raise TimeoutError(
+                        f"replica {index} did not connect within "
+                        f"{spawn_timeout_s}s (pid {proc.pid})"
+                    )
         finally:
             listener.close()
         conn.settimeout(spawn_timeout_s)

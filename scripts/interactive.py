@@ -28,13 +28,7 @@ def main():
         import jax
 
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", args.devices)
-        except AttributeError:  # jax 0.4.x: only the XLA flag exists
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "")
-                + f" --xla_force_host_platform_device_count={args.devices}"
-            ).strip()
+        jax.config.update("jax_num_cpu_devices", args.devices)
 
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
     import heat_tpu as ht

@@ -7,16 +7,19 @@ mesh, and split/replicated paths exercise real (CPU-emulated) collectives.
 Set HEAT_TEST_DEVICES to change the mesh size (e.g. 1 or 7 for the
 uneven-chunk edge cases the reference probes with -np 7).
 
-Device-count plumbing is version-portable: newer jax exposes the
-``jax_num_cpu_devices`` config option, jax 0.4.x only honors the
-``--xla_force_host_platform_device_count`` XLA flag.  The flag is appended
-to XLA_FLAGS BEFORE importing jax (the CPU client reads it at lazy backend
-init), then the config option is tried and an ``AttributeError`` from an
-older jax is ignored — whichever knob the installed version understands
-takes effect, and both agree on the same count when both exist.
+The device count is set twice on purpose: ``jax_num_cpu_devices`` for this
+process, and ``--xla_force_host_platform_device_count`` in ``XLA_FLAGS`` for
+the children tests spawn (procfleet replicas inherit the environment and must
+see the same emulated mesh their ``.aotx`` bundles were compiled for).
+
+The persistent compilation cache stays off, here and in every child (the
+entry points would otherwise place it in the checkout): the suite's compiles
+are CPU programs nobody runs again.
 """
 
 import os
+
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 _DEVICES = int(os.environ.get("HEAT_TEST_DEVICES", "8"))
 _FLAG = f"--xla_force_host_platform_device_count={_DEVICES}"
@@ -27,7 +30,5 @@ import jax  # noqa: E402  (after the XLA_FLAGS setup above, by design)
 
 # must run before any jax computation
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", _DEVICES)
-except AttributeError:
-    pass  # jax 0.4.x: the XLA_FLAGS fallback above already took effect
+jax.config.update("jax_num_cpu_devices", _DEVICES)
+jax.config.update("jax_enable_compilation_cache", False)

@@ -77,7 +77,8 @@ def test_roofline_rates_and_bounds():
         "cdist_gb_per_sec": 1000.0,
         "global_sum_gb_per_sec": 750.0,
     }
-    roof = bench._roofline(results)
+    peaks = bench.peaks_for("TPU v5 lite")
+    roof = bench._roofline(results, peaks)
     km = roof["kmeans_iter_per_sec"]
     flops, bytes_, _, _ = bench._work_models()["kmeans_iter_per_sec"]
     assert km["achieved_tflops"] == pytest.approx(flops * 9500.0 / 1e12, rel=1e-2)
@@ -95,10 +96,18 @@ def test_roofline_rates_and_bounds():
     assert gs["achieved_gb_per_sec"] == pytest.approx(750.0, rel=1e-2)
     # the hbm percentage always refers to the declared peak
     assert gs["pct_hbm_roofline"] == pytest.approx(
-        100 * 750.0 / bench._PEAKS["hbm_gb_per_sec"], rel=1e-2
+        100 * 750.0 / peaks["hbm_gb_per_sec"], rel=1e-2
     )
     # irregular metrics stay out, with reasons
     assert "kmedoids_iter_per_sec" in roof["not_modeled"]
+
+
+def test_peaks_are_keyed_by_device_kind_and_unknown_kind_is_an_error():
+    peaks = bench.peaks_for("TPU v5 lite")
+    assert peaks["source"]
+    assert peaks["f32_highest_tflops"] == pytest.approx(peaks["bf16_tflops"] / 6)
+    with pytest.raises(KeyError, match="no peaks recorded"):
+        bench.peaks_for("cpu")
 
 
 def test_summary_median_and_spread_semantics():
@@ -196,7 +205,7 @@ def _fake_full_result():
         "platform": "tpu",
     }
     rec["vs_golden"] = {k: 123.456 for k in bench._GOLDEN_MAP}
-    rec["roofline"] = bench._roofline(rec)
+    rec["roofline"] = bench._roofline(rec, bench.peaks_for("TPU v5 lite"))
     return rec
 
 

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 import heat_tpu as ht
-from heat_tpu.core._jax_compat import shard_map
+from jax import shard_map
 from suite import assert_array_equal
 
 RNG = np.random.default_rng(11)

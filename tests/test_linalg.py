@@ -127,6 +127,63 @@ def test_svd(split):
     np.testing.assert_allclose(s_only.numpy(), s.numpy(), rtol=1e-5)
 
 
+@pytest.mark.parametrize("np_dtype", [np.float32, np.int64], ids=["float32", "int64"])
+def test_svd_device_chain_is_lowered_with_x64_off(monkeypatch, np_dtype):
+    """The fused QR→SVD program must be lowered with x64 off: lowered under
+    the package's x64-on default it aborts the TPU compiler (svd.py).  An
+    integer operand is cast before it meets that context; float64 keeps
+    its eager host route under x64 on."""
+    import importlib
+
+    import jax
+
+    svd_mod = importlib.import_module("heat_tpu.core.linalg.svd")
+    seen = []
+    fused = svd_mod._fused_svd_pipeline
+
+    def spy(a, *rest):
+        seen.append((jax.config.jax_enable_x64, a.dtype))
+        return fused(a, *rest)
+
+    monkeypatch.setattr(svd_mod, "_fused_svd_pipeline", spy)
+    a = (np.random.default_rng(5).normal(size=(40, 6)) * 8).astype(np_dtype)
+    u, s, v = ht.linalg.svd(ht.array(a, split=0))
+    assert seen == [(False, ht.float32)]
+    assert jax.config.jax_enable_x64  # the context does not leak
+    np.testing.assert_allclose(
+        u.numpy() @ np.diag(s.numpy()) @ v.numpy().T, a, atol=1e-3
+    )
+    s64 = ht.linalg.svd(ht.array(a.astype(np.float64), split=0), compute_uv=False)
+    assert s64.dtype is ht.float64 and len(seen) == 1  # host route, not the fused chain
+
+
+@pytest.mark.parametrize("enclosing", ["fuse", "jit_of_small_factor"])
+def test_svd_traced_into_an_x64_program_for_a_tpu_is_refused(monkeypatch, enclosing):
+    """Where svd() does not own the lowering (a caller's ht.fuse / jax.jit,
+    aot.export_programs re-lowering the chain) and x64 is on, the TPU
+    compiler would abort the process: a diagnostic is raised instead, and
+    the same trace under x64 off goes through."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    svd_mod = importlib.import_module("heat_tpu.core.linalg.svd")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    a = ht.array(np.random.default_rng(6).normal(size=(40, 6)).astype(np.float32), split=0)
+    if enclosing == "fuse":
+        call = lambda: ht.fuse(lambda x: ht.linalg.svd(x))(a).S  # noqa: E731
+    else:
+        call = lambda: jax.jit(svd_mod._small_svd)(jnp.asarray(a.numpy()[:6]))[1]  # noqa: E731
+    with pytest.raises(ht.FuseTraceError, match="x64"):
+        call()
+    with jax.enable_x64(False):
+        assert call().shape == (6,)
+    # singular values alone lower with x64 on, and so does an untraced call
+    assert ht.fuse(lambda x: ht.linalg.svd(x, compute_uv=False))(a).shape == (6,)
+    assert ht.linalg.svd(a).S.shape == (6,)
+
+
 def test_svd_wide():
     rng = np.random.default_rng(4)
     a = rng.normal(size=(6, 30)).astype(np.float32)

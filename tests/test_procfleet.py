@@ -439,6 +439,26 @@ def test_replica_inherits_parent_policy_context(tmp_path, fitted):
         set_collective_threshold(prev)
 
 
+def test_replica_dead_at_boot_is_raised_at_once(tmp_path):
+    """A replica that exits before it connects back (on a chip: another
+    process holds the accelerator; here: a model the registry does not
+    have) is raised with its exit code and the end of its stderr as soon
+    as it is gone — not after the whole spawn timeout spent in accept()."""
+    import time
+
+    from heat_tpu.serve import ReplicaBootError, ReplicaProc
+
+    t0 = time.monotonic()
+    with pytest.raises(ReplicaBootError) as err:
+        ReplicaProc.spawn(
+            0, registry_root=str(tmp_path / "empty"),
+            warm_models=[("acme", "absent", 1)], spawn_timeout_s=120.0,
+        )
+    assert time.monotonic() - t0 < 60.0
+    assert err.value.returncode not in (0, None)
+    assert "absent" in err.value.stderr_tail
+
+
 def test_procfleet_ingress_and_canary_over_processes(fleet_root):
     """The full door: IngressClient → asyncio ingress → WFQ → replica
     processes, with a canary rollout whose assignments match the
