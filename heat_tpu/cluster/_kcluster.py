@@ -16,10 +16,12 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..core import factories, random, types
+from ..core._compile import launch
 from ..core._split_semantics import split_semantics as _split_semantics
 from ..core.base import BaseEstimator, ClusteringMixin
 from ..core.dndarray import DNDarray
 from ..core.fuse import fuse
+from ..telemetry import _core as _tel
 
 __all__ = ["_KCluster"]
 
@@ -70,13 +72,15 @@ def _kmeanspp(arr, first, us, rep_sh=None):
 
     def body(i, state):
         dmin, centers = state
-        d_new = rep(jnp.sum((arr - centers[i - 1]) ** 2, axis=1))
-        dmin = jnp.minimum(dmin, d_new)
-        cdf = jnp.cumsum(dmin)
-        total = cdf[-1]
-        draw = us[i] * jnp.where(total > 0, total, 1.0)
-        idx = jnp.clip(jnp.searchsorted(cdf, draw), 0, n - 1)
-        return dmin, centers.at[i].set(arr[idx])
+        with jax.named_scope("kmeanspp.distance"):
+            d_new = rep(jnp.sum((arr - centers[i - 1]) ** 2, axis=1))
+            dmin = jnp.minimum(dmin, d_new)
+        with jax.named_scope("kmeanspp.sample"):
+            cdf = jnp.cumsum(dmin)
+            total = cdf[-1]
+            draw = us[i] * jnp.where(total > 0, total, 1.0)
+            idx = jnp.clip(jnp.searchsorted(cdf, draw), 0, n - 1)
+            return dmin, centers.at[i].set(arr[idx])
 
     centers0 = jnp.zeros((k, arr.shape[1]), arr.dtype).at[0].set(arr[first])
     dmin0 = rep(jnp.full((n,), jnp.inf, dtype=arr.dtype))
@@ -152,13 +156,13 @@ class _KCluster(ClusteringMixin, BaseEstimator):
         # fit() leaves device scalars in place so it never blocks on the
         # host; the sync happens (once) here on first access
         if self._inertia is not None and not isinstance(self._inertia, float):
-            self._inertia = float(self._inertia)
+            self._inertia = _tel.host_read("sync:kcluster.inertia", self._inertia, float)
         return self._inertia
 
     @property
     def n_iter_(self) -> int:
         if self._n_iter is not None and not isinstance(self._n_iter, int):
-            self._n_iter = int(self._n_iter)
+            self._n_iter = _tel.host_read("sync:kcluster.n_iter", self._n_iter, int)
         return self._n_iter
 
     def _initialize_cluster_centers(self, x: DNDarray):
@@ -203,7 +207,9 @@ class _KCluster(ClusteringMixin, BaseEstimator):
                 self.n_clusters, device=x.device, comm=x.comm
             ).larray.astype(jnp.float32)
             rep_sh = x.comm.sharding(1, None) if x.comm.size > 1 else None
-            carr = _kmeanspp(arr, first, us, rep_sh=rep_sh).astype(x.dtype.jax_type())
+            carr = launch(
+                "jit:kmeans.kmeanspp", _kmeanspp, (arr, first, us), {"rep_sh": rep_sh}
+            ).astype(x.dtype.jax_type())
             self._cluster_centers = DNDarray(
                 x.comm.apply_sharding(carr, None),
                 (self.n_clusters, x.shape[1]),

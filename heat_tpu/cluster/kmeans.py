@@ -19,6 +19,7 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
+from ..core._compile import launch
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
 from ..spatial import distance
@@ -111,13 +112,15 @@ class KMeans(_KCluster):
         bandwidth roofline."""
 
         def step(c):
-            c2 = jnp.sum(c * c, axis=1)[None, :]  # (1, k)
-            d2 = c2 - 2.0 * jnp.matmul(arr, c.T)  # shifted by the const |x|²
-            labels = jnp.argmin(d2, axis=1)
-            sel = jax.nn.one_hot(labels, c.shape[0], dtype=arr.dtype)
-            sums = jnp.matmul(sel.T, arr)  # (k, f) masked sum on the MXU
-            counts = jnp.sum(sel, axis=0)[:, None]
-            nc = jnp.where(counts > 0, sums / jnp.maximum(counts, 1), c)
+            with jax.named_scope("kmeans.sweep.assign"):
+                c2 = jnp.sum(c * c, axis=1)[None, :]  # (1, k)
+                d2 = c2 - 2.0 * jnp.matmul(arr, c.T)  # shifted by the const |x|²
+                labels = jnp.argmin(d2, axis=1)
+            with jax.named_scope("kmeans.sweep.update"):
+                sel = jax.nn.one_hot(labels, c.shape[0], dtype=arr.dtype)
+                sums = jnp.matmul(sel.T, arr)  # (k, f) masked sum on the MXU
+                counts = jnp.sum(sel, axis=0)[:, None]
+                nc = jnp.where(counts > 0, sums / jnp.maximum(counts, 1), c)
             return labels, nc
 
         def cond(state):
@@ -137,9 +140,10 @@ class KMeans(_KCluster):
     def _finalize(arr, centers):
         """Final labels + inertia for the converged centers — the tail of
         the fit, split out of the loop program so segments stay cheap."""
-        c2 = jnp.sum(centers * centers, axis=1)[None, :]
-        labels = jnp.argmin(c2 - 2.0 * jnp.matmul(arr, centers.T), axis=1)
-        inertia = jnp.sum((arr - centers[labels]) ** 2)
+        with jax.named_scope("kmeans.finalize"):
+            c2 = jnp.sum(centers * centers, axis=1)[None, :]
+            labels = jnp.argmin(c2 - 2.0 * jnp.matmul(arr, centers.T), axis=1)
+            inertia = jnp.sum((arr - centers[labels]) ** 2)
         return labels, inertia
 
     def fit(self, x: DNDarray, resume=False, comm=None, device=None) -> "KMeans":
@@ -207,7 +211,7 @@ class KMeans(_KCluster):
             if use_q:
                 carry = carry + (jnp.asarray(state["error"], jnp.float32),)
         else:
-            self._initialize_cluster_centers(x)
+            _tel.spanned("kmeans:init", "other", self._initialize_cluster_centers, x)
             centers0 = self._cluster_centers.larray.astype(jnp.float32)
             carry = (jnp.int32(0), centers0, jnp.float32(jnp.inf))
             if use_q:
@@ -215,7 +219,7 @@ class KMeans(_KCluster):
 
         tol = jnp.float32(self.tol)
         while True:
-            it0 = int(carry[0])
+            it0 = _tel.host_read("sync:kmeans.it0", carry[0], int)
             stop = ckpt.stop(it0, self.max_iter)
             with _elastic.dispatch_guard(
                 "kmeans.seg_q" if use_q else "kmeans.seg", comm
@@ -225,8 +229,11 @@ class KMeans(_KCluster):
                         arr, tol, jnp.int32(stop), carry, comm=comm, mode=mode
                     )
                 else:
-                    carry = KMeans._fit_segment(arr, tol, jnp.int32(stop), carry)
-            it = int(carry[0])
+                    carry = launch(
+                        "jit:kmeans.fit_segment", KMeans._fit_segment,
+                        (arr, tol, jnp.int32(stop), carry),
+                    )
+            it = _tel.host_read("sync:kmeans.it", carry[0], int)
             if use_q and _tel.enabled and it > it0:
                 from ..comm import compressed as _cq
 
@@ -247,8 +254,8 @@ class KMeans(_KCluster):
         if use_q:
             labels, inertia = _kmeans_finalize_q(arr, centers, comm=comm)
         else:
-            labels, inertia = KMeans._finalize(arr, centers)
-        self._finalize_fit(x, centers, labels, carry[0])
+            labels, inertia = launch("jit:kmeans.finalize", KMeans._finalize, (arr, centers))
+        _tel.spanned("kmeans:wrap", "other", self._finalize_fit, x, centers, labels, carry[0])
         # device scalar; inertia_ property syncs lazily on access
         self._inertia = inertia
         return self

@@ -650,7 +650,7 @@ def allreduce_q(
             and overlap_enabled(p)
             and _padded_len(-(-n_res // p), blk) >= 2 * blk
         )
-        with _tel.span("commq:allreduce", mode=wire or "f32", mesh=p):
+        with _tel.span("commq:allreduce", "comm", mode=wire or "f32", mesh=p):
             out = timed_dispatch(
                 "allreduce_q", ring_ov,
                 (lambda: fn(payload, error)) if has_err else (lambda: fn(payload)),
@@ -778,7 +778,7 @@ def allgather_q(
         n_loc = int(np.prod(shape)) // p
         _account_wire("allgather", mode, n_loc, p)
         ring_ov = overlap_enabled(p) and _padded_len(n_loc, blk) >= 2 * blk
-        with _tel.span("commq:allgather", mode=mode, mesh=p):
+        with _tel.span("commq:allgather", "comm", mode=mode, mesh=p):
             out = timed_dispatch("allgather_q", ring_ov, lambda: fn(payload))
     else:
         out = fn(payload)

@@ -42,7 +42,7 @@ overhead while disabled):
 - per-ring ``comm:<ring>:step:issue`` / ``comm:<ring>:step:consume``
   span pairs around each eager ring dispatch: the *issue* span covers
   the (asynchronous) dispatch enqueue, the *consume* span covers the
-  wait for the result — in a Perfetto trace an overlapped ring shows a
+  wait for the result — in a profiler trace an overlapped ring shows a
   short issue slice and the whole wait in consume.  Spans are host-side
   by construction (SPMD205): they wrap the eager call site, never the
   traced body.
@@ -163,8 +163,8 @@ def timed_dispatch(ring: str, overlapped: bool, launch):
     if not _tel.enabled:
         return launch()
     _note_ring(overlapped)
-    with _tel.span(f"comm:{ring}:step:issue", overlapped=overlapped):
+    with _tel.span(f"comm:{ring}:step:issue", "comm", overlapped=overlapped):
         out = launch()
-    with _tel.span(f"comm:{ring}:step:consume", overlapped=overlapped):
+    with _tel.span(f"comm:{ring}:step:consume", "comm", overlapped=overlapped):
         jax.block_until_ready(out)
     return out

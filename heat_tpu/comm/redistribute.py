@@ -817,7 +817,7 @@ def execute(array, p_obj: Plan, comm):
             s[0] == "rotate" for s in p_obj.steps
         )
         with _tel.span(
-            "comm:resplit",
+            "comm:resplit", "comm",
             src=p_obj.src, dst=p_obj.dst, mesh=p_obj.size,
             steps=len(p_obj.steps), mode=p_obj.mode or "f32",
         ):

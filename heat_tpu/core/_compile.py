@@ -17,6 +17,7 @@ chain into one device round trip.
 
 from __future__ import annotations
 
+import functools
 import sys
 import types as _types
 from typing import Any, Callable, Dict, Tuple
@@ -26,10 +27,15 @@ import numpy as np
 import jax
 
 from ..telemetry import _core as _tel
-from ._tracing import record_dispatch
+from ._tracing import in_trace, record_dispatch
+
+# the profiler is the sink of every recorded span and, through
+# ``TraceAnnotation.is_enabled``, the switch (telemetry/_core.py imports no jax)
+_tel.install_profiler(jax.profiler.TraceAnnotation)
 
 __all__ = [
     "jitted",
+    "launch",
     "cache_stable",
     "clear_cache",
     "cache_size",
@@ -101,6 +107,49 @@ def cache_stable(fn: Any) -> bool:
     return mod is not None and name is not None and getattr(mod, name, None) is fn
 
 
+_NO_KWARGS: Dict[str, Any] = {}  # never written to
+
+
+def launch(site: str, fn: Callable, args: Tuple = (), kwargs=None, kind: str = "launch", **fields):
+    """Launch a compiled program: ``fn(*args, **kwargs)``, counted as one
+    device dispatch (always on) and, when recording, spanned as ``site``.
+
+    The one owner of "a program is issued": the ``jitted`` wrapper,
+    ``ht.fuse``'s dispatch and the estimators' own ``jax.jit`` programs go
+    through it with kind ``launch``, the ``device_put`` reshard with kind
+    ``comm`` — so the counter and the spans cannot drift apart, and there
+    is one span a counted dispatch.  A call made while a ``fuse`` trace is
+    active inlines into the surrounding program: neither counted nor
+    spanned."""
+    if kwargs is None:
+        kwargs = _NO_KWARGS
+    if in_trace():
+        return fn(*args, **kwargs)
+    record_dispatch()
+    if _tel.recording():
+        with _tel.span(site, kind, **fields):
+            return fn(*args, **kwargs)
+    return fn(*args, **kwargs)
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``, which ``jax.jit`` takes for the compiled
+    module's (``jit_<name>`` on the trace's ``XLA Modules`` line, where a
+    lambda would read ``jit__lambda_``).  A fresh closure is renamed in
+    place; a shared object (a module-level function, a ``PjitFunction``, a
+    partial) is wrapped instead."""
+    if isinstance(fn, _types.FunctionType) and not cache_stable(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
 def jitted(key: Tuple, make_fn: Callable[[], Callable], jit_kwargs=None) -> Callable:
     """Return a cached ``jax.jit`` of ``make_fn()`` memoized under ``key``.
 
@@ -113,10 +162,15 @@ def jitted(key: Tuple, make_fn: Callable[[], Callable], jit_kwargs=None) -> Call
     compares EQUAL to the monolithic reshard's).  The key must determine the
     kwargs, exactly as it determines the traced function.
 
-    The cached entry is a thin wrapper that records one device dispatch per
-    eager invocation (see :mod:`heat_tpu.core._tracing`); calls made while a
-    trace is active — an enclosing ``ht.fuse`` program or any jax trace —
-    inline into the surrounding program and are not counted.
+    The cached entry is a thin wrapper that goes through :func:`launch`: one
+    device dispatch per eager invocation (see :mod:`heat_tpu.core._tracing`)
+    and, when recording, one ``jitted:<key[0]>`` span of kind ``launch`` (the
+    entry's first call carries ``miss=True``: its duration holds trace, lower
+    and compile).  Calls made while a trace is active — an enclosing
+    ``ht.fuse`` program or any jax trace — inline into the surrounding
+    program and are neither counted nor spanned.  The compiled function is
+    named after the key's site (and the operation, where the key's second
+    part is one), so the device trace shows ``jit_dist.euclidean``.
     """
     if _KEY_CONTEXT:
         key = key + context_token()
@@ -124,25 +178,24 @@ def jitted(key: Tuple, make_fn: Callable[[], Callable], jit_kwargs=None) -> Call
     if fn is None:
         if _tel.enabled:
             _tel.inc("compile.cache.misses")
-        jfn = jax.jit(make_fn(), **(jit_kwargs or {}))
-        site = key[0] if key and isinstance(key[0], str) else getattr(
-            jfn, "__name__", "op"
-        )
-        staged = [False]  # first-call stage timing done (telemetry only)
+        made = make_fn()
+        if key and isinstance(key[0], str):
+            site = key[0]
+            op = getattr(key[1], "__name__", None) if len(key) > 1 and callable(key[1]) else None
+            made = _named(made, f"{site}.{op}" if op else site)
+        else:
+            site = getattr(made, "__name__", "op")
+        jfn = jax.jit(made, **(jit_kwargs or {}))
+        span_site = f"jitted:{site}"
+        fresh = [True]  # the entry has not been called yet
 
         def fn(*args, _jfn=jfn, **kwargs):
-            clean = not _traced(args, kwargs)
-            if clean:
-                record_dispatch()
-            if _tel.enabled and clean:
-                if not staged[0]:
-                    staged[0] = True
-                    out = _timed_first_call(site, _jfn, args, kwargs)
-                    if out is not _AOT_UNAVAILABLE:
-                        return out
-                with _tel.span(f"jitted:{site}"):
-                    return _jfn(*args, **kwargs)
-            return _jfn(*args, **kwargs)
+            if _traced(args, kwargs):
+                return _jfn(*args, **kwargs)
+            if fresh[0]:
+                fresh[0] = False
+                return launch(span_site, _jfn, args, kwargs, miss=True)
+            return launch(span_site, _jfn, args, kwargs)
 
         fn.lower = jfn.lower  # HLO inspection passthrough (tests)
         fn.jitted = jfn
@@ -152,34 +205,6 @@ def jitted(key: Tuple, make_fn: Callable[[], Callable], jit_kwargs=None) -> Call
     elif _tel.enabled:
         _tel.inc("compile.cache.hits")
     return fn
-
-
-_AOT_UNAVAILABLE = object()
-
-
-def _timed_first_call(site: str, jfn, args, kwargs):
-    """Telemetry-enabled first invocation of a freshly built ``jitted``
-    entry: stage the call through the AOT API so the compile-miss event
-    records trace+lower time and XLA compile time separately, then run
-    the compiled executable (one dispatch, already counted by the
-    caller).  Falls back to the plain call — returning the
-    ``_AOT_UNAVAILABLE`` sentinel — when the AOT path does not apply
-    (kwargs, older jax)."""
-    if kwargs:
-        return _AOT_UNAVAILABLE
-    t0 = _tel.clock()
-    try:
-        lowered = jfn.lower(*args)
-        t1 = _tel.clock()
-        compiled = lowered.compile()
-        t2 = _tel.clock()
-    except Exception:
-        return _AOT_UNAVAILABLE
-    _tel.record_event(
-        "compile", site=site, trace_lower_s=t1 - t0, compile_s=t2 - t1
-    )
-    with _tel.span(f"jitted:{site}", phase="first_run"):
-        return compiled(*args)
 
 
 def clear_cache() -> None:

@@ -3,8 +3,7 @@
 The zero-cold-start half of fleet serving (docs/design.md §22): a warm
 serving process captures its compiled ``ht.fuse`` predict programs,
 lowers them through the staged AOT path
-(``jfn.lower(specs).compile()`` — the same pipeline
-:func:`heat_tpu.core._compile._timed_first_call` stages for timing) and
+(``jfn.lower(specs).compile()``) and
 serializes the XLA executables via
 :mod:`jax.experimental.serialize_executable`.  A fresh replica installs
 the bundles straight into the fuse cache, so its first request is a

@@ -31,17 +31,18 @@ __all__ = [
 def _spanned_method(meth, label: str):
     """Wrap an estimator entry point in a telemetry span.
 
-    The wrapper is a single flag predicate per call while telemetry is
-    disabled; enabled, every ``fit``/``predict`` lands in the per-site
-    span aggregates under ``fit:<ClassName>`` / ``predict:<ClassName>``
-    (the class is resolved at call time, so subclasses inheriting a
-    wrapped method report under their own name)."""
+    The wrapper is a single predicate per call while nothing records;
+    recording, every ``fit``/``predict`` is a span of kind ``entry`` named
+    ``fit:<ClassName>`` / ``predict:<ClassName>`` (the class is resolved
+    at call time, so subclasses inheriting a wrapped method report under
+    their own name) that also holds the launches and host syncs counted
+    while it was open."""
 
     @functools.wraps(meth)
     def wrapper(self, *args, **kwargs):
-        if not _tel.enabled:
+        if not _tel.recording():
             return meth(self, *args, **kwargs)
-        with _tel.span(f"{label}:{type(self).__name__}"):
+        with _tel.span(f"{label}:{type(self).__name__}", "entry"):
             return meth(self, *args, **kwargs)
 
     wrapper._telemetry_wrapped = True

@@ -225,7 +225,7 @@ def save_estimator(est: BaseEstimator, path: str) -> None:
     }
     if _tel.enabled:
         _tel.inc("checkpoint.saves")
-        with _tel.span("ckpt:save_estimator", cls=type(est).__name__, path=path):
+        with _tel.span("ckpt:save_estimator", "io", cls=type(est).__name__, path=path):
             _io._save_hdf5_many(
                 path,
                 sorted(ctx.datasets.items()),
@@ -416,7 +416,7 @@ def load_estimator(path: str) -> BaseEstimator:
         )
     if _tel.enabled:
         _tel.inc("checkpoint.loads")
-        with _tel.span("ckpt:load_estimator", path=path):
+        with _tel.span("ckpt:load_estimator", "io", path=path):
             est = _instantiate(manifest["root"], path, {})
         _tel.record_event(
             "checkpoint", site=type(est).__name__, op="load", path=path

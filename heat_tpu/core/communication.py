@@ -58,8 +58,8 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..telemetry import _core as _tel
-from ._compile import jitted
-from ._tracing import in_trace, record_dispatch
+from ._compile import jitted, launch
+from ._tracing import in_trace
 
 __all__ = [
     "Communication",
@@ -607,7 +607,7 @@ class XlaCommunication(Communication):
                 _cq._account_wire(
                     "allgather", None, int(np.prod(array.shape)) // self.size, self.size
                 )
-                with _tel.span("comm:allgather", mesh=self.size):
+                with _tel.span("comm:allgather", "comm", mesh=self.size):
                     return _reshard(array, self.sharding(array.ndim, None))
         return _reshard(array, self.sharding(array.ndim, None))
 
@@ -834,7 +834,7 @@ class XlaCommunication(Communication):
 
             elems = int(np.prod(array.shape[1:])) if array.ndim > 1 else 1
             _account_wire("allreduce", None, elems, n)
-            with _tel.span("comm:allreduce", op=op, mesh=n):
+            with _tel.span("comm:allreduce", "comm", op=op, mesh=n):
                 return fn(array)
         return fn(array)
 
@@ -1102,12 +1102,9 @@ def _reshard(array, sh: NamedSharding):
         and len(getattr(array.sharding, "device_set", ())) > 1
     ):
         return _constrained_copy(array, sh)
-    record_dispatch()
     if _tel.enabled:
         _tel.inc("comm.reshards")
-        with _tel.span("comm:reshard"):
-            return jax.device_put(array, sh)
-    return jax.device_put(array, sh)
+    return launch("comm:reshard", jax.device_put, (array, sh), kind="comm")
 
 
 # ---------------------------------------------------------------------- #

@@ -43,6 +43,7 @@ Design invariants:
 from __future__ import annotations
 
 import math
+import operator
 import warnings
 from typing import List, Optional, Sequence, Tuple, Union
 
@@ -52,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import shard_map
 
+from ..telemetry import _core as _tel
 from . import types
 from ._compile import jitted
 from ._tracing import require_concrete
@@ -68,6 +70,8 @@ __all__ = ["DNDarray", "LocalIndex"]
 #: operands keep the plain jnp path — the ring's p rounds only pay off once
 #: per-device memory is at stake.  Override with HEAT_TPU_RING_INDEX_MIN.
 import os as _os
+
+_item = operator.methodcaller("item")  # the host read of a 0-d device array
 
 _RING_INDEX_MIN = int(_os.environ.get("HEAT_TPU_RING_INDEX_MIN", str(1 << 22)))
 
@@ -502,7 +506,7 @@ class DNDarray:
         """Gather to a host numpy array (reference dndarray.py: ``numpy`` —
         there an implicit resplit(None) + .numpy())."""
         require_concrete(".numpy()")
-        return np.asarray(self.larray)
+        return _tel.host_read("sync:dndarray.numpy", self.larray, np.asarray)
 
     def copy(self) -> "DNDarray":
         """An independent copy of this array (reference dndarray.py: ``copy``
@@ -546,13 +550,13 @@ class DNDarray:
 
     def __array__(self, dtype=None):
         require_concrete("np.asarray()")
-        arr = np.asarray(self.larray)
+        arr = _tel.host_read("sync:dndarray.asarray", self.larray, np.asarray)
         return arr.astype(dtype) if dtype is not None else arr
 
     def tolist(self, keepsplit: bool = False) -> list:
         """Nested python lists of the global data (reference dndarray.py:3718)."""
         require_concrete(".tolist()")
-        return np.asarray(self.larray).tolist()
+        return _tel.host_read("sync:dndarray.tolist", self.larray, np.asarray).tolist()
 
     def item(self):
         """The single element of a size-1 array as a python scalar
@@ -560,7 +564,7 @@ class DNDarray:
         require_concrete(".item()")
         if self.size != 1:
             raise ValueError("only one-element DNDarrays can be converted to Python scalars")
-        return self.larray.reshape(()).item()
+        return _tel.host_read("sync:dndarray.item", self.larray.reshape(()), _item)
 
     def __bool__(self) -> bool:
         require_concrete("bool()")

@@ -64,7 +64,6 @@ from ._tracing import (
     FuseTraceError,
     applying_layout_plan,
     in_trace,
-    record_dispatch,
     trace_mode,
 )
 from .dndarray import DNDarray
@@ -269,16 +268,14 @@ class _FusedFunction:
             if self._layout_plan is not None else _null_ctx()
         )
         with plan_ctx:
-            if _tel.enabled:
-                # a program whose out_treedef is still unset runs its
-                # DNDarray trace + XLA compile inside this first call, so
-                # that is the "build" span; later calls replay
-                site = "fuse:build" if program.out_treedef is None else "fuse:replay"
-                with _tel.span(site, name=getattr(self._fn, "__name__", "<pipeline>")):
-                    raws = program.jfn(tuple(operands))
-            else:
-                raws = program.jfn(tuple(operands))
-        record_dispatch()
+            # a program whose out_treedef is still unset runs its DNDarray
+            # trace + XLA compile inside this first call, so that is the
+            # "build" span; later calls replay
+            raws = _compile.launch(
+                "fuse:build" if program.out_treedef is None else "fuse:replay",
+                program.jfn, (tuple(operands),),
+                name=getattr(self._fn, "__name__", "<pipeline>"),
+            )
 
         if capture_specs is not None:
             entry = {

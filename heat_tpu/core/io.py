@@ -166,24 +166,24 @@ def _sharded_from_reader(shape, np_dtype, split, device, comm, read_slices):
 
         def _cb(index):
             if _tel.enabled:
-                with _tel.span("io:read", sharded=True):
+                with _tel.span("io:read", "io", sharded=True):
                     block = np.asarray(read_slices(index))
                 _tel.account_bytes("io", "read", block.nbytes, block.nbytes)
                 return block
             return read_slices(index)
 
         if _tel.enabled:
-            with _tel.span("io:h2d", bytes=total_bytes):
+            with _tel.span("io:h2d", "io", bytes=total_bytes):
                 garr = jax.make_array_from_callback(tuple(shape), sharding, _cb)
             _tel.account_bytes("io", "h2d", total_bytes, total_bytes)
         else:
             garr = jax.make_array_from_callback(tuple(shape), sharding, _cb)
     else:
         if _tel.enabled:
-            with _tel.span("io:read", sharded=False):
+            with _tel.span("io:read", "io", sharded=False):
                 block = np.asarray(read_slices(tuple(slice(None) for _ in shape)))
             _tel.account_bytes("io", "read", block.nbytes, block.nbytes)
-            with _tel.span("io:h2d", bytes=total_bytes):
+            with _tel.span("io:h2d", "io", bytes=total_bytes):
                 garr = jnp.asarray(block)
             _tel.account_bytes("io", "h2d", total_bytes, total_bytes)
         else:
@@ -600,7 +600,7 @@ def load(path: str, *args, **kwargs) -> DNDarray:
     """Extension-dispatched load (reference io.py:622-664)."""
     if _tel.enabled:
         _tel.inc("io.loads")
-        with _tel.span("io:load", path=str(path)):
+        with _tel.span("io:load", "io", path=str(path)):
             return _load_impl(path, *args, **kwargs)
     return _load_impl(path, *args, **kwargs)
 
@@ -628,7 +628,7 @@ def save(data: DNDarray, path: str, *args, **kwargs) -> None:
     saves data or a fitted model alike."""
     if _tel.enabled:
         _tel.inc("io.saves")
-        with _tel.span("io:save", path=str(path)):
+        with _tel.span("io:save", "io", path=str(path)):
             return _save_impl(data, path, *args, **kwargs)
     return _save_impl(data, path, *args, **kwargs)
 

@@ -426,7 +426,7 @@ def _summa_grid(aa, ba, dims, comm, precision, layout: str = "grid"):
         _tel.account_bytes(
             "summa2d", "f32", model["exact_wire_bytes"], model["wire_bytes"]
         )
-        with _tel.span("comm:summa2d", mesh=f"{r}x{c}", panels=L, layout=layout):
+        with _tel.span("comm:summa2d", "comm", mesh=f"{r}x{c}", panels=L, layout=layout):
             return timed_dispatch("summa2d", ov, lambda: fn(aa, ba))
     return timed_dispatch("summa2d", ov, lambda: fn(aa, ba))
 

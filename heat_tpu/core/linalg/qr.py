@@ -670,7 +670,7 @@ def _grid_qr(a: DNDarray, jt, tiles_per_proc: int):
             "qr2d", "f32", model["exact_wire_bytes"], model["wire_bytes"]
         )
         with _tel.span(
-            "comm:qr2d", mesh=f"{r}x{c}", panels=len(bounds), overlap=ov
+            "comm:qr2d", "comm", mesh=f"{r}x{c}", panels=len(bounds), overlap=ov
         ):
             return timed_dispatch("qr2d", ov, lambda: fn(arr))
     return timed_dispatch("qr2d", ov, lambda: fn(arr))

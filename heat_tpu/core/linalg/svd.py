@@ -393,6 +393,7 @@ def _grid_svd(a: DNDarray, dtype, compute_uv: bool):
         )
         with _tel.span(
             "comm:svd2d",
+            "comm",
             mesh=f"{r}x{c}",
             iterations=_QDWH_MAXIT,
             overlap=ov,

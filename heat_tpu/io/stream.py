@@ -401,7 +401,7 @@ def stream_chunks(
             arrs = []
             for src, sh in zip(sources, shardings):
                 if _tel.enabled:
-                    with _tel.span("io:read", path=str(src.path), rows=nv):
+                    with _tel.span("io:read", "io", path=str(src.path), rows=nv):
                         block = np.asarray(_read_chunk(src, lo, hi))
                     _tel.account_bytes("io", "read", block.nbytes, block.nbytes)
                 else:
@@ -420,7 +420,7 @@ def stream_chunks(
                     return _buf[index]
 
                 if _tel.enabled:
-                    with _tel.span("io:h2d", path=str(src.path), bytes=buf.nbytes):
+                    with _tel.span("io:h2d", "io", path=str(src.path), bytes=buf.nbytes):
                         garr = jax.make_array_from_callback(buf.shape, sh, _cb)
                     _tel.account_bytes("io", "h2d", buf.nbytes, buf.nbytes)
                 else:

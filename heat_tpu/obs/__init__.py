@@ -4,12 +4,12 @@ One import surface for the request-scoped observability layer built on
 :mod:`heat_tpu.telemetry` (docs/design.md §19):
 
 - :func:`trace_ctx` — request-scoped trace context.  Everything emitted
-  inside ``with obs.trace_ctx("req-42"):`` — spans, events, Perfetto
-  records, flight-recorder notes — carries the request id under
+  inside ``with obs.trace_ctx("req-42"):`` — spans, events, their stats in a
+  profiler trace, flight-recorder notes — carries the request id under
   ``rid``, and the serve stack propagates the ids across the
   MicroBatcher queue onto the per-micro-batch ``serve:batch`` span, so
   one request is walkable end to end: loadgen reply → tagged serve span
-  → Perfetto event → postmortem dump.
+  → profiler-trace event → postmortem dump.
 - :func:`observe` / :class:`Histogram` — fixed-memory streaming
   latency distributions (log8 buckets, ~4.4% relative quantile bound,
   mergeable across threads).

@@ -100,7 +100,7 @@ def save_loop_state(path: str, state: Dict[str, Any], meta: Optional[Dict[str, A
     }
     if _tel.enabled:
         _tel.inc("checkpoint.saves")
-        with _tel.span("ckpt:save", path=str(path)):
+        with _tel.span("ckpt:save", "io", path=str(path)):
             _io._save_hdf5_many(
                 path, datasets, attrs={_MANIFEST_ATTR: json.dumps(manifest)}
             )

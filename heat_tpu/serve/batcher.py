@@ -133,8 +133,8 @@ class Request:
     the queue with the payload (contextvars do not cross the worker
     thread, so the id must travel on the request itself), and the engine
     re-establishes ``telemetry.trace_ctx`` from the batch's ids around
-    execution — that is how the ``serve:batch`` span, the Perfetto
-    events, and the flight ring all get tagged with the requests of the
+    execution — that is how the ``serve:batch`` span, its event in
+    a profiler trace, and the flight ring all get tagged with the requests of the
     micro-batch they belong to."""
 
     seq: int

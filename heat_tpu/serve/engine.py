@@ -38,7 +38,7 @@ Request-scoped observability (docs/design.md §19): every request gets a
 ``trace_id`` (caller-supplied ``request_id`` or a minted
 ``<lane>#<seq>``), the engine re-establishes ``telemetry.trace_ctx``
 with the batch's ids around execution — so the ``serve:batch`` span,
-its Perfetto record, and the flight-recorder ring all say *which*
+its event in a profiler trace, and the flight-recorder ring all say *which*
 requests the micro-batch served — and the id comes back on the
 :class:`Reply`.  Per-request latencies stream into the
 ``serve.latency_ms`` histogram (``telemetry.observe``), feed the
@@ -80,7 +80,7 @@ class Reply:
     """One request's outcome: the per-row prediction values (host numpy,
     exactly the request's rows), the degrade flag, and bookkeeping.
     ``trace_id`` is the request's observability handle — grep it in the
-    event stream / Perfetto export / flight postmortem to walk this
+    event stream / profiler trace / flight postmortem to walk this
     request's path through the engine."""
 
     value: np.ndarray
@@ -465,7 +465,7 @@ class ServeEngine:
             else contextlib.nullcontext()
         )
         # the micro-batch trace context: every span/event below (the
-        # serve:batch span, nested comm:* spans, Perfetto records, flight
+        # serve:batch span, nested comm:* spans, profiler-trace events, flight
         # notes) is tagged with ALL coalesced request ids; ids already in
         # the ambient context (sync flush inside the caller's trace_ctx)
         # are not repeated

@@ -121,7 +121,7 @@ class LoadReport:
     twin: Optional[dict]
     #: the request ids the engine stamped on the replies, in submit
     #: order — the handles that walk each request through the event
-    #: stream / Perfetto export / flight postmortem
+    #: stream / profiler trace / flight postmortem
     trace_ids: Tuple[str, ...] = ()
     #: canonical ``Histogram.state()`` of the millisecond latency stream
     #: — the mergeable form: fleet-level percentiles come from merging

@@ -188,7 +188,7 @@ class ModelRegistry:
         path = self._path(tenant, model, version)
         if _tel.enabled:
             with _tel.span(
-                "serve:registry.publish", tenant=tenant, model=model, version=version
+                "serve:registry.publish", "io", tenant=tenant, model=model, version=version
             ):
                 _ckpt.save_estimator(est, path)
             _tel.inc("serve.registry.publishes")
@@ -278,7 +278,7 @@ class ModelRegistry:
         try:
             if _tel.enabled:
                 with _tel.span(
-                    "serve:registry.load", tenant=tenant, model=model, version=version
+                    "serve:registry.load", "io", tenant=tenant, model=model, version=version
                 ):
                     est = _ckpt.load_estimator(path)
                 _tel.inc("serve.registry.loads")

@@ -16,14 +16,18 @@ compiler.  Row-sharding of X propagates to row-sharding of D.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from ..core import types
 from ..core._compile import jitted
+from ..core._tracing import in_trace
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
+from ..telemetry import _core as _tel
 
 __all__ = ["cdist", "manhattan", "rbf", "quadratic_d2"]
 
@@ -63,15 +67,36 @@ def _wrap(x: DNDarray, garr, dtype) -> DNDarray:
 
 def _euclidean(xa, ya, quadratic_expansion: bool):
     if quadratic_expansion:
-        return jnp.sqrt(quadratic_d2(xa, ya))
-    diff = xa[:, None, :] - ya[None, :, :]
-    return jnp.sqrt(jnp.sum(diff * diff, axis=-1))
+        with jax.named_scope("cdist.quadratic"):
+            return jnp.sqrt(quadratic_d2(xa, ya))
+    with jax.named_scope("cdist.exact"):
+        diff = xa[:, None, :] - ya[None, :, :]
+        return jnp.sqrt(jnp.sum(diff * diff, axis=-1))
 
 
 from ..core._split_semantics import split_semantics as _split_semantics
 
 
+def _entry(site: str):
+    """A public entry as a span of kind ``entry`` (one predicate a call when
+    nothing records).  Inside an ``ht.fuse`` trace the call inlines into the
+    surrounding program and is no entry of its own."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            if in_trace() or not _tel.recording():
+                return fn(*args, **kwargs)
+            with _tel.span(site, "entry"):
+                return fn(*args, **kwargs)
+
+        return wrapper
+
+    return deco
+
+
 @_split_semantics("entry_split0")
+@_entry("spatial:cdist")
 def cdist(X: DNDarray, Y: Optional[DNDarray] = None, quadratic_expansion: bool = False) -> DNDarray:
     """Pairwise euclidean distances (reference distance.py:166-172).
 
@@ -87,6 +112,7 @@ def cdist(X: DNDarray, Y: Optional[DNDarray] = None, quadratic_expansion: bool =
     return _wrap(X, fn(xa, ya), dtype)
 
 
+@_entry("spatial:rbf")
 def rbf(
     X: DNDarray,
     Y: Optional[DNDarray] = None,
@@ -112,6 +138,7 @@ def rbf(
     return _wrap(X, fn(xa, ya, jnp.asarray(sigma, xa.dtype)), dtype)
 
 
+@_entry("spatial:manhattan")
 def manhattan(X: DNDarray, Y: Optional[DNDarray] = None, expand: bool = False) -> DNDarray:
     """Pairwise L1 distances (reference distance.py:180-186)."""
     xa, ya, dtype = _prep(X, Y)

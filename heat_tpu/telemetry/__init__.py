@@ -2,25 +2,34 @@
 
 One registry for everything the runtime can tell you about itself:
 
-- **spans** — ``telemetry.span("site")`` (context manager + decorator),
-  emitted automatically by the hot paths: ``jitted()`` replay vs
-  first-compile (miss events split trace+lower vs compile time),
-  ``ht.fuse`` program build/replay, communication-layer reshards and
-  collectives, checkpoint saves, estimator ``fit``/``predict``;
+- **spans** — ``telemetry.span("site", kind=...)`` (context manager +
+  decorator): one record with ``id`` / ``parent`` / ``root`` and the
+  ``kind`` of layer boundary it stands at (``entry``, ``launch``,
+  ``sync``, ``comm``, ``io``, ``other``), emitted automatically by the
+  hot paths: estimator ``fit``/``predict`` and ``ht.spatial`` entries,
+  every launch of a compiled program (``jitted()``, ``ht.fuse``
+  build/replay, the estimators' own ``jax.jit`` programs; a first call
+  carries ``miss=True``), every blocking host read
+  (:func:`host_read`), reshards and collectives, checkpoint saves.
+  :func:`self_times` gives each span's time less its children's;
 - **counters & gauges** — device dispatches, compile-cache hits /
   misses / size, collective invocations with exact-vs-wire byte
   accounting per precision mode (the compression ratio is the live
   gauge ``comm.wire_ratio.<mode>``), guard incidents, checkpoint
   save/load/resume events;
-- **exporters** — ``snapshot()`` (in-memory dict), a JSONL sink
-  (``set_jsonl(path)``), and Chrome/Perfetto trace-event JSON
-  (``start_trace(path)`` / ``stop_trace()``, optionally interleaved
-  with ``jax.profiler`` device capture);
+- **one timeline** — while a ``jax.profiler`` trace is being taken every
+  span is also a ``jax.profiler.TraceAnnotation`` of the same name, so
+  the program's spans lie in the profiler's own trace, on the clock of
+  the device planes, and the trace itself switches recording on
+  (:func:`recording`): ``jax.profiler.start_trace(dir)`` … ``stop_trace()``
+  then :func:`profiled_spans` for the same spans in memory;
+- **exporters** — ``events()`` / ``snapshot()`` (in memory) and a JSONL
+  sink (``set_jsonl(path)``);
 - **request tracing** — ``trace_ctx("req-1")`` tags every span and
   event emitted inside the context with the active request ids
   (``rid``), which is how a serve request is walked from the loadgen
-  reply through the ``serve:batch`` span into the Perfetto timeline
-  and the flight-recorder postmortem;
+  reply through the ``serve:batch`` span (its ``rid`` stat in a profiler
+  trace) and the flight-recorder postmortem;
 - **streaming histograms & SLOs** — ``observe(name, value)`` feeds a
   fixed-memory log-bucketed :class:`~heat_tpu.telemetry.hist.Histogram`
   (quantiles within a documented ~4.4% relative bound, mergeable across
@@ -38,9 +47,14 @@ Disabled (the default) it costs one predicate per instrumented site and
 contributes nothing to compile-cache keys; ``enable(deterministic=True)``
 swaps timestamps for a monotone sequence so tests can assert on event
 streams bitwise.  ``HEAT_TELEMETRY=1`` enables collection from the
-environment.  See docs/design.md ("Observability") and the tutorial
+environment, ``HEAT_TELEMETRY_JSONL=<path>`` opens the JSONL sink and
+``HEAT_FLIGHT_DIR=<dir>`` points the flight recorder's dumps at a
+directory (the hooks of the CI telemetry lane,
+scripts/run_test_matrix.sh).  See docs/design.md ("Observability") and the tutorial
 walkthrough for a worked example.
 """
+
+import os as _os
 
 from ._core import (
     account_bytes,
@@ -56,20 +70,25 @@ from ._core import (
     is_enabled,
     current_trace,
     histogram,
+    host_read,
+    host_sync_count,
     jsonl_path,
     observe,
+    profiled_spans,
     record_dispatch,
     record_event,
+    recording,
     reset,
     reset_dispatch_count,
+    self_times,
     set_clock,
     set_jsonl,
     set_max_events,
     snapshot,
     span,
+    spanned,
     trace_ctx,
 )
-from .export import start_trace, stop_trace, trace_active
 from .hist import Histogram
 from .slo import SloMonitor
 from . import flight
@@ -97,9 +116,12 @@ __all__ = [
     "dispatch_count",
     "reset_dispatch_count",
     "counting_dispatches",
-    "start_trace",
-    "stop_trace",
-    "trace_active",
+    "recording",
+    "spanned",
+    "self_times",
+    "profiled_spans",
+    "host_read",
+    "host_sync_count",
     "trace_ctx",
     "current_trace",
     "observe",
@@ -111,6 +133,22 @@ __all__ = [
     "MetricsServer",
     "prometheus_text",
 ]
+
+
+def _env_autostart() -> None:
+    """The CI-lane hooks (see the module docstring)."""
+    if _os.environ.get("HEAT_TELEMETRY") == "1":
+        enable()
+    jsonl = _os.environ.get("HEAT_TELEMETRY_JSONL")
+    if jsonl:
+        enable()
+        set_jsonl(jsonl)
+    flight_dir = _os.environ.get("HEAT_FLIGHT_DIR")
+    if flight_dir:
+        flight.set_dump_dir(flight_dir)
+
+
+_env_autostart()
 
 
 def __getattr__(name):

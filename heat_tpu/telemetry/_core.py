@@ -5,24 +5,37 @@ instrumented hot path (the ``jitted()`` replay wrapper, ``ht.fuse`` build
 and replay, the communication layer's reshards and collectives, the
 compressed rings' wire-byte accounting, guard incidents, checkpoint
 save/load/resume) reports here, and every exporter (``snapshot()``, the
-JSONL sink, the Perfetto trace writer in :mod:`heat_tpu.telemetry.export`)
-reads from here.
+JSONL sink, the profiler sink below) reads from here.
+
+One timeline
+------------
+A span is one record (:class:`_Span`): ``site``, ``kind`` (the layer
+boundary it stands at), ``id`` / ``parent`` / ``root``, ``ts``, ``dur``.
+It is kept in memory (:func:`events`) and, while a jax profiler trace is
+being taken, it is also a ``jax.profiler.TraceAnnotation`` of the same
+name: the program's spans then lie in the profiler's own trace, on the
+launching thread, on the clock of the device planes.  There is no second
+trace file.  Recording is on after :func:`enable` **or while the profiler
+runs** (:func:`recording`), so tracing a run records its spans with no
+switch of its own, and an untraced run records nothing.
 
 Overhead contract
 -----------------
 Telemetry is off by default and *disabled mode costs one predicate per
 site*: instrumented library code guards every report with
-``if _core.enabled:`` — a module-attribute load and a branch, no object
-allocation, no lock, no clock read.  Enabling flips one module-level
-flag; nothing is registered with the compile-cache key context, so
-toggling telemetry can never change what a cached program means or force
-a retrace (asserted by tests/test_telemetry.py).
+``if _core.enabled:`` (counters, events) or ``if _core.recording():``
+(span sites: the flag load plus one ``TraceAnnotation.is_enabled()``
+call) — no object allocation, no lock, no clock read.  Enabling flips one
+module-level flag; nothing is registered with the compile-cache key
+context, so toggling telemetry can never change what a cached program
+means or force a retrace (asserted by tests/test_telemetry.py).
 
-The one always-on piece of state is the *dispatch counter*: it predates
-telemetry (tier-1 dispatch-count gates consume it through the
-:mod:`heat_tpu.core._tracing` shim) and keeps counting with telemetry
-disabled.  It is guarded by the registry lock, so threaded serving does
-not lose increments.
+The always-on state is two counters: the *dispatch counter* (it predates
+telemetry; tier-1 dispatch-count gates consume it through the
+:mod:`heat_tpu.core._tracing` shim) and the *host-sync counter*
+(:func:`host_read`: blocking device-to-host reads).  Both keep counting
+with telemetry disabled and are guarded by the registry lock, so threaded
+serving does not lose increments.
 
 Determinism
 -----------
@@ -59,7 +72,14 @@ __all__ = [
     "is_deterministic",
     "clock",
     "set_clock",
+    "recording",
+    "install_profiler",
     "span",
+    "spanned",
+    "self_times",
+    "profiled_spans",
+    "host_read",
+    "host_sync_count",
     "inc",
     "gauge",
     "observe",
@@ -117,9 +137,26 @@ _trace_var: "contextvars.ContextVar[Tuple[str, ...]]" = contextvars.ContextVar(
 _jsonl = None  # type: Optional[Any]
 _jsonl_path: Optional[str] = None
 
-#: Perfetto trace-event buffer; managed by telemetry.export.  Lives here
-#: so span/event emission never has to import the exporter.
-_trace_buf: Optional[List[dict]] = None
+#: the profiler sink: ``jax.profiler.TraceAnnotation`` and its static
+#: ``is_enabled``, installed by :func:`install_profiler` from a module that
+#: imports jax anyway (``core/_compile.py``) so this one never has to.
+#: Until then ``bool`` stands in: ``bool()`` is ``False``.
+_annotation: Optional[Any] = None
+_profiler_on: Callable[[], bool] = bool
+#: what the last poll of the profiler saw, and the positions in ``_events``
+#: at which a poll first saw it on and first saw it off again
+_prof_seen = False
+_prof_lo = 0
+_prof_hi: Optional[int] = 0
+
+#: the innermost open span of this context as ``(id, root)``
+_span_var: "contextvars.ContextVar[Optional[Tuple[int, int]]]" = contextvars.ContextVar(
+    "heat_tpu_span", default=None
+)
+_next_id = 0
+
+#: the layer boundary a span stands at; readers select by it
+KINDS = ("entry", "launch", "sync", "comm", "io", "other")
 
 #: thread ids -> small stable indices (first-seen order), so exported
 #: ``tid`` values are deterministic in single-threaded runs
@@ -184,16 +221,47 @@ def is_enabled() -> bool:
     return enabled
 
 
+def install_profiler(annotation) -> None:
+    """Hand over ``jax.profiler.TraceAnnotation`` (the sink of every
+    recorded span, and through its ``is_enabled`` the switch)."""
+    global _annotation, _profiler_on
+    _annotation = annotation
+    _profiler_on = annotation.is_enabled
+
+
+def recording() -> bool:
+    """True when spans are being recorded: :func:`enable` was called, or a
+    jax profiler trace is being taken.  The guard of every span site; it is
+    also the poll that notes where in the event list a trace began and
+    ended (:func:`profiled_spans`)."""
+    on = _profiler_on()
+    if on != _prof_seen:
+        _profiler_edge(on)
+    return on or enabled
+
+
+def _profiler_edge(on: bool) -> None:
+    global _prof_seen, _prof_lo, _prof_hi
+    with _lock:
+        if on == _prof_seen:
+            return
+        _prof_seen = on
+        if on:
+            _prof_lo, _prof_hi = len(_events), None
+        else:
+            _prof_hi = len(_events)
+
+
 def is_deterministic() -> bool:
     return _deterministic
 
 
 def reset() -> None:
     """Drop all recorded counters, gauges, span aggregates, and events,
-    and rewind the deterministic sequence.  The dispatch counter is NOT
-    touched — it predates telemetry and tests scope it with
-    :func:`counting_dispatches` instead."""
-    global _det_seq
+    and rewind the deterministic sequence and the span ids.  The dispatch
+    and host-sync counters are NOT touched — tests scope them with
+    :func:`counting_dispatches` or a difference of :func:`host_sync_count`."""
+    global _det_seq, _next_id, _prof_lo, _prof_hi
     with _lock:
         _counters.clear()
         _gauges.clear()
@@ -201,9 +269,9 @@ def reset() -> None:
         _hists.clear()
         _events.clear()
         _tids.clear()
-        if _trace_buf is not None:
-            _trace_buf.clear()
         _det_seq = 0
+        _next_id = 0
+        _prof_lo, _prof_hi = 0, (None if _prof_seen else 0)
 
 
 # --------------------------------------------------------------------- #
@@ -220,15 +288,14 @@ def _tid() -> int:
 
 def _emit(ev: dict) -> None:
     """Append one event under the lock: bounded in-memory list, JSONL
-    sink, the flight-recorder ring, and the Perfetto buffer when a trace
-    is being collected.
+    sink and the flight-recorder ring.
 
     Overflow of the bounded list is NEVER silent: the drop is counted
     under ``telemetry.events.dropped`` — surfaced by ``snapshot()`` and
     the ``/metrics`` endpoint — so a long-running server that outlives
     the buffer shows exactly how much of the stream it lost.  The JSONL
-    sink, flight ring, and trace buffer still receive the event (each is
-    bounded or externally drained on its own)."""
+    sink and the flight ring still receive the event (each is bounded or
+    externally drained on its own)."""
     with _lock:
         if len(_events) < _MAX_EVENTS:
             _events.append(ev)
@@ -240,8 +307,6 @@ def _emit(ev: dict) -> None:
             _jsonl.write(json.dumps(ev, sort_keys=True, default=str) + "\n")
         if _flight_append is not None:
             _flight_append(ev)
-        if _trace_buf is not None:
-            _trace_buf.append(_trace_event(ev))
 
 
 def set_max_events(n: Optional[int]) -> int:
@@ -254,31 +319,6 @@ def set_max_events(n: Optional[int]) -> int:
         prev = _MAX_EVENTS
         _MAX_EVENTS = (1 << 16) if n is None else int(n)
     return prev
-
-
-def _trace_event(ev: dict) -> dict:
-    """Map one telemetry event onto the Chrome/Perfetto trace_event
-    schema (https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU):
-    spans become complete ("X") slices, everything else an instant."""
-    ts = int(ev.get("ts", 0.0) * 1e6)
-    args = {
-        k: v for k, v in ev.items() if k not in ("type", "site", "ts", "dur")
-    }
-    out = {
-        "name": ev.get("site", ev.get("type", "event")),
-        "cat": ev.get("type", "event"),
-        "ts": ts,
-        "tid": ev.get("tid", 0),
-    }
-    if ev.get("type") == "span":
-        out["ph"] = "X"
-        out["dur"] = int(ev.get("dur", 0.0) * 1e6)
-    else:
-        out["ph"] = "i"
-        out["s"] = "t"
-    if args:
-        out["args"] = args
-    return out
 
 
 def record_event(etype: str, site: str = "", **fields) -> None:
@@ -305,25 +345,11 @@ def inc(name: str, n: int = 1) -> None:
 
 
 def gauge(name: str, value: float) -> None:
-    """Set a named gauge to ``value``.  No-op while disabled.
-
-    While a Perfetto trace is being collected the update also lands on
-    the timeline as a counter ("C") event, so live gauges — e.g. the
-    exact-vs-wire compression ratio — render as a graph over time."""
+    """Set a named gauge to ``value``.  No-op while disabled."""
     if not enabled:
         return
     with _lock:
         _gauges[name] = value
-        if _trace_buf is not None:
-            _trace_buf.append(
-                {
-                    "name": name,
-                    "ph": "C",
-                    "ts": int(clock() * 1e6),
-                    "tid": 0,
-                    "args": {"value": value},
-                }
-            )
 
 
 def observe(name: str, value: float) -> None:
@@ -358,8 +384,9 @@ def trace_ctx(*request_ids):
     The tentpole of request-scoped observability: ``trace_ctx("rq-17")``
     installs the id in a contextvar, and every span and instant event
     that closes inside the context carries ``rid=[...]`` — on the event
-    stream, in the JSONL sink, in the flight-recorder ring, and in the
-    Perfetto export (as ``args.rid``), so one slow request can be walked
+    stream, in the JSONL sink, in the flight-recorder ring, and as the
+    ``rid`` stat of the span's event in a profiler trace, so one slow
+    request can be walked
     from its reply back through the micro-batch's ``serve:*`` span and
     any nested ``comm:*`` spans to the device dispatch that served it.
 
@@ -432,36 +459,82 @@ def account_bytes(op: str, mode: str, exact_bytes: int, wire_bytes: int) -> None
 # --------------------------------------------------------------------- #
 # spans                                                                 #
 # --------------------------------------------------------------------- #
+def _stat(value):
+    """A span field as a stat of its ``TraceAnnotation``: numbers as they
+    are, anything else as text without the two characters (``,`` ``#``) the
+    profiler's own encoding of stats is built from."""
+    if isinstance(value, (int, float)):
+        return value
+    if isinstance(value, (list, tuple)):
+        value = ";".join(str(v) for v in value)
+    return str(value).replace(",", ";").replace("#", "")
+
+
 class _Span:
     """One ``telemetry.span("site")`` — context manager and decorator.
 
-    Enter/exit are each a single predicate when telemetry is disabled.
-    On exit the span lands twice: in the per-site aggregate (count +
-    total seconds, what ``snapshot()`` reports) and as one event on the
-    stream (what the JSONL sink and the Perfetto exporter consume).
+    Enter/exit are each a single predicate when nothing records.  A
+    recorded span carries ``id``, ``parent`` (the span open on this
+    context when it opened, ``None`` for a root) and ``root`` (the id of
+    the outermost span: what all spans of one public call share), and its
+    ``kind``.  An ``entry`` span also records, at the same boundary as its
+    time, the ``launches`` and ``syncs`` counted while it was open.  On
+    exit the span lands in the per-site aggregate (count + total seconds,
+    what ``snapshot()`` reports) and as one event on the stream (what the
+    JSONL sink and :func:`profiled_spans` consume); while the profiler
+    runs it is also a ``TraceAnnotation`` in the profiler's trace.
     Exceptions propagate; the span still records, tagged with the
     exception type."""
 
-    __slots__ = ("site", "fields", "_t0")
+    __slots__ = ("site", "kind", "fields", "_t0", "_id", "_up", "_token", "_ann", "_base")
 
-    def __init__(self, site: str, fields: Optional[dict] = None):
+    def __init__(self, site: str, kind: str = "other", fields: Optional[dict] = None):
         self.site = site
+        self.kind = kind
         self.fields = fields or None
         self._t0 = None
 
     def __enter__(self):
-        if enabled:
-            self._t0 = clock()
+        global _next_id
+        if not recording():
+            return self
+        with _lock:
+            self._id = _next_id
+            _next_id += 1
+        self._up = _span_var.get()
+        self._token = _span_var.set((self._id, self._id if self._up is None else self._up[1]))
+        self._base = (_dispatches, _host_syncs) if self.kind == "entry" else None
+        self._ann = None
+        self._t0 = clock()
+        if _prof_seen:
+            stats = {"kind": self.kind, "id": self._id}
+            if self._up is not None:
+                stats["parent"] = self._up[0]
+            rids = _trace_var.get()
+            if rids:
+                stats["rid"] = _stat(rids)
+            if self.fields:
+                for k, v in self.fields.items():
+                    stats[k] = _stat(v)
+            self._ann = _annotation(self.site, **stats)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if self._t0 is None:
             return False
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         t1 = clock()
         dur = t1 - self._t0
+        _span_var.reset(self._token)
         ev = {
             "type": "span",
             "site": self.site,
+            "kind": self.kind,
+            "id": self._id,
+            "parent": None if self._up is None else self._up[0],
+            "root": self._id if self._up is None else self._up[1],
             "ts": self._t0,
             "dur": dur,
             "tid": _tid(),
@@ -471,6 +544,9 @@ class _Span:
             ev["rid"] = list(rids)
         if self.fields:
             ev.update(self.fields)
+        if self._base is not None:
+            ev["launches"] = _dispatches - self._base[0]
+            ev["syncs"] = _host_syncs - self._base[1]
         if exc_type is not None:
             ev["error"] = exc_type.__name__
         with _lock:
@@ -486,30 +562,80 @@ class _Span:
 
     def __call__(self, fn):
         """Decorator form: ``@telemetry.span("site")``.  The wrapper
-        re-checks the flag per call, so decoration at import time with
+        re-checks the switch per call, so decoration at import time with
         telemetry disabled still records once it is enabled."""
-        site, fields = self.site, self.fields
+        site, kind, fields = self.site, self.kind, self.fields
 
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            if not enabled:
+            if not recording():
                 return fn(*args, **kwargs)
-            with _Span(site, fields):
+            with _Span(site, kind, fields):
                 return fn(*args, **kwargs)
 
         wrapper.__telemetry_site__ = site
         return wrapper
 
 
-def span(site: str, **fields) -> _Span:
+def span(site: str, kind: str = "other", **fields) -> _Span:
     """A host-side timing span — use as a ``with`` block or a decorator.
+
+    ``kind`` names the layer boundary the span stands at, one of
+    :data:`KINDS`: ``entry`` (a public call), ``launch`` (a compiled
+    program is issued), ``sync`` (the host waits for a device value),
+    ``comm``, ``io``, ``other``.  Readers select by it, never by a name's
+    prefix.
 
     NOTE: spans are host-side by construction.  Inside a ``jax.jit`` /
     ``shard_map`` / ``ht.fuse``-traced function a span measures *trace*
     time, not run time — spmdlint rule SPMD205 flags that misuse; put
     spans around the eager call site instead.
     """
-    return _Span(site, fields or None)
+    return _Span(site, kind, fields or None)
+
+
+def spanned(site: str, kind: str, fn: Callable, *args):
+    """``fn(*args)``, as a span ``site`` of ``kind`` when recording: the
+    guarded form of ``with span(...)`` for a site that is one call (no span
+    object is made when nothing records)."""
+    if recording():
+        with _Span(site, kind):
+            return fn(*args)
+    return fn(*args)
+
+
+def self_times(spans) -> Dict[int, float]:
+    """Self time of each closed span of ``spans`` (span events, as
+    :func:`events` gives them), by ``id``: its duration less the union of
+    its direct children's intervals, so what two overlapping children cover
+    together is taken off once.  Children are found among ``spans`` alone."""
+    children: Dict[int, List[Tuple[float, float]]] = {}
+    for ev in spans:
+        if ev.get("parent") is not None:
+            children.setdefault(ev["parent"], []).append((ev["ts"], ev["ts"] + ev["dur"]))
+    out = {}
+    for ev in spans:
+        lo, hi = ev["ts"], ev["ts"] + ev["dur"]
+        covered, end = 0.0, lo
+        for a, b in sorted(children.get(ev["id"], ())):
+            a, b = max(a, end), min(b, hi)
+            if b > a:
+                covered += b - a
+                end = b
+        out[ev["id"]] = ev["dur"] - covered
+    return out
+
+
+def profiled_spans() -> Tuple[dict, ...]:
+    """The span events recorded during the most recent profiler trace:
+    those between the position in the event list at which a poll
+    (:func:`recording`, which every span site calls) first saw the profiler
+    on and the one at which a poll, this function's own included, first saw
+    it off again.  Empty where no poll has seen a trace."""
+    recording()
+    with _lock:
+        hi = len(_events) if _prof_hi is None else _prof_hi
+        return tuple(e for e in _events[_prof_lo:hi] if e.get("type") == "span")
 
 
 # --------------------------------------------------------------------- #
@@ -590,6 +716,32 @@ def reset_dispatch_count() -> None:
     global _dispatches
     with _lock:
         _dispatches = 0
+
+
+# --------------------------------------------------------------------- #
+# host-sync counter                                                     #
+# --------------------------------------------------------------------- #
+_host_syncs = 0
+
+
+def host_read(site: str, value, convert: Callable[[Any], Any]):
+    """``convert(value)`` where that blocks until the device has produced
+    ``value`` and copies it to the host (``int``, ``float``,
+    ``np.asarray``): the one way the library reads a device value back.
+    Always counts one host sync (one lock and one add, like the dispatch
+    counter); when recording, the conversion is a span of kind ``sync``
+    named ``site``, whose duration is how long this thread waited."""
+    global _host_syncs
+    with _lock:
+        _host_syncs += 1
+        if enabled:
+            _counters["host_syncs"] = _counters.get("host_syncs", 0) + 1
+    return spanned(site, "sync", convert, value)
+
+
+def host_sync_count() -> int:
+    """Blocking device-to-host reads counted since process start."""
+    return _host_syncs
 
 
 class _DispatchWindow:

@@ -67,14 +67,13 @@ if ! HEAT_CHAOS_SEED="${HEAT_CHAOS_SEED:-0}" python -m pytest tests/test_elastic
 fi
 # telemetry lane: a tier-1 smoke slice with collection armed process-wide
 # (HEAT_TELEMETRY=1) — proves the instrumented hot paths stay green with
-# spans/counters live and archives the event stream + Perfetto trace as
-# CI artifacts (docs/design.md §13)
+# spans/counters live and archives the event stream as a CI artifact
+# (docs/design.md §13)
 tel_dir="${HEAT_TELEMETRY_ARTIFACT_DIR:-/tmp/heat-telemetry-artifacts}"
 mkdir -p "$tel_dir"
 echo "=== telemetry lane (HEAT_TELEMETRY=1 smoke; artifacts in $tel_dir) ==="
 if ! HEAT_TELEMETRY=1 \
      HEAT_TELEMETRY_JSONL="$tel_dir/events.jsonl" \
-     HEAT_TELEMETRY_TRACE="$tel_dir/trace.json" \
      python -m pytest tests/test_telemetry.py tests/test_fuse.py \
          tests/test_compressed_collectives.py tests/test_compile_cache.py -q; then
     echo "FAILED telemetry lane"

@@ -9,7 +9,8 @@ The load-bearing assertions:
   (dyadic values) across threads;
 - **one id, walkable everywhere**: a request id handed to
   ``ServeEngine.submit`` comes back on the ``Reply``, tags the
-  ``serve:batch`` span, lands in the Perfetto export as ``args.rid``,
+  ``serve:batch`` span, lands in the profiler's trace as that event's
+  ``rid`` stat,
   and sits in the flight-recorder ring of the postmortem dump;
 - **overhead contract**: toggling observability never retraces, a
   disabled site records nothing, and serve p99 with full obs (events +
@@ -43,7 +44,7 @@ import heat_tpu as ht
 from heat_tpu import telemetry
 from heat_tpu.resilience import incidents
 from heat_tpu.serve import ModelRegistry, ServeEngine, loadgen
-from heat_tpu.telemetry import SloMonitor, _core, export, flight
+from heat_tpu.telemetry import SloMonitor, _core, flight
 from heat_tpu.telemetry.hist import Histogram
 from heat_tpu.telemetry.httpz import (
     MetricsServer,
@@ -303,7 +304,7 @@ def test_trace_ctx_without_telemetry_still_tracks_ids():
 
 
 # --------------------------------------------------------------------- #
-# the end-to-end id walk: reply -> span -> Perfetto -> flight dump      #
+# the end-to-end id walk: reply -> span -> profiler trace -> flight dump #
 # --------------------------------------------------------------------- #
 def test_request_id_walkable_reply_span_perfetto_flight(
     registry, det_tel, clean_flight, tmp_path
@@ -311,8 +312,10 @@ def test_request_id_walkable_reply_span_perfetto_flight(
     flight.set_dump_dir(str(tmp_path / "dumps"))
     incidents.clear_incident_log()
     eng = ServeEngine(registry, max_batch_rows=32, min_bucket=8)
-    trace_path = str(tmp_path / "trace.json")
-    export.start_trace(trace_path)
+    import jax
+
+    trace_dir = str(tmp_path / "trace")
+    jax.profiler.start_trace(trace_dir)
     try:
         good = payload(3, seed=1)
         bad = payload(2, seed=2)
@@ -322,7 +325,7 @@ def test_request_id_walkable_reply_span_perfetto_flight(
         eng.flush()
         r1, r2 = f1.result(), f2.result()
     finally:
-        path = export.stop_trace()
+        jax.profiler.stop_trace()
         eng.close()
 
     # 1. the reply carries the id back to the caller
@@ -339,14 +342,19 @@ def test_request_id_walkable_reply_span_perfetto_flight(
     )
     assert any("rq-poison" in e.get("rid", ()) for e in spans)
 
-    # 3. the Perfetto export carries the same ids under args.rid
-    with open(path) as fh:
-        doc = json.load(fh)
+    # 3. the profiler's own trace (the one timeline) carries the same id
+    #    as the ``rid`` stat of the ``serve:batch`` event
+    import glob
+
+    (pb,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
     rid_events = [
-        e for e in doc["traceEvents"]
-        if "rq-good" in (e.get("args", {}).get("rid") or [])
+        ev
+        for plane in jax.profiler.ProfileData.from_file(pb).planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name == "serve:batch" and "rq-good" in str(dict(ev.stats).get("rid", ""))
     ]
-    assert rid_events, "no Perfetto event tagged with the request id"
+    assert rid_events, "no serve:batch event in the profiler trace tagged with the request id"
 
     # 4. the poisoned request produced an incident, and the postmortem's
     #    ring contains events tagged with its id
@@ -668,16 +676,19 @@ def test_loadgen_percentiles_match_exact_within_bucket_error():
 # --------------------------------------------------------------------- #
 # the overhead contract                                                 #
 # --------------------------------------------------------------------- #
-def test_obs_toggles_and_trace_ctx_never_retrace():
+def test_obs_toggles_and_trace_ctx_never_retrace(tmp_path):
     """Full observability around an op — enabled telemetry, an active
     trace_ctx, histogram observations — adds ZERO compile-cache entries:
     nothing obs-related may reach a cache key."""
     from heat_tpu.core import _compile
 
+    import jax
+
     was = _core.is_enabled()
     x = ht.arange(8, split=0)
     (x + 2).larray.block_until_ready()  # populate the cache
     n0 = _compile.cache_size()
+    traces0 = {k: f.jitted._cache_size() for k, f in _compile._CACHE.items()}
     try:
         telemetry.enable()
         with telemetry.trace_ctx("rq-cache"):
@@ -685,7 +696,16 @@ def test_obs_toggles_and_trace_ctx_never_retrace():
             (x + 2).larray.block_until_ready()
         telemetry.disable()
         (x + 2).larray.block_until_ready()
+        # a profiler trace switches recording on by itself; the request
+        # context rides into the span's stats, not into any key
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with telemetry.trace_ctx("rq-cache"):
+                (x + 2).larray.block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
         assert _compile.cache_size() == n0
+        assert {k: f.jitted._cache_size() for k, f in _compile._CACHE.items()} == traces0
     finally:
         if was:
             telemetry.enable()

@@ -4,9 +4,9 @@ The suite pins the two halves of the observability contract:
 
 * enabled, the registry reproduces ground truth — span aggregates match
   the nesting structure, the wire-byte ledger matches the hand-derived
-  ring arithmetic of docs/design.md at every mesh size, the Perfetto
-  export is loadable trace-event JSON, and deterministic mode makes two
-  identical runs bitwise-equal;
+  ring arithmetic of docs/design.md at every mesh size, and deterministic
+  mode makes two identical runs bitwise-equal (the one timeline, spans in
+  the profiler's trace, is tests/test_telemetry_spans.py's);
 * disabled, telemetry is invisible — ``snapshot()`` is empty, zero
   events record, no compile-cache keys change, and the tier-1
   dispatch-count gates keep their exact values (asserted indirectly by
@@ -141,7 +141,7 @@ def test_disabled_records_nothing():
             telemetry.enable()
 
 
-def test_toggling_telemetry_never_changes_cache_keys():
+def test_toggling_telemetry_never_changes_cache_keys(tmp_path):
     """Enabling telemetry must not register a key context or retrace:
     the same op replayed across toggles adds zero cache entries."""
     from heat_tpu.core import _compile
@@ -150,12 +150,24 @@ def test_toggling_telemetry_never_changes_cache_keys():
     x = ht.arange(8, split=0)
     (x + 1).larray.block_until_ready()  # populate the cache
     n0 = _compile.cache_size()
+    traces0 = {k: f.jitted._cache_size() for k, f in _compile._CACHE.items()}
     try:
         telemetry.enable()
         (x + 1).larray.block_until_ready()
         telemetry.disable()
         (x + 1).larray.block_until_ready()
+        # the other switch: a profiler trace turns recording on and off
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert telemetry.recording()
+            (x + 1).larray.block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        assert not telemetry.recording()
+        (x + 1).larray.block_until_ready()
         assert _compile.cache_size() == n0
+        # and no entry was traced again (jit's own cache of traces)
+        assert {k: f.jitted._cache_size() for k, f in _compile._CACHE.items()} == traces0
     finally:
         if was:
             telemetry.enable()
@@ -315,18 +327,24 @@ def test_exact_allreduce_accounts_f32_bytes(tel):
 # --------------------------------------------------------------------- #
 # compile-cache observability                                            #
 # --------------------------------------------------------------------- #
-def test_compile_miss_records_staged_timings(tel):
+def test_first_call_is_the_same_launch_span_marked_miss(tel):
+    """A first call is no other code path: the same ``launch`` span with
+    ``miss=True`` (its duration holds trace, lower and compile), and the
+    jit function is traced exactly once however often recording toggles."""
     from heat_tpu.core._compile import jitted
 
     def make():
-        return jax.jit(lambda a: a * 3)
+        return lambda a: a * 3
 
     fn = jitted(("telemetry-test-miss", 0), make)
     fn(jnp.ones((4,), jnp.float32)).block_until_ready()
-    compiles = [e for e in telemetry.events() if e["type"] == "compile"]
-    assert compiles and compiles[-1]["site"] == "telemetry-test-miss"
-    assert compiles[-1]["trace_lower_s"] >= 0.0
-    assert compiles[-1]["compile_s"] >= 0.0
+    fn(jnp.ones((4,), jnp.float32)).block_until_ready()
+    first, second = [e for e in telemetry.events() if e["site"] == "jitted:telemetry-test-miss"]
+    assert first["kind"] == second["kind"] == "launch"
+    assert first["miss"] is True and "miss" not in second
+    assert first["dur"] >= second["dur"] >= 0.0
+    assert not [e for e in telemetry.events() if e["type"] == "compile"]
+    assert fn.jitted._cache_size() == 1  # compiled once, by jit itself
     c = telemetry.snapshot()["counters"]
     assert c["compile.cache.misses"] >= 1
     # a second jitted() lookup of the same key is a hit, not a miss
@@ -339,61 +357,6 @@ def test_compile_miss_records_staged_timings(tel):
 # --------------------------------------------------------------------- #
 # exporters                                                              #
 # --------------------------------------------------------------------- #
-@pytest.fixture
-def own_trace():
-    """Exclusive use of the (single) trace collector: parks an active
-    env-armed trace (the HEAT_TELEMETRY_TRACE CI lane) and resumes it
-    into the same path afterwards."""
-    from heat_tpu.telemetry import export
-
-    parked = export._trace_path
-    if parked is not None:
-        export.stop_trace()
-    yield export
-    if export.trace_active():
-        export.stop_trace()
-    if parked is not None:
-        export.start_trace(parked)
-
-
-def test_perfetto_export_is_valid_trace_json(tmp_path, tel, own_trace):
-    path = str(tmp_path / "trace.json")
-    export = own_trace
-
-    export.start_trace(path)
-    try:
-        with telemetry.span("traced", mode="x"):
-            pass
-        telemetry.record_event("incident", site="guard")
-        telemetry.gauge("live", 2.0)
-    finally:
-        out = export.stop_trace()
-    assert out == path
-    with open(path) as f:
-        doc = json.load(f)
-    evs = doc["traceEvents"]
-    assert len(evs) == 3
-    for ev in evs:
-        assert {"ph", "ts", "name"} <= set(ev)
-        assert ev["pid"] == os.getpid()
-    span_ev = next(e for e in evs if e["ph"] == "X")
-    assert span_ev["name"] == "traced" and span_ev["args"]["mode"] == "x"
-    assert any(e["ph"] == "i" and e["name"] == "guard" for e in evs)
-    counter = next(e for e in evs if e["ph"] == "C")
-    assert counter["name"] == "live" and counter["args"]["value"] == 2.0
-
-
-def test_start_trace_twice_raises(tmp_path, tel, own_trace):
-    export = own_trace
-    export.start_trace(str(tmp_path / "a.json"))
-    try:
-        with pytest.raises(RuntimeError, match="already"):
-            export.start_trace(str(tmp_path / "b.json"))
-    finally:
-        export.stop_trace()
-    assert export.stop_trace() is None
-
-
 def test_jsonl_sink_streams_events(tmp_path, tel):
     path = str(tmp_path / "events.jsonl")
     telemetry.set_jsonl(path)
@@ -430,6 +393,9 @@ def test_deterministic_mode_is_bitwise_replayable(det_tel):
     # (span events append at EXIT, so b's event precedes a's)
     assert [e["ts"] for e in first] == [1.0, 0.0, 4.0]
     assert [e["site"] for e in first] == ["b", "a", "guard"]
+    # ids are a counter that reset() rewinds: the same in both runs
+    assert [(e["id"], e["parent"], e["root"]) for e in first[:2]] == [(1, 0, 0), (0, None, 0)]
+    assert [e.get("id") for e in second] == [e.get("id") for e in first]
 
 
 def test_incident_log_uses_injectable_telemetry_clock(tel):

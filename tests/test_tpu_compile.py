@@ -8,6 +8,10 @@ a slice off the tiling.  These tests ask it for every kernel ``chip_smoke.py``
 runs, at the widths it runs them — about two seconds each, no chip time — so a
 later change that the chip would refuse fails here first.
 
+The benchmark's own programs (the Lloyd segment, the finalize, k-means++,
+the exact-form cdist) are compiled too, at the cells' shapes, for the stable
+names their phases carry into the compiled modules (``jax.named_scope``).
+
 Nothing runs and nothing is timed: a compile that passes is not a chip run.
 
 All of them live in this one file and take the topology from a module-scoped
@@ -163,3 +167,97 @@ def test_svd_chain_compiles_when_lowered_with_x64_off(one_chip, topo):
     with jax.enable_x64(False):
         compiled = jax.jit(chain).lower(x).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
+# --------------------------------------------------------------------- #
+# the benchmark cells' programs, and the names their phases carry        #
+# --------------------------------------------------------------------- #
+CELL_F, CELL_K = 6_291_456, 8  # one flattened Cityscapes image a row, 8 clusters
+CELL_ROWS = {"one_chip": 300, "four_chips": 448}  # kmeans_300_c1, kmeans_448_c4
+
+
+def _op_names(compiled) -> set:
+    """Every ``op_name`` of the compiled module's instructions' metadata."""
+    import re
+
+    return set(re.findall(r'op_name="([^"]+)"', compiled.as_text()))
+
+
+def _assert_scopes(compiled, module: str, scopes) -> None:
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule {module}"), text[:80]
+    names = _op_names(compiled)
+    for scope in scopes:
+        assert any(f"/{scope}/" in n for n in names), f"{scope} is in no op_name of {module}"
+
+
+@pytest.fixture(params=["one_chip", "four_chips"])
+def cell(request, topo):
+    """``(rows, sharding of X, sharding of everything else, rep_sh for
+    k-means++)`` of the two KMeans cells: X on one chip, or row-sharded over
+    the described 2x2 with the small operands replicated."""
+    if request.param == "one_chip":
+        one = SingleDeviceSharding(topo.devices[0])
+        return CELL_ROWS["one_chip"], one, one, None
+    comm = ht.XlaCommunication(topo.devices)
+    return (
+        CELL_ROWS["four_chips"], comm.sharding(2, 0), NamedSharding(comm.mesh, PartitionSpec()),
+        comm.sharding(1, None),
+    )
+
+
+def _shape(shape, sharding, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_kmeans_fit_segment_compiles_with_its_sweep_scopes(cell):
+    from heat_tpu.cluster.kmeans import KMeans
+
+    rows, xs, rep, _ = cell
+    carry = (_shape((), rep, jnp.int32), _shape((CELL_K, CELL_F), rep), _shape((), rep))
+    compiled = KMeans._fit_segment.lower(
+        _shape((rows, CELL_F), xs), _shape((), rep), _shape((), rep, jnp.int32), carry
+    ).compile()
+    _assert_scopes(compiled, "jit__fit_segment", ["kmeans.sweep.assign", "kmeans.sweep.update"])
+    if xs is not rep:  # the per-sweep exchange of the centre sums exists only across chips
+        assert "all-reduce" in compiled.as_text()
+
+
+def test_kmeans_finalize_compiles_with_its_scope(cell):
+    from heat_tpu.cluster.kmeans import KMeans
+
+    rows, xs, rep, _ = cell
+    compiled = KMeans._finalize.lower(
+        _shape((rows, CELL_F), xs), _shape((CELL_K, CELL_F), rep)
+    ).compile()
+    _assert_scopes(compiled, "jit__finalize", ["kmeans.finalize"])
+
+
+def test_kmeanspp_compiles_with_its_scopes(cell):
+    from heat_tpu.cluster._kcluster import _kmeanspp
+
+    rows, xs, rep, rep_sh = cell
+    compiled = _kmeanspp.lower(
+        _shape((rows, CELL_F), xs), _shape((), rep, jnp.int32), _shape((CELL_K,), rep),
+        rep_sh=rep_sh,
+    ).compile()
+    _assert_scopes(compiled, "jit__kmeanspp", ["kmeanspp.distance", "kmeanspp.sample"])
+    # fits beside the data on a 16 GB chip (the four-chip cell is sized by this program)
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes < 16 << 30
+
+
+def test_cdist_compiles_under_its_sites_name_with_its_scope(one_chip):
+    """``cdist_40k_c1``: the ``jitted`` entry is named after its key's site,
+    so the device's ``XLA Modules`` line reads ``jit_dist.euclidean`` where
+    it read ``jit__lambda_``."""
+    from heat_tpu.core._compile import jitted
+    from heat_tpu.spatial import distance
+
+    x = _shape((40_000, 18), one_chip)
+    for quadratic, scope in ((False, "cdist.exact"), (True, "cdist.quadratic")):
+        fn = jitted(
+            ("dist.euclidean", quadratic),
+            lambda: lambda a, b: distance._euclidean(a, b, quadratic),
+        )
+        _assert_scopes(fn.lower(x, x).compile(), "jit_dist.euclidean", [scope])
