@@ -3,8 +3,10 @@
 Reference: heat/cluster/_kcluster.py:4-249 — centroid initialization
 (uniform sampling or k-means++/probability-based), cluster assignment via
 the distance metric, and the fit/predict skeleton.  The reference's
-per-sample owner-rank ``Bcast`` during init (:104-113) is plain global
-indexing here.
+per-sample owner-rank ``Bcast`` during init (:104-113) is
+:func:`~heat_tpu.core.communication.fetch_row` where the rows lie evenly
+over the mesh (the owner answers, one all-reduce of a single row) and
+plain global indexing everywhere else.
 """
 
 from __future__ import annotations
@@ -19,6 +21,7 @@ from ..core import factories, random, types
 from ..core._compile import launch
 from ..core._split_semantics import split_semantics as _split_semantics
 from ..core.base import BaseEstimator, ClusteringMixin
+from ..core.communication import fetch_row
 from ..core.dndarray import DNDarray
 from ..core.fuse import fuse
 from ..telemetry import _core as _tel
@@ -26,6 +29,7 @@ from ..telemetry import _core as _tel
 __all__ = ["_KCluster"]
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec
 
 
 def _quadratic_cdist(x, y):
@@ -47,28 +51,56 @@ def _assign_program(x: DNDarray, centers: DNDarray, metric: Callable) -> DNDarra
 _fused_assign = fuse(_assign_program)
 
 
-@partial(jax.jit, static_argnames=("rep_sh",))
-def _kmeanspp(arr, first, us, rep_sh=None):
+def _rows_evenly_sharded(x: DNDarray) -> bool:
+    """Whether ``x``'s rows lie over the mesh in equal blocks: the layout
+    the explicit ``shard_map`` forms (the row fetch of k-means++, KMeans'
+    quantized ring) are written for.  Everything else (one device,
+    ``split=None``, ``split=1``, a ragged ``n`` whose ``larray`` GSPMD
+    keeps replicated) can read any row locally."""
+    return x.split == 0 and x.comm.size > 1 and x.shape[0] % x.comm.size == 0
+
+
+@partial(jax.jit, static_argnames=("rows_sh",))
+def _kmeanspp(arr, first, us, rows_sh=None):
     """The ENTIRE k-means++ draw sequence as one compiled ``fori_loop``:
     each step folds the newest center into the running min-distance vector
-    and samples the next row index from the d² CDF with a dynamic gather —
-    zero host syncs and ONE compilation for all k draws.  (A per-draw
-    formulation with ``arr[int(idx)]`` on the host recompiles the gather
-    for every distinct index — measured ~1 s/draw on a 2-device mesh,
-    dwarfing the fused fit loop it feeds.)  ``us`` is the (k,) uniform
-    draw vector; its static length sets the number of centers.
+    and samples the next row index from the d² CDF, then reads that one
+    row — zero host syncs and ONE compilation for all k draws.  (A
+    per-draw formulation with ``arr[int(idx)]`` on the host recompiles the
+    gather for every distinct index — measured ~1 s/draw on a 2-device
+    mesh, dwarfing the fused fit loop it feeds.)  ``us`` is the (k,)
+    uniform draw vector; its static length sets the number of centers.
 
-    ``rep_sh`` (a replicated NamedSharding, hashable → static) pins the
-    (n,) min-distance vector to every device: the distance pass still runs
-    row-sharded, but the cumsum/searchsorted sampling runs on a local
-    replica — a prefix scan along a SHARDED axis is pathological under
-    GSPMD (measured 1000 ms vs 4 ms for the sharded distance pass on a
-    2-device 100k-row mesh; replicating the 400 KB vector costs ~nothing
-    and takes the whole init from 6.8 s to 46 ms)."""
+    ``rows_sh`` (a NamedSharding, hashable → static; None on one device)
+    is the sharding of an (n,) vector laid out like ``arr``'s rows.  It
+    carries the mesh and decides two things:
+
+    * the (n,) min-distance vector is pinned to every device: the distance
+      pass still runs row-sharded, but the cumsum/searchsorted sampling
+      runs on a local replica — a prefix scan along a SHARDED axis is
+      pathological under GSPMD (measured 1000 ms vs 4 ms for the sharded
+      distance pass on a 2-device 100k-row mesh; replicating the 400 KB
+      vector costs ~nothing and takes the whole init from 6.8 s to 46 ms);
+    * where it shards the rows (``comm.sharding(1, 0)``, see
+      :func:`_rows_evenly_sharded`), the drawn row comes from its owner
+      (:func:`fetch_row`: f elements on the wire).  ``arr[idx]`` there
+      makes GSPMD all-gather ALL of ``arr`` onto every device per draw
+      (measured 99 ms x 8 of a 1147 ms fit on four chips, and the compiler
+      refuses the source's 1200 rows).  Where it does not
+      (``comm.sharding(1, None)``), and on one device, the read is the
+      plain dynamic slice."""
     n, k = arr.shape[0], us.shape[0]
+    rep_sh = None if rows_sh is None else NamedSharding(rows_sh.mesh, PartitionSpec())
+    by_owner = rows_sh is not None and len(rows_sh.spec) > 0
 
     def rep(v):
         return jax.lax.with_sharding_constraint(v, rep_sh) if rep_sh is not None else v
+
+    def row(i):
+        if not by_owner:
+            return arr[i]
+        with jax.named_scope("kmeanspp.fetch"):
+            return fetch_row(arr, i, rows_sh)
 
     def body(i, state):
         dmin, centers = state
@@ -80,9 +112,9 @@ def _kmeanspp(arr, first, us, rep_sh=None):
             total = cdf[-1]
             draw = us[i] * jnp.where(total > 0, total, 1.0)
             idx = jnp.clip(jnp.searchsorted(cdf, draw), 0, n - 1)
-            return dmin, centers.at[i].set(arr[idx])
+            return dmin, centers.at[i].set(row(idx))
 
-    centers0 = jnp.zeros((k, arr.shape[1]), arr.dtype).at[0].set(arr[first])
+    centers0 = jnp.zeros((k, arr.shape[1]), arr.dtype).at[0].set(row(first))
     dmin0 = rep(jnp.full((n,), jnp.inf, dtype=arr.dtype))
     _, centers = jax.lax.fori_loop(1, k, body, (dmin0, centers0))
     return centers
@@ -206,10 +238,24 @@ class _KCluster(ClusteringMixin, BaseEstimator):
             us = random.rand(
                 self.n_clusters, device=x.device, comm=x.comm
             ).larray.astype(jnp.float32)
-            rep_sh = x.comm.sharding(1, None) if x.comm.size > 1 else None
+            by_owner = _rows_evenly_sharded(x)
+            rows_sh = (
+                x.comm.sharding(1, 0 if by_owner else None) if x.comm.size > 1 else None
+            )
             carr = launch(
-                "jit:kmeans.kmeanspp", _kmeanspp, (arr, first, us), {"rep_sh": rep_sh}
+                "jit:kmeans.kmeanspp", _kmeanspp, (arr, first, us), {"rows_sh": rows_sh},
+                row_fetch="owner_psum" if by_owner else "local",
             ).astype(x.dtype.jax_type())
+            if by_owner and _tel.enabled:
+                from ..comm.compressed import _account_wire
+
+                # the row fetches run INSIDE the compiled program (one
+                # all-reduce of f float32 per centre), out of sight of the
+                # host-level accounting: credited here, as KMeans.fit
+                # credits its quantized ring
+                _account_wire(
+                    "allreduce", None, arr.shape[1], x.comm.size, reps=self.n_clusters
+                )
             self._cluster_centers = DNDarray(
                 x.comm.apply_sharding(carr, None),
                 (self.n_clusters, x.shape[1]),

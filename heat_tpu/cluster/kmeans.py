@@ -24,7 +24,7 @@ from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
 from ..spatial import distance
 from ..telemetry import _core as _tel
-from ._kcluster import _KCluster, _quadratic_cdist
+from ._kcluster import _KCluster, _quadratic_cdist, _rows_evenly_sharded
 
 __all__ = ["KMeans"]
 
@@ -178,7 +178,7 @@ class KMeans(_KCluster):
         k = self.n_clusters
 
         mode = None
-        if x.split == 0 and comm.size > 1 and n % comm.size == 0:
+        if _rows_evenly_sharded(x):
             from ..comm import compressed as _cq
 
             # collective-precision policy: the per-iteration (k, f)

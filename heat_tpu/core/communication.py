@@ -1047,6 +1047,38 @@ class XlaCommunication(Communication):
         return self.scan(array, op=op, exclusive=True)
 
 
+def fetch_row(arr: jax.Array, idx: jax.Array, rows_sh: NamedSharding) -> jax.Array:
+    """``arr[idx]`` for a traced row number ``idx`` in ``[0, n)`` of an
+    ``(n, f)`` array whose rows lie evenly over one mesh axis, answered by
+    the row's owner (the reference's per-sample owner-rank ``Bcast``,
+    heat/cluster/_kcluster.py:104-113).  For use INSIDE a traced program.
+
+    ``rows_sh`` is the sharding of a length-``n`` vector laid out like
+    ``arr``'s rows (``comm.sharding(1, 0)``): it names the mesh and the
+    axis.  GSPMD answers ``arr[idx]`` on such an operand by replicating
+    ALL of ``arr`` (a data-dependent slice along a sharded axis); here
+    every position slices its own block at the clamped local row, the
+    owner alone keeps it, and one ``psum`` of ``f`` elements replicates
+    it.  ``where``, not a multiply by a mask: an ``inf``/``nan`` in
+    another position's clamped row must not leak.  The sum is the owner's
+    row plus zeros: bitwise the row, save that a ``-0.0`` reads ``+0.0``."""
+    mesh, axis = rows_sh.mesh, rows_sh.spec[0]
+    w = arr.shape[0] // mesh.shape[axis]
+
+    def kernel(block, i):
+        lo = jax.lax.axis_index(axis) * w
+        row = jax.lax.dynamic_slice_in_dim(block, jnp.clip(i - lo, 0, w - 1), 1)[0]
+        mine = (i >= lo) & (i < lo + w)
+        return jax.lax.psum(jnp.where(mine, row, jnp.zeros_like(row)), axis)
+
+    return shard_map(
+        kernel,
+        mesh=mesh,
+        in_specs=(PartitionSpec(axis, None), PartitionSpec()),
+        out_specs=PartitionSpec(),
+    )(arr, idx)
+
+
 def _constrained_copy(array: jax.Array, sh: NamedSharding) -> jax.Array:
     """Best-effort reshard for non-divisible shapes via a compiled
     with_sharding_constraint.

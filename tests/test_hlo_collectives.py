@@ -111,3 +111,21 @@ def test_public_resplit_collective_count(mesh):
     Y = X.resplit(1)
     assert Y.split == 1
     np.testing.assert_array_equal(Y.numpy(), a)
+
+
+def test_sharded_kmeanspp_fetches_one_row_and_gathers_no_operand(mesh):
+    """k-means++ on a row-sharded operand reads each drawn row from its
+    owner: an all-reduce of one ``f32[f]`` row a draw, and no all-gather of
+    anything shaped like the operand (``arr[idx]`` there replicates ALL of
+    it, once a draw).  The only thing replicated is the (n,) distance
+    vector."""
+    from heat_tpu.cluster._kcluster import _kmeanspp
+
+    m, f = _dims()
+    x = jax.device_put(jnp.zeros((m, f), jnp.float32), _sharding(mesh, "x", None))
+    hlo = _kmeanspp.lower(
+        x, jnp.int32(0), jnp.zeros((4,), jnp.float32), rows_sh=_sharding(mesh, "x")
+    ).compile().as_text()
+    assert f"f32[{f}]" in {s.split("{")[0] for s in re.findall(r"(\S+)\s+all-reduce", hlo)}, hlo[-2000:]
+    for shape in _all_gather_shapes(hlo):
+        assert f"{m},{f}" not in shape and f"{m // jax.device_count()},{f}" not in shape, shape

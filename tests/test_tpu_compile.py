@@ -24,6 +24,8 @@ branch here, so each test steers them onto the chip's branch itself.
 from __future__ import annotations
 
 import importlib
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -178,8 +180,6 @@ CELL_ROWS = {"one_chip": 300, "four_chips": 448}  # kmeans_300_c1, kmeans_448_c4
 
 def _op_names(compiled) -> set:
     """Every ``op_name`` of the compiled module's instructions' metadata."""
-    import re
-
     return set(re.findall(r'op_name="([^"]+)"', compiled.as_text()))
 
 
@@ -193,7 +193,7 @@ def _assert_scopes(compiled, module: str, scopes) -> None:
 
 @pytest.fixture(params=["one_chip", "four_chips"])
 def cell(request, topo):
-    """``(rows, sharding of X, sharding of everything else, rep_sh for
+    """``(rows, sharding of X, sharding of everything else, rows_sh for
     k-means++)`` of the two KMeans cells: X on one chip, or row-sharded over
     the described 2x2 with the small operands replicated."""
     if request.param == "one_chip":
@@ -202,7 +202,7 @@ def cell(request, topo):
     comm = ht.XlaCommunication(topo.devices)
     return (
         CELL_ROWS["four_chips"], comm.sharding(2, 0), NamedSharding(comm.mesh, PartitionSpec()),
-        comm.sharding(1, None),
+        comm.sharding(1, 0),
     )
 
 
@@ -233,18 +233,50 @@ def test_kmeans_finalize_compiles_with_its_scope(cell):
     _assert_scopes(compiled, "jit__finalize", ["kmeans.finalize"])
 
 
-def test_kmeanspp_compiles_with_its_scopes(cell):
+def _compiled_kmeanspp(rows, xs, rep, rows_sh):
     from heat_tpu.cluster._kcluster import _kmeanspp
 
-    rows, xs, rep, rep_sh = cell
-    compiled = _kmeanspp.lower(
+    return _kmeanspp.lower(
         _shape((rows, CELL_F), xs), _shape((), rep, jnp.int32), _shape((CELL_K,), rep),
-        rep_sh=rep_sh,
+        rows_sh=rows_sh,
     ).compile()
+
+
+_COLLECTIVE = r"(all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)"
+
+
+def test_kmeanspp_compiles_with_its_scopes(cell):
+    rows, xs, rep, rows_sh = cell
+    compiled = _compiled_kmeanspp(rows, xs, rep, rows_sh)
     _assert_scopes(compiled, "jit__kmeanspp", ["kmeanspp.distance", "kmeanspp.sample"])
-    # fits beside the data on a 16 GB chip (the four-chip cell is sized by this program)
-    m = compiled.memory_analysis()
+    text, m = compiled.as_text(), compiled.memory_analysis()
+    # fits beside the data on a 16 GB chip
     assert m.temp_size_in_bytes + m.argument_size_in_bytes < 16 << 30
+    if rows_sh is None:
+        # one chip reads the drawn row where it lies, and talks to nobody
+        assert "dynamic-slice(" in text
+        assert not re.findall(_COLLECTIVE + r"(-start)?\(", text)
+        return
+    # across chips the owner answers (``arr[idx]`` here all-gathered the whole
+    # of X, 11.3 GB of scratch a chip, once a draw): nothing larger than the
+    # (n,) distance vector is gathered, and the scratch is gone
+    _assert_scopes(compiled, "jit__kmeanspp", ["kmeanspp.fetch"])
+    for dims in re.findall(r"f32\[([\d,]*)\]\S*\s+all-gather", text):
+        assert 4 * math.prod(int(d) for d in dims.split(",") if d) < 1 << 20, dims
+    assert f"f32[{CELL_F}]" in {s.split("{")[0] for s in re.findall(r"(\S+)\s+all-reduce", text)}
+    assert m.temp_size_in_bytes < 1 << 30
+
+
+def test_kmeanspp_compiles_at_the_sources_1200_rows_on_four_chips(four_chips):
+    """The weak-scaling arm's own size at four processes, 300 images a chip
+    (``kmeans_448_c4`` holds 448 because the all-gather of X, 30.6 GB at this
+    size, was refused): what a ``benchmark`` PR needs to grow the cell."""
+    rep = NamedSharding(four_chips.mesh, PartitionSpec())
+    m = _compiled_kmeanspp(
+        1200, four_chips.sharding(2, 0), rep, four_chips.sharding(1, 0)
+    ).memory_analysis()
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes < 16 << 30
+    assert m.temp_size_in_bytes < 1 << 30
 
 
 def test_cdist_compiles_under_its_sites_name_with_its_scope(one_chip):
