@@ -49,6 +49,7 @@ FULL = {
     "moments": dict(n=8_000_000, f=32),
     "kmeans": dict(k=8, iters=30, n_ref=1_000_000),
     "cdist": dict(n=40_000, f=18, block=512),
+    "spectral": dict(n=8_192, f=18, k=8, m=300),
     "lasso": dict(n=10_000_000, f=32, sweeps=10),
     "qr_svd": dict(m=4_194_304, n=64),
     "attention": dict(S=4096, H=16, D=64),
@@ -313,6 +314,48 @@ def phase_cdist(seed: int, n: int, f: int, block: int):
         "reference": f"numpy float64 on rows [{lo}, {lo + block}) of the result "
                      f"(seeded block: the full result is {n * n * 4 / 1e9:.1f} GB)",
         "checks": checks, "device_dtypes": device_dtypes(),
+    }
+    return line, None
+
+
+def _spectral_limits() -> dict:
+    """The limits of the benchmark's cell ``spectral_40k_c1`` (PERF.md
+    section 2), which the smoke holds a smaller fit to."""
+    with open(os.path.join(ROOT, "perf", "workloads", "spectral_40k_c1.json")) as fh:
+        return json.load(fh)["limits"]
+
+
+def phase_spectral(seed: int, n: int, f: int, k: int, m: int):
+    """``ht.cluster.Spectral`` at a reduced n (the benchmark's cell holds the
+    full 40 000 rows) against the benchmark's plain reference, by the cell's
+    own five numbers and limits."""
+    import importlib.util
+
+    import jax.numpy as jnp
+
+    import heat_tpu as ht
+
+    spec = importlib.util.spec_from_file_location(
+        "spectral_plain", os.path.join(ROOT, "perf", "references", "spectral_plain.py")
+    )
+    plain = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(plain)
+
+    rng = np.random.default_rng(seed + 9)
+    centres = 0.35 * rng.standard_normal((k, f))
+    host = (centres[np.arange(n) % k] + 0.15 * rng.standard_normal((n, f))).astype(np.float32)
+    X = ht.array(host, split=0)
+    sp = ht.cluster.Spectral(n_clusters=k, gamma=1.0, n_lanczos=m).fit(X)
+    out = {"labels": sp.labels_.larray, "embedding": sp.embedding_.larray, "eigenvalues": sp.eigenvalues_}
+    numbers = plain.judge(jnp.asarray(host), out, k, 1.0, m)
+    plain.forget()
+    limits = _spectral_limits()
+    line = {
+        "sizes": {"rows": n, "features": f, "clusters": k, "n_lanczos": m, "laplacian_bytes": n * n * 4},
+        "reference": "perf/references/spectral_plain.py (jax.numpy float32 at highest, exact-form "
+                     "similarity, Python Lanczos loop), by the five numbers and limits of spectral_40k_c1",
+        "checks": {name: check(numbers[name], limits[name]) for name in sorted(limits)},
+        "device_dtypes": device_dtypes(),
     }
     return line, None
 
@@ -821,7 +864,7 @@ def run_one_chip(counter, seed: int) -> None:
     X = timed(counter, "moments", phase_moments, seed, **FULL["moments"])
     km = timed(counter, "kmeans", phase_kmeans, X, seed, **FULL["kmeans"])
     del X
-    for name, fn in (("cdist", phase_cdist), ("lasso", phase_lasso),
+    for name, fn in (("cdist", phase_cdist), ("spectral", phase_spectral), ("lasso", phase_lasso),
                      ("qr_svd", phase_qr_svd), ("attention", phase_attention),
                      ("io", phase_io)):
         timed(counter, name, fn, seed, **FULL[name])

@@ -8,18 +8,22 @@ spectral-gap heuristic choosing k when unspecified (:98-165).
 
 from __future__ import annotations
 
-from typing import Optional, Union
+import functools
+from typing import Optional
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ..core import types
+from ..core._compile import jitted
 from ..core.base import BaseEstimator, ClusteringMixin
 from ..core.dndarray import DNDarray
-from ..core.linalg import solver
+from ..core.linalg import basics, solver
 from ..core.sanitation import sanitize_in
 from ..graph import Laplacian
 from ..spatial import distance
+from ..telemetry import _core as _tel
 from .kmeans import KMeans
 
 __all__ = ["Spectral"]
@@ -71,20 +75,40 @@ class Spectral(ClusteringMixin, BaseEstimator):
         )
         self._labels = None
         self._cluster_centers = None
+        self._eigenvalues = None
+        self._embedding = None
 
     def _checkpoint_attrs(self):
         # the fitted KMeans nests recursively; _laplacian is rebuilt by
         # __init__ from the constructor params
-        return ["_labels", "_cluster_centers", "_kmeans", "_embedding_dim"]
+        return [
+            "_labels", "_cluster_centers", "_kmeans", "_embedding_dim",
+            "_eigenvalues", "_embedding",
+        ]
 
     @property
     def labels_(self):
         return self._labels
 
-    def _spectral_embedding(self, x: DNDarray):
-        """Eigenvector embedding of the Laplacian
-        (reference spectral.py:98-137): lanczos tridiagonalization, then an
-        on-host eig of the small (m, m) tridiagonal T."""
+    @property
+    def eigenvalues_(self):
+        """The k smallest Ritz values of the Laplacian (host, float64)."""
+        return self._eigenvalues
+
+    @property
+    def embedding_(self):
+        """The (n, k) spectral embedding ``fit`` clustered: the Ritz vectors
+        of the k smallest Ritz values, laid out like the data."""
+        return self._embedding
+
+    def _spectral_embedding(self, x: DNDarray, k: Optional[int]):
+        """The k lowest eigenpairs of the Laplacian
+        (reference spectral.py:98-137): Lanczos tridiagonalization, an
+        on-host eig of the small (m, m) tridiagonal T while the chip has
+        nothing to do, then the Ritz vectors ``V @ evecs[:, :k]`` at the
+        library's linalg precision and k of their rows as seeds for KMeans
+        (:func:`_embed`).  ``k=None`` picks k by the spectral-gap
+        heuristic (reference spectral.py:151-157)."""
         L = self._laplacian.construct(x)
         m = min(self.n_lanczos, x.shape[0])
         # deterministic start vector: fit() and predict() on the same data
@@ -96,41 +120,40 @@ class Spectral(ClusteringMixin, BaseEstimator):
             (n,), types.float32, None, x.device, x.comm, True,
         )
         V, T = solver.lanczos(L, m, v0=v0)
-        evals, evecs = np.linalg.eigh(np.asarray(T.larray))  # T symmetric
-        # eigenvectors of L ≈ V @ evecs, ascending eigenvalues
-        emb = jnp.matmul(V.larray, jnp.asarray(evecs, dtype=V.larray.dtype))
-        return evals, emb
+        del L
+        # the one wait for similarity, Laplacian and Lanczos together
+        t = _tel.host_read("sync:spectral.tridiag", T.larray, np.asarray)
+        evals, evecs = _tel.spanned("spectral:eigh", "other", np.linalg.eigh, t.astype(np.float64))
+        if k is None:
+            diffs = np.diff(evals[: min(len(evals), 15)])
+            k = max(int(np.argmax(diffs) + 1) if len(diffs) else 1, 1)
+        precision = basics._precision()
+        embed = jitted(("spectral.embed", precision), lambda: functools.partial(_embed, precision=precision))
+        emb, seeds = embed(V.larray, jnp.asarray(evecs[:, :k], dtype=V.larray.dtype))
+        comp = DNDarray(
+            x.comm.apply_sharding(emb, x.split), tuple(emb.shape), types.float32,
+            x.split, x.device, x.comm, True,
+        )
+        return evals[:k], comp, seeds
 
     def fit(self, x: DNDarray) -> "Spectral":
         """(reference spectral.py:138-180)"""
         sanitize_in(x)
         if x.split is not None and x.split != 0:
             raise NotImplementedError("Not implemented for other splitting-axes")
-        evals, emb = self._spectral_embedding(x)
-
-        k = self.n_clusters
-        if k is None:
-            # spectral-gap heuristic (reference spectral.py:151-157)
-            diffs = np.diff(evals[: min(len(evals), 15)])
-            k = int(np.argmax(diffs) + 1) if len(diffs) else 1
-            k = max(k, 1)
-
-        components = emb[:, :k]
-        comp = DNDarray(
-            x.comm.apply_sharding(components, x.split),
-            tuple(components.shape),
-            types.float32,
-            x.split,
-            x.device,
-            x.comm,
-            True,
+        evals, comp, seeds = self._spectral_embedding(x, self.n_clusters)
+        k = comp.shape[1]
+        seeds = DNDarray(
+            x.comm.apply_sharding(seeds, None), (k, k), types.float32, None, x.device, x.comm, True
         )
-        kmeans = KMeans(n_clusters=k, init="probability_based", random_state=0)
+        kmeans = KMeans(n_clusters=k, init=seeds)
         kmeans.fit(comp)
         self._labels = kmeans.labels_
         self._cluster_centers = kmeans.cluster_centers_
         self._kmeans = kmeans
         self._embedding_dim = k
+        self._eigenvalues = evals
+        self._embedding = comp
         return self
 
     def predict(self, x: DNDarray) -> DNDarray:
@@ -139,15 +162,29 @@ class Spectral(ClusteringMixin, BaseEstimator):
         sanitize_in(x)
         if self._labels is None:
             raise RuntimeError("Spectral has not been fitted — call fit() first")
-        _, emb = self._spectral_embedding(x)
-        components = emb[:, : self._embedding_dim]
-        comp = DNDarray(
-            x.comm.apply_sharding(components, x.split),
-            tuple(components.shape),
-            types.float32,
-            x.split,
-            x.device,
-            x.comm,
-            True,
-        )
+        _, comp, _ = self._spectral_embedding(x, self._embedding_dim)
         return self._kmeans.predict(comp)
+
+
+def _embed(V, evecs, precision):
+    """Ritz vectors of the Laplacian from the Krylov basis, and k of their
+    rows to seed KMeans with: row 0, then k - 1 times the row farthest from
+    the rows chosen so far (Ng, Jordan & Weiss 2001 seed their k-means on a
+    spectral embedding the same way).  The rows of one group lie close
+    together beside the distance between groups, so this puts one seed in
+    each group; one k-means++ draw, which the reference's ``KMeans`` would
+    make, puts two in one group for about one data set in ten and ends in a
+    partition with two groups merged and one split."""
+    with jax.named_scope("spectral.embed"):
+        emb = jnp.matmul(V, evecs, precision=precision)
+    with jax.named_scope("spectral.seed"):
+        k = evecs.shape[1]
+
+        def pick(i, state):
+            dmin, seeds = state
+            dmin = jnp.minimum(dmin, jnp.sum((emb - seeds[i - 1]) ** 2, axis=1))
+            return dmin, seeds.at[i].set(emb[jnp.argmax(dmin)])
+
+        seeds = jnp.zeros((k, k), emb.dtype).at[0].set(emb[0])
+        _, seeds = jax.lax.fori_loop(1, k, pick, (jnp.full(emb.shape[:1], jnp.inf, emb.dtype), seeds))
+    return emb, seeds

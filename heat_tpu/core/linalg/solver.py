@@ -7,12 +7,14 @@ which is equally true here.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from .. import factories, types
+from .. import types
+from .._compile import launch
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
 from . import basics
@@ -81,8 +83,32 @@ def cg(A: DNDarray, b: DNDarray, x0: DNDarray, out: Optional[DNDarray] = None) -
     return x
 
 
-@jax.jit
-def _lanczos_segment(arr, R, start, stop, carry):
+def _matvec(arr, w, precision):
+    """``arr @ w``: the one pass over the (n, n) operator a step makes."""
+    with jax.named_scope("lanczos.matvec"):
+        return jnp.matmul(arr, w, precision=precision)
+
+
+def _reorth(V, w, precision):
+    """``w`` less its projection on the columns of ``V`` (two thin products)."""
+    with jax.named_scope("lanczos.reorth"):
+        return w - jnp.matmul(V, jnp.matmul(V.T, w, precision=precision), precision=precision)
+
+
+@functools.partial(jax.jit, static_argnames=("m", "precision"))
+def _lanczos_start(arr, v, m, precision=None):
+    """Step 0: the normalised start vector as V's first column, its image
+    and T's first entry — the carry ``_lanczos_segment`` enters with."""
+    v = (v / jnp.linalg.norm(v)).astype(arr.dtype)
+    w0 = _matvec(arr, v, precision)
+    alpha0 = jnp.dot(w0, v, precision=precision)
+    V = jnp.zeros((arr.shape[0], m), dtype=arr.dtype).at[:, 0].set(v)
+    T = jnp.zeros((m, m), dtype=arr.dtype).at[0, 0].set(alpha0)
+    return V, T, w0 - alpha0 * v, v
+
+
+@functools.partial(jax.jit, static_argnames=("precision",))
+def _lanczos_segment(arr, R, start, stop, carry, precision=None):
     """Lanczos steps ``[start, stop)`` as ONE device program.
 
     The reference (solver.py:74-184) — and this module until the fuse PR —
@@ -102,6 +128,12 @@ def _lanczos_segment(arr, R, start, stop, carry):
     columns ≥ i are still zero, so their coefficients vanish and the
     projection equals the reference's ``V[:, :i]`` slice — this is what
     lets the loop body stay shape-static inside ``fori_loop``.
+
+    ``precision`` is the products' (static): ``lanczos`` passes the
+    library's linalg policy, ``highest`` unless set otherwise.  On a TPU
+    jax's default is one bf16 pass, which bounds the basis' orthogonality
+    and the Ritz pairs' residuals at about 1e-3; the matvec streams the
+    operator from memory either way, so float32 products cost it nothing.
     """
 
     def body(i, state):
@@ -110,20 +142,20 @@ def _lanczos_segment(arr, R, start, stop, carry):
         breakdown = beta < 1e-10
         # restart candidate: random column re-orthogonalized against V
         # (reference :120-130); computed unconditionally — a lax.cond would
-        # re-trace both branches anyway and the extra matvec is noise next
-        # to the m host syncs this loop used to pay
-        vr = jnp.take(R, i, axis=1).astype(arr.dtype)
-        vr = vr - V @ (V.T @ vr)
-        vr_nrm = jnp.linalg.norm(vr)
-        vr = jnp.where(vr_nrm > 0, vr / vr_nrm, vr)
-        w = jnp.where(breakdown, vr, w / jnp.where(breakdown, 1.0, beta))
+        # re-trace both branches anyway and the two thin products are noise
+        # next to the matvec
+        with jax.named_scope("lanczos.restart"):
+            vr = _reorth(V, jnp.take(R, i, axis=1).astype(arr.dtype), precision)
+            vr_nrm = jnp.linalg.norm(vr)
+            vr = jnp.where(vr_nrm > 0, vr / vr_nrm, vr)
+            w = jnp.where(breakdown, vr, w / jnp.where(breakdown, 1.0, beta))
         # full re-orthogonalization (reference :140-152)
-        w = w - V @ (V.T @ w)
+        w = _reorth(V, w, precision)
         nrm = jnp.linalg.norm(w)
         w = jnp.where(nrm > 0, w / nrm, w)
         V = V.at[:, i].set(w)
-        wnew = arr @ w
-        alpha = jnp.dot(wnew, w)
+        wnew = _matvec(arr, w, precision)
+        alpha = jnp.dot(wnew, w, precision=precision)
         w_next = wnew - alpha * w - beta * v_prev
         T = T.at[i, i].set(alpha)
         T = T.at[i - 1, i].set(beta)
@@ -170,6 +202,7 @@ def lanczos(
 
     n = A.shape[0]
     arr = A.larray.astype(jnp.float32 if types.heat_type_is_exact(A.dtype) else A.larray.dtype)
+    precision = basics._precision()
 
     from .. import random
     from ...resilience import elastic as _elastic
@@ -198,28 +231,27 @@ def lanczos(
             v = random.rand(
                 n, dtype=types.float32, device=A.device, comm=A.comm
             ).larray
-            v = v / jnp.linalg.norm(v)
         else:
             sanitize_in(v0)
-            v = v0.larray / jnp.linalg.norm(v0.larray)
-        v = v.astype(arr.dtype)
+            v = v0.larray
         # breakdown-restart candidates, one per iteration (drawn per fit,
         # used on device only when the matching step actually breaks down)
         R = random.rand(
             n, m, dtype=types.float32, device=A.device, comm=A.comm
         ).larray
-
-        V = jnp.zeros((n, m), dtype=arr.dtype).at[:, 0].set(v)
-        w0 = arr @ v
-        alpha0 = jnp.dot(w0, v)
-        T = jnp.zeros((m, m), dtype=arr.dtype).at[0, 0].set(alpha0)
-        carry = (V, T, w0 - alpha0 * v, v)
+        carry = launch(
+            "jit:lanczos.start", _lanczos_start, (arr, v), {"m": m, "precision": precision}, n=n, m=m
+        )
         it = 1
 
     while it < m:
         stop = ckpt.stop(it, m)
         with _elastic.dispatch_guard("lanczos.seg", A.comm):
-            carry = _lanczos_segment(arr, R, jnp.int32(it), jnp.int32(stop), carry)
+            carry = launch(
+                "jit:lanczos.segment", _lanczos_segment,
+                (arr, R, jnp.int32(it), jnp.int32(stop), carry), {"precision": precision},
+                steps=stop - it, n=n, m=m,
+            )
         it = stop
         if it >= m:
             break

@@ -26,19 +26,23 @@ from ..core import types
 from ..core._compile import jitted
 from ..core._tracing import in_trace
 from ..core.dndarray import DNDarray
+from ..core.linalg import basics as _linalg
 from ..core.sanitation import sanitize_in
 from ..telemetry import _core as _tel
 
 __all__ = ["cdist", "manhattan", "rbf", "quadratic_d2"]
 
 
-def quadratic_d2(xa, ya):
+def quadratic_d2(xa, ya, precision=None):
     """Squared euclidean distances via the MXU-native quadratic expansion
     |x|² + |y|² − 2xy, clamped at 0 against rounding (the one shared
-    implementation — reference _quadratic_expand, distance.py:40-72)."""
+    implementation — reference _quadratic_expand, distance.py:40-72).
+    ``precision`` is the product's (``None``: jax's default, one bf16 pass
+    on a TPU)."""
     x2 = jnp.sum(xa * xa, axis=-1, keepdims=True)
     y2 = jnp.sum(ya * ya, axis=-1, keepdims=True).swapaxes(-1, -2)
-    return jnp.maximum(x2 + y2 - 2.0 * jnp.matmul(xa, ya.swapaxes(-1, -2)), 0.0)
+    xy = jnp.matmul(xa, ya.swapaxes(-1, -2), precision=precision)
+    return jnp.maximum(x2 + y2 - 2.0 * xy, 0.0)
 
 
 def _prep(x: DNDarray, y: Optional[DNDarray]):
@@ -120,21 +124,29 @@ def rbf(
     quadratic_expansion: bool = False,
 ) -> DNDarray:
     """Gaussian (RBF) kernel matrix exp(−d²/2σ²)
-    (reference distance.py:173-179)."""
+    (reference distance.py:173-179).
+
+    A similarity is read through ``exp``, so an absolute error in d² is a
+    relative one in the result: the expansion's product takes the library's
+    linalg precision (``ht.linalg.set_matmul_precision``, ``highest`` unless
+    set otherwise), not the one bf16 pass ``cdist``'s expansion runs."""
     xa, ya, dtype = _prep(X, Y)
+    precision = _linalg._precision() if quadratic_expansion else None
 
     def _make():
         def _rbf(a, b, sig):
             if quadratic_expansion:
-                d2 = quadratic_d2(a, b)
+                with jax.named_scope("rbf.quadratic"):
+                    d2 = quadratic_d2(a, b, precision)
             else:
-                diff = a[:, None, :] - b[None, :, :]
-                d2 = jnp.sum(diff * diff, axis=-1)
+                with jax.named_scope("rbf.exact"):
+                    diff = a[:, None, :] - b[None, :, :]
+                    d2 = jnp.sum(diff * diff, axis=-1)
             return jnp.exp(-d2 / (2.0 * sig * sig))
 
         return _rbf
 
-    fn = jitted(("dist.rbf", quadratic_expansion), _make)
+    fn = jitted(("dist.rbf", quadratic_expansion, precision), _make)
     return _wrap(X, fn(xa, ya, jnp.asarray(sigma, xa.dtype)), dtype)
 
 

@@ -70,10 +70,11 @@ def test_kmeans_phase_and_its_64_bit_labels_are_seen(kmeans):
     "phase,sizes",
     [
         (chip_smoke.phase_cdist, dict(n=512, f=18, block=64)),
+        (chip_smoke.phase_spectral, dict(n=512, f=18, k=8, m=64)),
         (chip_smoke.phase_lasso, dict(n=8192, f=32, sweeps=10)),
         (chip_smoke.phase_qr_svd, dict(m=4096, n=64)),
     ],
-    ids=["cdist", "lasso", "qr_svd"],
+    ids=["cdist", "spectral", "lasso", "qr_svd"],
 )
 def test_phase_against_its_reference(phase, sizes):
     line, _ = phase(SEED, **sizes)

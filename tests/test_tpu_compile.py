@@ -293,3 +293,106 @@ def test_cdist_compiles_under_its_sites_name_with_its_scope(one_chip):
             lambda: lambda a, b: distance._euclidean(a, b, quadratic),
         )
         _assert_scopes(fn.lower(x, x).compile(), "jit_dist.euclidean", [scope])
+
+
+# --------------------------------------------------------------------- #
+# spectral_40k_c1: the similarity, the Laplacian, the Lanczos segment    #
+# --------------------------------------------------------------------- #
+SPEC_N, SPEC_F, SPEC_M, SPEC_K = 40_000, 18, 300, 8
+
+
+def _fits_the_chip(compiled, name: str) -> None:
+    """Arguments, outputs (less what they share with the arguments) and
+    temporaries together under the chip's 16 GB."""
+    m = compiled.memory_analysis()
+    total = (
+        m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+        + m.temp_size_in_bytes
+    )
+    assert total < 16e9, (name, m)
+
+
+@pytest.mark.parametrize("quadratic,scope", [(True, "rbf.quadratic"), (False, "rbf.exact")])
+def test_rbf_compiles_at_the_cells_size_with_its_scope(one_chip, quadratic, scope):
+    """The similarity ``Spectral`` asks for (the expansion, its product at the
+    linalg precision) and the exact form, 40 000 x 18 -> 6.4 GB: nothing of
+    that size beside the result."""
+    from heat_tpu.core import _compile
+
+    x = ht.array(jnp.zeros((8, SPEC_F), jnp.float32), split=0)
+    ht.spatial.rbf(x, sigma=1.0, quadratic_expansion=quadratic)  # makes the cached entry
+    key = ("dist.rbf", quadratic, "highest" if quadratic else None)
+    entry = next(fn for k, fn in _compile._CACHE.items() if k[:3] == key)
+    shape = _shape((SPEC_N, SPEC_F), one_chip)
+    compiled = entry.lower(shape, shape, _shape((), one_chip)).compile()
+    _assert_scopes(compiled, "jit_dist.rbf", [scope])
+    _fits_the_chip(compiled, "dist.rbf")
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    if quadratic:  # the product is float32's, not one bf16 pass
+        (precision,) = set(re.findall(r"convolution\(.*operand_precision=\{(\w+),(\w+)\}", compiled.as_text()))
+        assert precision == ("highest", "highest")
+
+
+@pytest.mark.parametrize("definition", ["norm_sym", "simple"])
+def test_laplacian_compiles_in_place_with_its_scopes(one_chip, definition):
+    """One program turns the 6.4 GB similarity into L in its own buffer."""
+    import functools
+
+    from heat_tpu.graph import laplacian
+
+    fn = functools.partial(
+        laplacian._laplacian, definition=definition, mode="fully_connected", key="upper", val=1.0, weighted=True
+    )
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(_shape((SPEC_N, SPEC_N), one_chip)).compile()
+    text = compiled.as_text()
+    assert "input_output_alias={ {}: (0, {}" in text.splitlines()[0]
+    names = _op_names(compiled)
+    for scope in ("laplacian.degree", "laplacian.normalize"):
+        assert any(f"/{scope}/" in n for n in names), scope
+    _fits_the_chip(compiled, "laplacian." + definition)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 4 * SPEC_N * SPEC_N and m.temp_size_in_bytes < 1 << 30
+
+
+def test_lanczos_segment_compiles_beside_the_operator_with_float32_products(one_chip):
+    from heat_tpu.core.linalg import solver
+
+    carry = (
+        _shape((SPEC_N, SPEC_M), one_chip), _shape((SPEC_M, SPEC_M), one_chip),
+        _shape((SPEC_N,), one_chip), _shape((SPEC_N,), one_chip),
+    )
+    shapes = (
+        _shape((SPEC_N, SPEC_N), one_chip), _shape((SPEC_N, SPEC_M), one_chip),
+        _shape((), one_chip, jnp.int32), _shape((), one_chip, jnp.int32), carry,
+    )
+    lowered = solver._lanczos_segment.lower(*shapes, precision="highest")
+    # as traced: every product of the step asks for float32's precision
+    dots = re.findall(r"stablehlo\.dot_general.*", lowered.as_text())
+    assert dots and all("HIGHEST" in d for d in dots), dots
+    compiled = lowered.compile()
+    _assert_scopes(compiled, "jit__lanczos_segment", ["lanczos.matvec", "lanczos.reorth", "lanczos.restart"])
+    _fits_the_chip(compiled, "lanczos.segment")
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    # as compiled: the matvec is either no MXU product at all (the compiler
+    # streams the operator through a float32 multiply-and-add) or one at
+    # ``highest``; never a bf16 pass
+    for line in compiled.as_text().splitlines():
+        if "lanczos.matvec" in line and re.search(r"= \S+ (convolution|dot)\(", line):
+            assert "operand_precision={highest,highest}" in line, line
+
+
+def test_lanczos_start_and_the_embedding_compile(one_chip):
+    import functools
+
+    from heat_tpu.cluster import spectral
+    from heat_tpu.core.linalg import solver
+
+    start = solver._lanczos_start.lower(
+        _shape((SPEC_N, SPEC_N), one_chip), _shape((SPEC_N,), one_chip), m=SPEC_M, precision="highest"
+    ).compile()
+    _assert_scopes(start, "jit__lanczos_start", ["lanczos.matvec"])
+    _fits_the_chip(start, "lanczos.start")
+    embed = jax.jit(functools.partial(spectral._embed, precision="highest")).lower(
+        _shape((SPEC_N, SPEC_M), one_chip), _shape((SPEC_M, SPEC_K), one_chip)
+    ).compile()
+    assert any("/spectral.embed/" in n for n in _op_names(embed))
