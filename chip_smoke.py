@@ -328,12 +328,16 @@ def _spectral_limits() -> dict:
 def phase_spectral(seed: int, n: int, f: int, k: int, m: int):
     """``ht.cluster.Spectral`` at a reduced n (the benchmark's cell holds the
     full 40 000 rows) against the benchmark's plain reference, by the cell's
-    own five numbers and limits."""
+    own five numbers and limits.  The line says which route Lanczos' matvec
+    took, read off the fit's own launch spans: at 8 192 rows on one chip the
+    symmetric-half kernel (``_symv.MIN_N``), on the CPU mesh the dense product."""
     import importlib.util
 
     import jax.numpy as jnp
 
     import heat_tpu as ht
+    from heat_tpu import telemetry
+    from heat_tpu.core.linalg import _symv
 
     spec = importlib.util.spec_from_file_location(
         "spectral_plain", os.path.join(ROOT, "perf", "references", "spectral_plain.py")
@@ -345,13 +349,26 @@ def phase_spectral(seed: int, n: int, f: int, k: int, m: int):
     centres = 0.35 * rng.standard_normal((k, f))
     host = (centres[np.arange(n) % k] + 0.15 * rng.standard_normal((n, f))).astype(np.float32)
     X = ht.array(host, split=0)
-    sp = ht.cluster.Spectral(n_clusters=k, gamma=1.0, n_lanczos=m).fit(X)
+    was_on = telemetry.is_enabled()
+    telemetry.enable()
+    first = len(telemetry.events())
+    try:
+        sp = ht.cluster.Spectral(n_clusters=k, gamma=1.0, n_lanczos=m).fit(X)
+        routes = sorted({
+            e["matvec"] for e in telemetry.events()[first:]
+            if e["type"] == "span" and e["site"] in ("jit:lanczos.start", "jit:lanczos.segment")
+        })
+    finally:
+        if not was_on:
+            telemetry.disable()
     out = {"labels": sp.labels_.larray, "embedding": sp.embedding_.larray, "eigenvalues": sp.eigenvalues_}
     numbers = plain.judge(jnp.asarray(host), out, k, 1.0, m)
     plain.forget()
     limits = _spectral_limits()
     line = {
         "sizes": {"rows": n, "features": f, "clusters": k, "n_lanczos": m, "laplacian_bytes": n * n * 4},
+        "lanczos_matvec": {"route": routes, "kernel_from_rows": _symv.MIN_N,
+                           "rows_under_the_kernels_threshold": n < _symv.MIN_N},
         "reference": "perf/references/spectral_plain.py (jax.numpy float32 at highest, exact-form "
                      "similarity, Python Lanczos loop), by the five numbers and limits of spectral_40k_c1",
         "checks": {name: check(numbers[name], limits[name]) for name in sorted(limits)},

@@ -17,7 +17,7 @@ from .. import types
 from .._compile import launch
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
-from . import basics
+from . import _symv, basics
 
 __all__ = ["cg", "lanczos"]
 
@@ -83,9 +83,22 @@ def cg(A: DNDarray, b: DNDarray, x0: DNDarray, out: Optional[DNDarray] = None) -
     return x
 
 
+def _matvec_route(arr) -> str:
+    """Which product ``_matvec`` compiles for ``arr``, as the launch spans
+    name it: ``symmetric_half`` or ``dense``."""
+    return "symmetric_half" if _symv.conforms(arr) else "dense"
+
+
 def _matvec(arr, w, precision):
-    """``arr @ w``: the one pass over the (n, n) operator a step makes."""
+    """``arr @ w``: the one pass over the (n, n) operator a step makes.
+
+    Lanczos' operator is symmetric, so where ``_symv.conforms(arr)`` (float32,
+    at least ``_symv.MIN_N`` rows, one TPU) the pass reads only the upper
+    block-triangle and uses each tile for both products; every other operand
+    takes the dense product, at ``precision``."""
     with jax.named_scope("lanczos.matvec"):
+        if _matvec_route(arr) == "symmetric_half":
+            return _symv.symv(arr, w, interpret=_symv._interpret())
         return jnp.matmul(arr, w, precision=precision)
 
 
@@ -179,6 +192,22 @@ def lanczos(
     (reference solver.py:74-184).  Returns (V, T) with ``T = V.T A V``
     tridiagonal, ``V`` the (n, m) orthonormal Krylov basis.
 
+    ``A`` must be symmetric (the reference documents it as symmetric
+    positive definite): ``T`` is tridiagonal only then, and the step's
+    product with ``A`` relies on it.  That product takes one of two routes,
+    chosen from what the operand shows, never by a switch.  A float32 ``A`` of
+    at least ``_symv.MIN_N`` (8 192) rows in a process that drives one TPU is
+    bound by the stream of its n x n entries from memory, so a Pallas kernel
+    (``_symv.symv``) reads only the tiles on and above the block diagonal and
+    uses each for both halves of the product, in float32 on the vector units:
+    the operator applied is ``triu(A) + triu(A, 1)^T``, exactly symmetric, and
+    differs from a stored ``A`` by no more than ``A[j, i] - A[i, j]`` (the last
+    bit, where ``Laplacian`` computed the two from ``(-a * d_i) * d_j``).
+    Every other operand (row-sharded over several chips, float64 or bfloat16,
+    smaller, on the CPU) takes the dense ``A @ w`` at the linalg precision.
+    The launch spans ``jit:lanczos.start`` and ``jit:lanczos.segment`` name
+    the route in their ``matvec`` field (``symmetric_half`` or ``dense``).
+
     The reference re-orthogonalizes rank-locally and Allreduces dot
     products (:140-152); here the inner products on the sharded vectors
     compile to all-reduces automatically, and the whole m-step iteration —
@@ -203,6 +232,7 @@ def lanczos(
     n = A.shape[0]
     arr = A.larray.astype(jnp.float32 if types.heat_type_is_exact(A.dtype) else A.larray.dtype)
     precision = basics._precision()
+    route = _matvec_route(arr)
 
     from .. import random
     from ...resilience import elastic as _elastic
@@ -240,7 +270,8 @@ def lanczos(
             n, m, dtype=types.float32, device=A.device, comm=A.comm
         ).larray
         carry = launch(
-            "jit:lanczos.start", _lanczos_start, (arr, v), {"m": m, "precision": precision}, n=n, m=m
+            "jit:lanczos.start", _lanczos_start, (arr, v), {"m": m, "precision": precision},
+            n=n, m=m, matvec=route,
         )
         it = 1
 
@@ -250,7 +281,7 @@ def lanczos(
             carry = launch(
                 "jit:lanczos.segment", _lanczos_segment,
                 (arr, R, jnp.int32(it), jnp.int32(stop), carry), {"precision": precision},
-                steps=stop - it, n=n, m=m,
+                steps=stop - it, n=n, m=m, matvec=route,
             )
         it = stop
         if it >= m:

@@ -82,6 +82,15 @@ def test_phase_against_its_reference(phase, sizes):
     assert _failed(line) == []
 
 
+def test_spectral_phase_says_which_matvec_route_its_fit_took():
+    """Off the chip, and under the kernel's threshold: the dense product, and
+    the line says both."""
+    line, _ = chip_smoke.phase_spectral(SEED, n=256, f=18, k=4, m=32)
+    assert line["lanczos_matvec"] == {
+        "route": ["dense"], "kernel_from_rows": 8192, "rows_under_the_kernels_threshold": True,
+    }
+
+
 def test_io_phase_round_trip_under_the_given_directory(tmp_path):
     line, _ = chip_smoke.phase_io(SEED, n=1024, f=32, work=str(tmp_path))
     _complete(line)

@@ -354,7 +354,32 @@ def test_laplacian_compiles_in_place_with_its_scopes(one_chip, definition):
     assert m.alias_size_in_bytes >= 4 * SPEC_N * SPEC_N and m.temp_size_in_bytes < 1 << 30
 
 
-def test_lanczos_segment_compiles_beside_the_operator_with_float32_products(one_chip):
+@pytest.fixture
+def on_one_chip(on_the_chip, monkeypatch):
+    """``on_the_chip`` for the cell's machine: the process drives one device,
+    which is what opens Lanczos' symmetric-half matvec."""
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+
+
+def _assert_the_matvec_is_the_kernel(compiled) -> None:
+    """The step's product with the operator is the Pallas kernel's custom
+    call, granted exactly the VMEM the kernel states (the compiler refuses a
+    kernel that needs more), and no MXU product of the program runs at less
+    than float32's precision."""
+    from heat_tpu.core.linalg import _symv
+
+    matvec = [line for line in compiled.as_text().splitlines() if "/lanczos.matvec/" in line]
+    calls = [line for line in matvec if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, [line[:200] for line in matvec]
+    (granted,) = re.findall(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"\}\]', calls[0])
+    assert int(granted) == _symv._VMEM_LIMIT <= 32 << 20
+    assert f"f32[{SPEC_N},{SPEC_N}]" in calls[0], "the operator is not the kernel's own operand"
+    for line in compiled.as_text().splitlines():
+        if re.search(r"= \S+ (convolution|dot)\(", line):
+            assert "operand_precision={highest,highest}" in line, line
+
+
+def test_lanczos_segment_compiles_beside_the_operator_with_float32_products(one_chip, on_one_chip):
     from heat_tpu.core.linalg import solver
 
     carry = (
@@ -365,23 +390,40 @@ def test_lanczos_segment_compiles_beside_the_operator_with_float32_products(one_
         _shape((SPEC_N, SPEC_N), one_chip), _shape((SPEC_N, SPEC_M), one_chip),
         _shape((), one_chip, jnp.int32), _shape((), one_chip, jnp.int32), carry,
     )
+    assert solver._matvec_route(shapes[0]) == "symmetric_half"
     lowered = solver._lanczos_segment.lower(*shapes, precision="highest")
-    # as traced: every product of the step asks for float32's precision
+    # as traced: the thin products of the step ask for float32's precision,
+    # and the product with the operator is no dot_general at all
     dots = re.findall(r"stablehlo\.dot_general.*", lowered.as_text())
     assert dots and all("HIGHEST" in d for d in dots), dots
+    assert not any(f"{SPEC_N}x{SPEC_N}" in d for d in dots), dots
     compiled = lowered.compile()
     _assert_scopes(compiled, "jit__lanczos_segment", ["lanczos.matvec", "lanczos.reorth", "lanczos.restart"])
     _fits_the_chip(compiled, "lanczos.segment")
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
-    # as compiled: the matvec is either no MXU product at all (the compiler
-    # streams the operator through a float32 multiply-and-add) or one at
-    # ``highest``; never a bf16 pass
-    for line in compiled.as_text().splitlines():
-        if "lanczos.matvec" in line and re.search(r"= \S+ (convolution|dot)\(", line):
-            assert "operand_precision={highest,highest}" in line, line
+    _assert_the_matvec_is_the_kernel(compiled)
 
 
-def test_lanczos_start_and_the_embedding_compile(one_chip):
+def test_lanczos_segment_on_a_row_sharded_operator_keeps_the_dense_product(four_chips, on_the_chip):
+    """Four chips, L split by rows: no kernel (GSPMD would gather L around
+    it), the parent's program, a quarter of L a chip."""
+    from heat_tpu.core.linalg import solver
+
+    comm = four_chips
+    rows = NamedSharding(comm.mesh, PartitionSpec(comm.axis_name, None))
+    rep = NamedSharding(comm.mesh, PartitionSpec())
+    carry = (_shape((SPEC_N, SPEC_M), rep), _shape((SPEC_M, SPEC_M), rep), _shape((SPEC_N,), rep), _shape((SPEC_N,), rep))
+    operator = _shape((SPEC_N, SPEC_N), rows)
+    assert solver._matvec_route(operator) == "dense"
+    compiled = solver._lanczos_segment.lower(
+        operator, _shape((SPEC_N, SPEC_M), rep), _shape((), rep, jnp.int32), _shape((), rep, jnp.int32), carry,
+        precision="highest",
+    ).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().argument_size_in_bytes < 4 * SPEC_N * SPEC_N // 4 + (1 << 28)
+
+
+def test_lanczos_start_and_the_embedding_compile(one_chip, on_one_chip):
     import functools
 
     from heat_tpu.cluster import spectral
@@ -392,6 +434,8 @@ def test_lanczos_start_and_the_embedding_compile(one_chip):
     ).compile()
     _assert_scopes(start, "jit__lanczos_start", ["lanczos.matvec"])
     _fits_the_chip(start, "lanczos.start")
+    assert start.memory_analysis().temp_size_in_bytes < 1 << 30
+    _assert_the_matvec_is_the_kernel(start)
     embed = jax.jit(functools.partial(spectral._embed, precision="highest")).lower(
         _shape((SPEC_N, SPEC_M), one_chip), _shape((SPEC_M, SPEC_K), one_chip)
     ).compile()
