@@ -1,4 +1,4 @@
-"""Shared helpers for the benchmark harnesses."""
+"""Shared helpers for the ports of the reference's harness scripts."""
 
 from __future__ import annotations
 

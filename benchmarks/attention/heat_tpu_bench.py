@@ -1,12 +1,15 @@
-"""Sequence-parallel attention benchmark — the long-context flagship.
+"""Sequence-parallel attention script, in the style of the reference's
+harness scripts.
 
 No reference analog (HeAT has no attention; SURVEY.md §5.7 maps its
-communication mechanisms onto this toolkit).  Measures exact causal/full
-attention tokens/s through the public ring formulation: on one TPU chip
+communication mechanisms onto this toolkit).  Times exact causal/full
+attention through the public ring formulation: on one TPU chip
 the ring degenerates to the fused Pallas flash kernel; on a multi-device
 mesh each ring round runs the flash partial update per device while K/V
 blocks rotate on the ICI ring (``--local-kernel xla`` times the
-GSPMD/XLA formulation instead).
+GSPMD/XLA formulation instead).  No cell of the benchmark runs attention:
+its rate on the chip is not measured.  Under ``--devices N`` (a virtual CPU
+mesh) what this prints checks the code path and is no rate.
 """
 
 from __future__ import annotations

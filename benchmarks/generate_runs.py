@@ -6,7 +6,9 @@ shell scripts sweeping mesh sizes: virtual CPU meshes for development (the
 harnesses' ``--devices N`` flag, which configures jax_num_cpu_devices
 before import) and, on real hardware, the device counts of the attached
 slice (a TPU job owns its whole slice, so the reference's nodes×tasks grid
-collapses to the mesh size).
+collapses to the mesh size).  Virtual devices share one host's cores: a
+sweep over them checks that every mesh size runs, and its times are no
+scaling curve.  The repo's benchmark is ``BENCHMARK.json`` + ``perf/``.
 """
 
 from __future__ import annotations

@@ -3,6 +3,12 @@
 
 Synthetic blobs stand in for the cityscapes H5 input (config.json:1-7);
 pass --h5 PATH DATASET to reproduce the reference's file-driven runs.
+
+A port of the reference's harness script, kept as the origin of the
+benchmark's data and settings.  It prints wall time on whatever device it
+runs on: under ``--devices N`` (a virtual CPU mesh) that checks the
+distributed code path and is no rate.  The repo's benchmark is
+``BENCHMARK.json`` + ``perf/``; its numbers are in ``PERF_LEDGER.jsonl``.
 """
 
 from __future__ import annotations

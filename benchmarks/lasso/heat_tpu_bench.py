@@ -1,5 +1,12 @@
 """Lasso benchmark (reference: benchmarks/lasso/heat-cpu.py — coordinate
-descent on the eurad H5 set, 1 iteration, 10 trials)."""
+descent on the eurad H5 set, 1 iteration, 10 trials).
+
+A port of the reference's harness script, kept as the origin of the
+benchmark's data and settings.  It prints wall time on whatever device it
+runs on: under ``--devices N`` (a virtual CPU mesh) that checks the
+distributed code path and is no rate.  The repo's benchmark is
+``BENCHMARK.json`` + ``perf/``; its numbers are in ``PERF_LEDGER.jsonl``.
+"""
 
 from __future__ import annotations
 

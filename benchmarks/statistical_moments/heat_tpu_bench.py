@@ -1,5 +1,12 @@
 """Statistical-moments benchmark (reference: benchmarks/
-statistical_moments/heat-cpu.py — mean/std along axis 0, 10 trials)."""
+statistical_moments/heat-cpu.py — mean/std along axis 0, 10 trials).
+
+A port of the reference's harness script, kept as the origin of the
+benchmark's data and settings.  It prints wall time on whatever device it
+runs on: under ``--devices N`` (a virtual CPU mesh) that checks the
+distributed code path and is no rate.  The repo's benchmark is
+``BENCHMARK.json`` + ``perf/``; its numbers are in ``PERF_LEDGER.jsonl``.
+"""
 
 from __future__ import annotations
 

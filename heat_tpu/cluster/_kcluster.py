@@ -66,9 +66,9 @@ def _kmeanspp(arr, first, us, rows_sh=None):
     each step folds the newest center into the running min-distance vector
     and samples the next row index from the d² CDF, then reads that one
     row — zero host syncs and ONE compilation for all k draws.  (A
-    per-draw formulation with ``arr[int(idx)]`` on the host recompiles the
-    gather for every distinct index — measured ~1 s/draw on a 2-device
-    mesh, dwarfing the fused fit loop it feeds.)  ``us`` is the (k,)
+    per-draw formulation with ``arr[int(idx)]`` on the host compiles the
+    gather anew for every distinct index: k compilations and k host reads
+    a fit.)  ``us`` is the (k,)
     uniform draw vector; its static length sets the number of centers.
 
     ``rows_sh`` (a NamedSharding, hashable → static; None on one device)
@@ -77,15 +77,17 @@ def _kmeanspp(arr, first, us, rows_sh=None):
 
     * the (n,) min-distance vector is pinned to every device: the distance
       pass still runs row-sharded, but the cumsum/searchsorted sampling
-      runs on a local replica — a prefix scan along a SHARDED axis is
-      pathological under GSPMD (measured 1000 ms vs 4 ms for the sharded
-      distance pass on a 2-device 100k-row mesh; replicating the 400 KB
-      vector costs ~nothing and takes the whole init from 6.8 s to 46 ms);
+      runs on a local replica: GSPMD turns a prefix scan along a SHARDED
+      axis into a sequential program across the shards, where the
+      replicated vector is 4 bytes a row (its time on the chip: not
+      measured apart; k-means++ whole is 26.1 of 354.4 ms a job on four
+      chips, ``PERF.md`` §5, ``kmeans_448_c4``);
     * where it shards the rows (``comm.sharding(1, 0)``, see
       :func:`_rows_evenly_sharded`), the drawn row comes from its owner
       (:func:`fetch_row`: f elements on the wire).  ``arr[idx]`` there
       makes GSPMD all-gather ALL of ``arr`` onto every device per draw
-      (measured 99 ms x 8 of a 1147 ms fit on four chips, and the compiler
+      (795 of 1 147 ms a fit on four chips; ledger, PR 24 against PR 26,
+      ``kmeans_448_c4``: ``job_ms`` 1 147.2 -> 354.5; and the compiler
       refuses the source's 1200 rows).  Where it does not
       (``comm.sharding(1, None)``), and on one device, the read is the
       plain dynamic slice."""

@@ -105,11 +105,13 @@ class KMeans(_KCluster):
         per iteration).  The |x|² row norms are omitted from the
         assignment entirely: they are constant across the k candidates,
         so ``argmin_k(|x|² + |c|² − 2x·c) == argmin_k(|c|² − 2x·c)``
-        exactly.  Dropping them removes a full HBM pass over ``arr`` and
-        lets XLA fuse the whole step — distance matmul, argmin, one-hot
-        masked-sum matmul — into one row-blocked sweep: 141.7 →
-        65.1 µs/iter on TPU v5e (~2.2x), right at the single-pass
-        bandwidth roofline."""
+        exactly, which saves a pass over ``arr``.  On the chip a sweep is
+        still TWO passes over a bf16 copy of ``arr``: XLA emits the
+        distance matmul with its argmin and the one-hot masked-sum
+        matmul as two fusions, 160 + 169 ms of a 496.6 ms job at
+        300 x 6 291 456 (``roofline_pct`` 75.5; ledger, PR 29,
+        ``kmeans_300_c1``; breakdown in ``PERF.md`` §5).  The one-pass
+        row-blocked sweep is ROADMAP Speed 2, open."""
 
         def step(c):
             with jax.named_scope("kmeans.sweep.assign"):

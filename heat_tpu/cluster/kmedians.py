@@ -34,12 +34,12 @@ __all__ = ["KMedians"]
 def _presort_values(arr):
     """One-time (per fit) value sort of every feature column plus the
     per-column finite clamp range: ``(svals, fmin, fmax)``.  The sort is
-    a single-operand non-stable ``lax.sort`` — measured 250x faster on
-    TPU than the stable variant the original ``argsort`` emitted — and
-    the ONLY sort in the whole KMedians fit.  The clamp range is computed
-    HERE because it is loop-invariant: computing it inside the Lloyd
-    while_loop cost ~4.5 ms/iteration in full-matrix reduces (XLA does
-    not hoist out of while bodies)."""
+    a single-operand non-stable ``lax.sort`` (no index operand to carry,
+    as the stable ``argsort`` had) and the ONLY sort in the whole KMedians
+    fit.  The clamp range is computed HERE because it is loop-invariant:
+    inside the Lloyd while_loop it is two full-matrix reduces an
+    iteration (XLA does not hoist out of while bodies).  No cell runs
+    KMedians: its time on the chip is not measured."""
     svals = jax.lax.sort(arr, dimension=0, is_stable=False)
     finite = jnp.isfinite(svals)
     fmax = jnp.max(jnp.where(finite, svals, -jnp.inf), axis=0)
@@ -61,8 +61,7 @@ _WARM_WINDOW = 64
 def _cluster_medians(arr, svals, fmin, fmax, onehot, counts, k, prev_pos=None):
     """Exact per-cluster per-feature medians, (k, f), by RANK-SPACE
     BISECTION with matmul rank counts — zero per-iteration sorts and zero
-    O(n·f) gathers (TPU gathers of (n, f) indices measured ~13 ms at the
-    benchmark config; this routine's only gathers are (k, f, 2) threshold
+    O(n·f) gathers (this routine's only gathers are (k, f, 2) threshold
     probes).
 
     The t-th smallest member of cluster c in feature j is found by binary
@@ -82,9 +81,8 @@ def _cluster_medians(arr, svals, fmin, fmax, onehot, counts, k, prev_pos=None):
     last and are never counted by ``x <= thr``, so a cluster whose median
     position lands in its NaN tail returns the column maximum/NaN — the
     sort-last semantics of the reference's gathered-member median
-    (reference kmedians.py:43-66).  Replaces the r2 per-cluster
-    ``nanmedian`` (k full sorts per step, BENCH_r02: 2,300x a KMeans
-    step)."""
+    (reference kmedians.py:43-66).  Replaces a per-cluster
+    ``nanmedian``, which is k full sorts per step."""
     n, f = arr.shape
     # 1-indexed member ranks of the two middles (equal when count is odd)
     t = jnp.maximum(

@@ -92,20 +92,6 @@ class KMedoids(_KCluster):
         n_iter, centers, _ = jax.lax.while_loop(cond, body, init)
         return centers, _assign(arr, centers), n_iter
 
-    @staticmethod
-    @jax.jit
-    def _step_loop(arr, centers, n):
-        """Exactly ``n`` assign+update steps with NO convergence test, for
-        slope-timed benchmarking (bench.py): snapping converges exactly, so
-        a tolerance knob cannot force the while_loop to keep iterating the
-        way KMeans/KMedians ``tol=-1`` does — this fori_loop runs the same
-        step kernel a fixed number of times instead."""
-
-        def body(i, c):
-            return _medoid_update(arr, _assign(arr, c), c)
-
-        return jax.lax.fori_loop(0, n, body, centers)
-
     def fit(self, x: DNDarray) -> "KMedoids":
         """Iterate until the medoids stop moving (reference
         kmedoids.py:104-130), as a single on-device loop."""

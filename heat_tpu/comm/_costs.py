@@ -80,8 +80,8 @@ _COMPRESSIBLE = ("float32", "bfloat16")
 
 #: Nominal per-link ICI bandwidth (GB/s, one direction) used when a
 #: critical-path estimate needs a wire-time denominator and no measured
-#: figure is supplied.  A planning constant, not a measurement — bench
-#: headlines always pair modeled time with a same-run measured twin.
+#: figure is supplied.  A planning constant, not a measurement: no cell
+#: of the benchmark has calibrated it (ROADMAP Design 4).
 DEFAULT_ICI_GBPS = 45.0
 
 
@@ -113,8 +113,8 @@ def critical_path_ms(
 
 #: Nominal sustained host storage read bandwidth (GB/s) for the
 #: streaming-ingest model when no measured figure is supplied — local
-#: NVMe territory; like :data:`DEFAULT_ICI_GBPS`, a planning constant the
-#: bench always pairs with a same-run measured twin.
+#: NVMe territory; like :data:`DEFAULT_ICI_GBPS`, a planning constant,
+#: not a measurement.
 DEFAULT_HOST_READ_GBPS = 2.0
 
 #: Nominal host→device copy bandwidth (GB/s, one direction) — a PCIe-class
@@ -899,9 +899,8 @@ def summa_grid_model(
     they share one bitwise replicated twin.  Figures assume f32 panels
     (:func:`ring_wire_model`'s exact-byte convention); degenerate mesh
     axes contribute zero wire.  This function is the single source the
-    runtime telemetry is credited from (``core/linalg/basics.py``) and
-    the bench headline prices — delegation keeps accounted and modeled
-    bytes identical.
+    runtime telemetry is credited from (``core/linalg/basics.py``), so
+    accounted and modeled bytes are identical.
     """
     if layout not in ("grid", "rowcol", "colrow"):
         raise ValueError(f"unknown SUMMA layout {layout!r}")

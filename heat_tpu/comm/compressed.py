@@ -699,10 +699,10 @@ def wire_model(n_elems: int, size: int, mode: Optional[str], *,
     allreduce"`` models the reduce-scatter + all-gather ring (each device
     sends ``2*(size-1)`` chunks of ``ceil(n/size)`` elements padded to
     the block grid); ``op="allgather"`` the one-way ring (``size-1`` hops
-    of the ``n_elems``-element local shard).  Shared by bench.py's
-    ``allreduce_q_wire_model`` headline and the telemetry layer's live
-    exact-vs-wire byte accounting, so the reported ratio and the tested
-    exact-byte math can never drift apart.  The arithmetic itself lives
+    of the ``n_elems``-element local shard).  The telemetry layer's live
+    exact-vs-wire byte accounting (:func:`_account_wire`) is credited from
+    this same model, so the accounted ratio and the tested exact-byte math
+    cannot drift apart.  The arithmetic itself lives
     in the shared jax-free model (:mod:`heat_tpu.comm._costs`), which the
     static analyzer loads by file path."""
     return _costs.ring_wire_model(n_elems, size, mode, block=block, op=op)

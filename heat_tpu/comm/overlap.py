@@ -47,8 +47,7 @@ overhead while disabled):
   by construction (SPMD205): they wrap the eager call site, never the
   traced body.
 
-docs/design.md §18 documents the double-buffer carry shapes and the
-overlap-efficiency bench metric built on this policy.
+docs/design.md §18 documents the double-buffer carry shapes.
 """
 
 from __future__ import annotations

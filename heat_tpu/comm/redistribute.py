@@ -38,7 +38,7 @@ The cost model (:meth:`Plan.wire_bytes` / :meth:`Plan.peak_live_bytes`,
 :func:`monolithic_model` for the one-shot reshard's envelope) follows
 :func:`heat_tpu.comm.compressed.wire_model`'s conventions — per-device
 bytes, block-padded compressed payloads — and is the same arithmetic the
-telemetry ledger is credited with, so benched ratios and accounted bytes
+telemetry ledger is credited with, so modeled ratios and accounted bytes
 cannot drift apart.  ``plan(..., max_live_bytes=)`` turns the model into
 a hard bound: a schedule whose modeled peak exceeds it raises instead of
 silently over-allocating.
@@ -202,7 +202,7 @@ class Plan:
     steps: Tuple[Tuple, ...]
     #: modeled bytes each device puts on the wire (mode-dependent)
     wire_bytes: int
-    #: same traffic shipped as the exact dtype (the bench denominator)
+    #: same traffic shipped as the exact dtype (the ratio's denominator)
     exact_wire_bytes: int
     #: modeled peak live bytes per device while the program runs
     peak_live_bytes: int
@@ -238,14 +238,14 @@ class Plan:
 
     def wire_model(self, compute_ms_per_step: float = 0.0) -> dict:
         """Cost-model dict in the :func:`compressed.wire_model` shape —
-        the single source for bench headlines and telemetry accounting.
+        the single source for telemetry accounting.
 
         ``critical_path_ms`` prices the schedule's wire time under both
         ring schedules (:func:`heat_tpu.comm._costs.critical_path_ms`):
         ``"serial"`` sums wire + compute per hop, ``"overlap"`` is the
         pipelined ``max(wire, compute)`` roofline the overlap policy
         targets.  ``compute_ms_per_step`` defaults to 0 (pure wire
-        bound); bench passes its measured per-step compute probe."""
+        bound)."""
         exact = self.exact_wire_bytes
         hops = sum(1 for s in self.steps if s[0] == "rotate")
         return {

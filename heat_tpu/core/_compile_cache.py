@@ -1,7 +1,7 @@
 """Where a process keeps JAX's persistent compilation cache.
 
 The library sets nothing at import.  The entry points that start a process
-(``chip_smoke.py``, ``bench.py``, ``benchmarks/_common.py``, the serving
+(``chip_smoke.py``, ``perf/run.py``, ``benchmarks/_common.py``, the serving
 replica's ``_replica_main``) call :func:`place_compile_cache` once, before
 their first compile:
 

@@ -13,7 +13,7 @@ flag that tells the rest of the core which of the two worlds it is in,
 plus the diagnostic error raised when traced code demands a concrete
 value.
 
-It also hosts the *dispatch counter* — the test/bench shim that counts
+It also hosts the *dispatch counter* — the shim that counts
 device program launches at the library level.  Counting at the jax/XLA
 layer is not reliable from Python (the C++ pjit fast path bypasses any
 Python wrapper after the first call), so the counter is incremented by the

@@ -180,7 +180,7 @@ class _AutoshardFunction:
     def plan(self, comm=None) -> Optional[dict]:
         """The solved plan for ``comm`` (default communicator when
         ``None``) under the CURRENT comm policies — introspection for
-        tests, benches, and docs.  ``None`` on the plain-fuse fallback."""
+        tests and docs.  ``None`` on the plain-fuse fallback."""
         from .communication import sanitize_comm
 
         return self._program(sanitize_comm(comm))[1]

@@ -158,8 +158,8 @@ def _sharded_from_reader(shape, np_dtype, split, device, comm, read_slices):
     split = sanitize_axis(shape, split)
     hdtype = types.canonical_heat_type(np_dtype)
     # io:read brackets the slab reads, io:h2d the device commit, and both
-    # credit account_bytes("io", ...) — the streaming/bench bandwidth
-    # headlines reconcile against this ledger like every comm headline
+    # credit account_bytes("io", ...): streamed bytes reconcile against
+    # this ledger like every collective's
     total_bytes = int(np.prod(shape, dtype=np.int64)) * np.dtype(hdtype._np_type).itemsize
     if split is not None and shape[split] % comm.size == 0 and comm.size > 1:
         sharding = comm.sharding(len(shape), split)

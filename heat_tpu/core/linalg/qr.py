@@ -65,9 +65,7 @@ def _tsqr_program(comm):
     """The two-stage TSQR pipeline as a traceable ``f(x) -> (q, r)`` over
     a shard-padded row-split operand: per-shard local QR inside shard_map,
     a second QR of the small (size·n, n) R stack, and the Q-correction
-    matmul.  Module-level so bench.py can embed the EXACT production
-    compute graph inside its single-dispatch timing region; :func:`_tsqr`
-    wraps it in the keyed-jit cache.  A single-device mesh degenerates to
+    matmul.  :func:`_tsqr` wraps it in the keyed-jit cache.  A single-device mesh degenerates to
     one on-device QR (what :func:`qr` dispatches there)."""
     if comm.size == 1:
         return jnp.linalg.qr
@@ -553,8 +551,8 @@ def _grid_qr_reference(arr, mesh_shape, *, tiles_per_proc=1, overlapped=False):
     """Replicated golden twin of the grid CAQR: runs the exact panel
     schedule of :func:`_grid_qr_fn` on an unsharded operand via
     :func:`_caqr_sim` and reassembles the padded global ``(q, r)`` —
-    bitwise-equal to the kernel's outputs (bench.py and
-    tests/test_linalg2d.py pin this).
+    bitwise-equal to the kernel's outputs (tests/test_linalg2d.py pins
+    this).
 
     The whole simulation runs as ONE jitted program: eager per-op
     execution changes XLA CPU's fusion context and with it the emission

@@ -421,7 +421,7 @@ def _qdwh_svd_reference(arr, mesh_shape):
 
     The golden replays the SERIAL panel order only: the kernel's overlap
     arm is pinned bitwise to its serial arm (asserted directly in
-    tests/bench), so one canonical golden covers both.  Simulating the
+    tests/test_linalg2d.py), so one canonical golden covers both.  Simulating the
     reordered overlap schedule inside this much larger program trips
     XLA CPU's fusion-context sensitivity in ops beyond the barriered
     matmuls/reductions — the two sim arms match bitwise in a minimal

@@ -24,8 +24,8 @@ Determinism contract (what makes the streaming fits' twins bitwise):
   the consuming program masks pads exactly;
 - the prefetch policy changes host scheduling ONLY — both arms read the
   same bytes in the same order and dispatch the same compiled program,
-  so prefetch-on is bitwise-equal to prefetch-off by construction (the
-  bench gate asserts it every run);
+  so prefetch-on is bitwise-equal to prefetch-off by construction
+  (``tests/test_stream.py`` asserts it);
 - every chunk read crosses the ``faults.io_open(..., site="stream.read")``
   seam under the bounded, seeded io retry policy: an injected transient
   ``OSError`` mid-stream heals with the attempt incident-logged, and the
@@ -132,7 +132,7 @@ def _prefetch_token() -> Tuple:
     key.  The traced chunk programs are schedule-independent (prefetch
     only reorders host work), but keying on the policy keeps each arm's
     first-dispatch/compile telemetry attributable to its own setting —
-    the same discipline as ``set_overlap``, and what lets one bench run
+    the same discipline as ``set_overlap``, and what lets one process
     hold both arms side by side.  The backend check inside
     :func:`prefetch_enabled` is deliberately NOT part of the token — the
     process backend is fixed for the life of the cache."""
@@ -191,7 +191,7 @@ def slab_peak() -> int:
 
 
 def reset_slab_peak() -> None:
-    """Reset the slab high-water mark (test/bench bracketing)."""
+    """Reset the slab high-water mark (test bracketing)."""
     _SLABS.reset()
 
 

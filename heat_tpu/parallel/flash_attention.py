@@ -3,15 +3,15 @@
 The plain attention path (ring_attention's single-block branch; the
 reference has no fused kernel at all — its long-context story is
 process-level sequence parallelism) materializes the full (H, S, S)
-score tensor in HBM: at S=4096, H=16 that is 1 GB written + read twice
-more through softmax and the PV matmul, so the whole op runs at the HBM
-roofline (~15 TFLOP/s measured on v5e).  This kernel never materializes
+score tensor in HBM: at S=4096, H=16 in float32 that is 1 GB written and
+read twice more through softmax and the PV matmul.  This kernel never materializes
 scores: each (q-block, k-block) tile lives in VMEM, the softmax is the
 streaming one-pass rescaling (same algebra as
 ring_attention._blockwise_update, which IS flash attention across
 devices — here applied across VMEM blocks), and only the (S, D) output
-ever touches HBM.  Measured on v5e at S=4096 H=16 D=64 bf16:
-60 TFLOP/s vs 15 for the plain path (4×); causal ~31 TFLOP/s effective.
+ever touches HBM.  Its rate is not measured on today's code: no cell of
+the benchmark runs attention (ROADMAP Reach B7, ``attn_32k_c1``);
+``chip_smoke.py`` checks its result on the chip, not its time.
 
 Layout: grid (batch*heads, S/BQ); each program pins its q block plus the
 full local K/V in VMEM and streams K/V through the running softmax in
@@ -24,14 +24,14 @@ and chunks wholly above it are never visited at all (a dynamic-bound
 cost zero MXU work — unlike a value-level ``lax.cond``, which lowers to
 compute-both-select).  Causal also clamps BK to BQ: with BK=2048 a
 512-row q block's diagonal chunk is 87% masked work, while BK=BQ=512
-bounds the masked fraction of visited tiles by ~1/(2n).  Design notes
-from the measured alternatives (same shapes, v5e):
-- a third k grid dimension with scratch accumulators: 24-42 TF/s — the
-  per-chunk scratch round-trips and small DMAs dominate;
-- VMEM scratch accumulators instead of loop carries: 24 TF/s;
+bounds the masked fraction of visited tiles by ~1/(2n).  Alternatives
+that were tried and dropped before PR 1 (their rates are in no record):
+- a third k grid dimension with scratch accumulators: a scratch round
+  trip and a small DMA per chunk;
+- VMEM scratch accumulators instead of loop carries;
 - causal tail skip via ``lax.cond`` (the pre-triangular scheme): Mosaic
-  lowers the value-level cond to compute-both-select, which pinned
-  causal at ~31 TF/s — the same masked half computed and discarded.
+  lowers the value-level cond to compute-both-select, so the masked half
+  is computed and discarded.
 
 Falls back to the jnp path (XLA-fused, HBM-bound but correct) off-TPU
 unless ``interpret=True`` (used by the CPU test suite), and for local

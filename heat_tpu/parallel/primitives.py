@@ -237,9 +237,10 @@ def prefix_scan(
     The engine under distributed cumulative ops (the data-axis analog of
     the reference's ``Scan`` collective, communication.py:524-567): asking
     GSPMD to partition ``jnp.cumsum`` along a sharded axis produces a
-    pathological sequential program — measured 1000 ms at 1M elements on
-    the 8-device dev mesh where this formulation runs the two bandwidth
-    passes it actually needs (~4 ms).  Any axis length is accepted: the
+    sequential program across the shards, where this formulation runs
+    the two passes over the data it actually needs plus one all-gather
+    of p totals (its time on the chip: not measured; no cell).  Any axis
+    length is accepted: the
     canonical padding is filled with the op identity, so it is invisible
     to the scan.
     """

@@ -46,7 +46,7 @@ def _blockwise_update(q, k, v, m, num, den, scale, mask=None):
     ``preferred_element_type`` so neither a bf16 input nor a wide scalar
     can move the softmax off f32 — under x64 an unpinned
     ``np.float64`` scale silently promoted the whole S×S score tensor to
-    software-emulated f64 (measured 0.3 TFLOP/s vs MXU-native f32)."""
+    software-emulated f64, which never reaches the MXU."""
     from .flash_attention import _matmul_precision
 
     acc = num.dtype
@@ -106,8 +106,8 @@ def ring_attention(
       (flash_attention_partial) on TPU when the local block conforms
       (flash_attention.conforms: L a multiple of 128, f32/bf16, K/V
       within the VMEM budget) — it never materializes the L×L score
-      tile in HBM, which at long context is the difference between
-      ~60 and ~15 TFLOP/s per device — else the XLA blockwise update;
+      tile in HBM (rates of either engine: not measured on today's
+      code; no cell) — else the XLA blockwise update;
     - ``"flash"``: force the Pallas engine (interpreted off-TPU — the
       CPU test suite's path for exercising the real ring+flash program);
     - ``"xla"``: force the jnp blockwise update.

@@ -18,9 +18,8 @@ Boot sequence (the zero-compile contract, design.md §22/§25):
 3. run one warmup predict per warm model and measure the
    ``fuse.cache.misses`` / ``compile.cache.misses`` deltas across it —
    a sidecar-warmed replica serves its first request with BOTH deltas
-   zero, and the **hello frame ships the deltas**, so the parent (and
-   the bench's ``fleet_proc_model.zero_compile_spinups``) asserts the
-   contract across the process boundary instead of trusting it;
+   zero, and the **hello frame ships the deltas**, so the parent asserts
+   the contract across the process boundary instead of trusting it;
 4. serve the RPC loop: strictly sequential recv → handle → reply, so
    within one replica the reply order is the request order (the parent
    keeps at most one request in flight per replica, which is what makes

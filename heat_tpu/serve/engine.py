@@ -16,8 +16,8 @@ to the device itself (a plain ``jax.device_put`` against the lane
 comm's NamedSharding) instead of routing it through ``factories.array``
 — the factory's layout commit records a dispatch of its own, which
 would double-count the host→device staging transfer as a program
-launch.  The staging put is a transfer, not a launch; the dispatch
-models in bench account it under wire bytes instead.
+launch.  The staging put is a transfer, not a launch; the engine's
+counters account it under wire bytes instead.
 
 Degrade wiring (``resilience.guard("degrade")`` per request): every
 payload is health-screened at submit — the same
@@ -221,7 +221,7 @@ class ServeEngine:
         self._lock = threading.Lock()
         self._background = False
         self._closed = False
-        # dispatch/wire accounting (the bench models read these)
+        # dispatch/wire accounting (``loadgen.run`` and ``stats()`` read these)
         self.n_requests = 0
         self.n_batches = 0
         self.n_rows = 0

@@ -21,8 +21,8 @@ plus three control loops the single-host engine never needed:
   traffic for one ``(tenant, model)`` to the canary version while the
   stable version keeps the rest.  Assignment is a pure function of
   ``(seed, submit order)``, so the non-canary slice of a canary run is
-  bitwise-comparable to a stable-only run of the same payload stream —
-  the bench's golden-twin discipline extended to deployment.
+  bitwise-comparable to a stable-only run of the same payload stream:
+  the golden-twin discipline extended to deployment.
 
 Chaos rides the same seams as everything else: ``device_arrival`` /
 ``device_loss`` plans with ``site="fleet.tick"`` force scale events
@@ -178,7 +178,7 @@ class FleetEngine:
         self.assignments: List[bool] = []  # True = routed to canary
         self.n_canary = 0
         self.n_stable = 0
-        # scale-event ledger (the bench and the chaos lane read these)
+        # scale-event ledger (the chaos lane reads these)
         self.cold_start_ms: List[float] = []
         self.scale_events: List[Dict] = []
         self.n_scale_ups = 0

@@ -7,8 +7,8 @@ standard-normal payloads from a derived stream — :func:`schedule` and
 :func:`payloads` take no wall-clock input at all, so the same seed
 replays the same request sequence byte for byte.
 
-:func:`run` drives an engine with that sequence and reports the two
-bench headlines — ``serve_predictions_per_sec`` and ``serve_p99_ms`` —
+:func:`run` drives an engine with that sequence and reports
+``serve_predictions_per_sec`` and ``serve_p99_ms``,
 plus the dispatch model (dispatches per micro-batch, batch occupancy)
 and wire model (payload/reply bytes).  With ``twin=True`` it re-runs
 every request through the engine's UNBATCHED direct-predict path and

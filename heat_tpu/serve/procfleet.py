@@ -7,8 +7,8 @@ number.  :class:`ProcFleet` is the other half: each replica is an OS
 **process** (:mod:`heat_tpu.serve._replica_main`) hosting a sidecar-
 warmed :class:`ServeEngine`, joined to the parent by one loopback TCP
 connection speaking the :mod:`heat_tpu.net.wire` framing.  Processes do
-not share a GIL, so the ``fleet_aggregate_pps`` scaling curve measured
-over 1→2→4 replicas is real even on CPU smoke hardware.
+not share a GIL, so replicas serve in parallel (no cell measures the
+fleet: ROADMAP Reach B10).
 
 Architecture (design.md §25)::
 
@@ -507,7 +507,7 @@ class ProcFleet:
 
     def scale_to(self, n: int) -> None:
         """Grow the fleet to ``n`` live replicas (warm spawns).  Shrink
-        is not implemented — the scaling bench only grows."""
+        is not implemented: no caller shrinks."""
         while len(self.alive()) < int(n):
             self._spawn_one()
 

@@ -427,7 +427,7 @@ def account_bytes(op: str, mode: str, exact_bytes: int, wire_bytes: int) -> None
     """Credit one collective's traffic to the exact-vs-wire ledger.
 
     ``exact_bytes`` is what the payload would cost on the wire as exact
-    f32 (the common denominator the bench suite already reports in);
+    f32 (the common denominator of the wire models);
     ``wire_bytes`` what the resolved precision mode actually ships.  The
     per-mode compression ratio is maintained as a live gauge
     ``comm.wire_ratio.<mode>`` — for ``int8_block`` ring traffic it sits

@@ -82,7 +82,7 @@ fi
 echo "--- telemetry artifacts ---"
 ls -l "$tel_dir" 2>/dev/null || true
 # redistribution lane: the full planned-vs-monolithic parity matrix plus
-# a CPU bench smoke asserting the planner's modeled wire bytes never
+# a CPU smoke asserting the planner's modeled wire bytes never
 # exceed the monolithic envelope and the modeled peak respects the
 # max_live_bytes bound (docs/design.md §14)
 echo "=== redistribution lane (planner parity matrix + cost-model smoke) ==="
@@ -226,9 +226,7 @@ then
 fi
 # obs lane (docs/design.md §19): the request-scoped observability suite,
 # then a /metrics scrape of a LIVE ServeEngine (Prometheus text parsed
-# and byte-compared against telemetry.snapshot()), then the bench_diff
-# regression gate — self-compare must pass clean AND an injected
-# synthetic regression must flip the exit status (the gate's self-test)
+# and byte-compared against telemetry.snapshot())
 echo "=== obs lane (tracing, histograms, SLO burn, flight recorder, /metrics) ==="
 if ! HEAT_CHAOS_SEED="${HEAT_CHAOS_SEED:-0}" python -m pytest tests/test_obs.py -q; then
     echo "FAILED obs suite (reproduce with HEAT_CHAOS_SEED=${HEAT_CHAOS_SEED:-0})"
@@ -287,16 +285,6 @@ then
     echo "FAILED /metrics scrape smoke"
     fail=1
 fi
-if ! python scripts/bench_diff.py > /dev/null; then
-    echo "FAILED bench_diff self-compare (must be 0 flags)"
-    fail=1
-fi
-if python scripts/bench_diff.py --inject serve_p99_ms=2.0 > /dev/null; then
-    echo "FAILED bench_diff gate self-test (injected regression not caught)"
-    fail=1
-else
-    echo "bench_diff: self-compare clean; injected regression caught (exit nonzero)"
-fi
 # overlap lane: the latency-hiding policy (docs/design.md §18) — every
 # double-buffered ring against its same-run serial twin at byte
 # granularity, then the compressed + redistribution suites re-run with
@@ -328,34 +316,6 @@ raise SystemExit(pytest.main([
 PY
 then
     echo "FAILED overlap lane (suite under set_overlap('on'))"
-    fail=1
-fi
-# fresh overlap-efficiency headline, archived beside the telemetry
-# artifacts: on CPU the roofline is not modeled (value null, disposition
-# recorded) but the serial-twin bitwise gate still runs for real
-if ! HEAT_BENCH_SMOKE=1 python - <<'PY'
-import json
-import os
-
-import numpy as np
-
-import heat_tpu as ht
-import bench
-
-X = ht.array(np.random.default_rng(0).normal(
-    size=(64 * ht.get_comm().size, 8)).astype(np.float32), split=0)
-value, ratios, model = bench.overlap_efficiency_rates(X)
-art = os.environ.get("HEAT_TELEMETRY_ARTIFACT_DIR", "/tmp/heat-telemetry-artifacts")
-os.makedirs(art, exist_ok=True)
-path = os.path.join(art, "overlap-headline.json")
-with open(path, "w") as fh:
-    json.dump({"ring_overlap_efficiency": value, "overlap_vs_serial": ratios,
-               "ring_overlap_model": model}, fh, indent=1)
-assert all(f["bitwise_equal"] for f in model["families"].values()), model
-print("overlap headline artifact:", path)
-PY
-then
-    echo "FAILED overlap headline (bench smoke / twin parity)"
     fail=1
 fi
 # mesh2d lane (docs/design.md §20): the 2-D grid suite — splits-tuple

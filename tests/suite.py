@@ -112,3 +112,15 @@ def run_in_fresh_python(script: str, env_overrides=None, drop_env=(), timeout=24
         env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
+
+
+def assert_within_bound(got, want, bound):
+    """``|got - want| <= bound`` entry by entry, ``got`` finite: for a
+    comparison stated as a rounding bound, which is entrywise where
+    ``assert_allclose``'s ``atol`` is one number.  Names the worst entry."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape, (got.shape, want.shape)
+    assert np.isfinite(got).all()
+    err = np.abs(got.astype(np.float64) - want.astype(np.float64))
+    worst = np.unravel_index(np.argmax(err - bound), err.shape)
+    assert (err <= bound).all(), (worst, err[worst], np.broadcast_to(bound, err.shape)[worst])

@@ -8,10 +8,10 @@ Three layers of assertion:
 - interpret-mode parity of the triangular kernel against the dense
   reference across block configurations;
 - the zig-zag ring (both local engines) against the single-device causal
-  reference across mesh sizes, INCLUDING a bitwise comparison against a
-  serial replay of the identical fold schedule — floating-point
-  non-associativity makes bit-for-bit against a dense softmax
-  meaningless, but the ring must reproduce its own schedule exactly.
+  reference across mesh sizes, INCLUDING a comparison against a serial
+  replay of the identical fold schedule, held to the rounding of the
+  operations both sides share (a stated tolerance: see
+  ``test_zigzag_ring_bitwise_vs_schedule_replay``).
 """
 
 from __future__ import annotations
@@ -26,6 +26,7 @@ from heat_tpu.core.communication import XlaCommunication
 from heat_tpu.parallel import flash_attention
 from heat_tpu.parallel.flash_attention import _causal_chunk_bounds, conforms
 from heat_tpu.parallel.ring_attention import _blockwise_update
+from suite import assert_within_bound
 
 RNG = np.random.default_rng(23)
 
@@ -245,11 +246,31 @@ def _zigzag_replay(q, k, v, size):
 
 
 def test_zigzag_ring_bitwise_vs_schedule_replay():
-    # the ring result must be BIT-FOR-BIT the serial replay of its own
-    # fold schedule in f32 — communication and SPMD staging may not
-    # perturb a single ulp.  (Bitwise equality against a dense softmax is
-    # impossible for any blockwise algorithm: fp addition is not
-    # associative; the schedule replay is the honest bitwise reference.)
+    """The ring against the serial replay of its own fold schedule: a
+    STATED TOLERANCE, not one reduction order by construction.
+
+    Why not bitwise: the replay runs the same folds in the same order on
+    the same (1, H, Lh, D) operands through the same ``_blockwise_update``,
+    but as one straight-line program a device where the ring is a
+    ``fori_loop`` body inside ``shard_map``.  XLA's CPU backend picks a
+    dot's blocking, and whether ``a * b + c`` becomes one FMA, from the
+    fusion it lands in, so the two read one float32 rounding apart in a
+    few entries of a hundred on some machines and bit-equal on others;
+    the ring's kernel is a closure the test cannot re-enter, so the same
+    compiled dots cannot be had from here.
+
+    What can be guaranteed of two float32 runs of ONE operation sequence
+    that differ only in the rounding inside an operation, per output
+    entry, in units of ``eps * attention(|v|)`` (eps = 2**-23 covers both
+    sides): ``2 (D + 1) A`` for a key's score (D products and a scale,
+    ``A`` the largest ``sum_d |q||k| * scale``, entering numerator and
+    denominator weights), ``2 (Lh + 1)`` for the fold's two sums the key
+    sits in, 6 a fold for the correction's exp, multiply and add, and 4
+    for the key's own exp and the final divide.  A wrong chunk, a missed
+    or doubled fold, or a mask off by one row reads 1e-2 and more.
+
+    Exact part: the ring is finite everywhere and a second call returns
+    the same bits (one compiled program, no order left to the runtime)."""
     comm = ht.get_comm()
     if comm.size == 1:
         pytest.skip("needs a mesh")
@@ -261,7 +282,15 @@ def test_zigzag_ring_bitwise_vs_schedule_replay():
         qs, ks, vs, causal=True, comm=comm, local_kernel="xla"
     ))
     replay = _zigzag_replay(q, k, v, size)
-    np.testing.assert_array_equal(ring, replay)
+    Lh, n_folds = S // (2 * size), 2 * size
+    A = float(np.max(np.einsum("qhd,khd->hqk", np.abs(q), np.abs(k)))) / np.sqrt(D)
+    k_round = 2 * (D + 1) * A + 2 * (Lh + 1) + 6 * n_folds + 4
+    bound = k_round * float(np.finfo(np.float32).eps) * _reference(q, k, np.abs(v))
+    assert_within_bound(ring, replay, bound)
+    again = np.asarray(ht.parallel.ring_attention(
+        qs, ks, vs, causal=True, comm=comm, local_kernel="xla"
+    ))
+    np.testing.assert_array_equal(again, ring)
 
 
 def test_zigzag_flash_and_xla_engines_agree():

@@ -141,34 +141,3 @@ def test_conditional_accelerator_singletons():
         assert not hasattr(ht, "gpu")
     else:
         assert ht.gpu is devices.gpu
-
-
-def test_bench_regression_guard(tmp_path, monkeypatch):
-    """bench.regression_check flags >10% headline slides against the
-    newest BENCH_r*.json (VERDICT r2: the qr_svd regression cost nothing
-    because nothing compared rounds)."""
-    import json
-    import sys
-    import os
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
-    import bench
-
-    rec = tmp_path / "BENCH_r09.json"
-    rec.write_text(json.dumps({"parsed": {
-        "metric": "kmeans_iter_per_sec", "value": 1000.0,
-        "qr_svd_tall_skinny_ms": 100.0, "kmedians_iter_per_sec": 50.0,
-    }}))
-    monkeypatch.setattr(bench.glob, "glob", lambda pat: [str(rec)])
-
-    ok = bench.regression_check({
-        "metric": "kmeans_iter_per_sec", "value": 995.0,
-        "qr_svd_tall_skinny_ms": 105.0, "kmedians_iter_per_sec": 49.0,
-    })
-    assert ok == {}
-    bad = bench.regression_check({
-        "metric": "kmeans_iter_per_sec", "value": 500.0,   # halved rate
-        "qr_svd_tall_skinny_ms": 150.0,                    # 50% slower
-        "kmedians_iter_per_sec": 60.0,                     # improved: fine
-    })
-    assert set(bad) == {"kmeans_iter_per_sec", "qr_svd_tall_skinny_ms"}

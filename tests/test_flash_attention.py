@@ -1,6 +1,7 @@
 """flash_attention — the fused Pallas kernel, run through the Pallas
-interpreter on the CPU mesh (the real-TPU lowering is exercised by
-bench.py's attention headline), plus the fallback contract."""
+interpreter on the CPU mesh (the real-TPU lowering is compiled by
+tests/test_tpu_compile.py and run by chip_smoke.py), plus the fallback
+contract."""
 
 from __future__ import annotations
 
