@@ -30,6 +30,7 @@ otherwise) and ``chiprun_out/chip_smoke.jsonl`` (a copy of the phase lines).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import shutil
@@ -78,6 +79,27 @@ def check(value, limit, *, at_least: bool = False) -> dict:
     value = float(value)
     ok = bool(np.isfinite(value) and (value >= limit if at_least else value <= limit))
     return {"value": value, "limit": limit, "ok": ok}
+
+
+@contextlib.contextmanager
+def launch_spans(*sites):
+    """The launch spans the program records at ``sites`` while the block runs
+    (``heat_tpu.telemetry`` on for its length): the list fills as the block
+    ends.  A phase reads off them which of its program's forms ran."""
+    from heat_tpu import telemetry
+
+    was_on = telemetry.is_enabled()
+    telemetry.enable()
+    first = len(telemetry.events())
+    spans = []
+    try:
+        yield spans
+        spans += [
+            e for e in telemetry.events()[first:] if e["type"] == "span" and e["site"] in sites
+        ]
+    finally:
+        if not was_on:
+            telemetry.disable()
 
 
 def device_dtypes() -> dict:
@@ -295,7 +317,8 @@ def phase_cdist(seed: int, n: int, f: int, block: int):
 
     ht.random.seed(seed + 2)
     X = ht.random.randn(n, f, split=0)
-    D = ht.spatial.cdist(X, X)
+    with launch_spans("jitted:dist.euclidean") as spans:
+        D = ht.spatial.cdist(X, X)
     block = min(block, n)
     lo = int(np.random.default_rng(seed).integers(0, n - block + 1))
     got = D[lo:lo + block].numpy()
@@ -303,14 +326,16 @@ def phase_cdist(seed: int, n: int, f: int, block: int):
     rows = host[lo:lo + block]
     d2 = (rows * rows).sum(1)[:, None] + (host * host).sum(1)[None, :] - 2.0 * rows @ host.T
     ref = np.sqrt(np.maximum(d2, 0.0))
-    # the default cdist is the exact broadcast form in f32: 1e-4 absolute on
-    # distances of size ~6 (a row to itself is exactly 0 on the chip)
+    # the default cdist is the exact form in f32, at 18 features unrolled over
+    # them (one pass): 1e-4 absolute on distances of size ~6 (a row to itself
+    # is exactly 0 on the chip)
     checks = {
         "block_abs": check(np.abs(got - ref).max(), 1e-4),
         "diagonal_abs": check(np.abs(np.diagonal(got, lo)).max(), 0.0),
     }
     line = {
         "sizes": {"rows": n, "features": f, "result_bytes": n * n * 4},
+        "exact_form": sorted({e["form"] for e in spans}),
         "reference": f"numpy float64 on rows [{lo}, {lo + block}) of the result "
                      f"(seeded block: the full result is {n * n * 4 / 1e9:.1f} GB)",
         "checks": checks, "device_dtypes": device_dtypes(),
@@ -336,7 +361,6 @@ def phase_spectral(seed: int, n: int, f: int, k: int, m: int):
     import jax.numpy as jnp
 
     import heat_tpu as ht
-    from heat_tpu import telemetry
     from heat_tpu.core.linalg import _symv
 
     spec = importlib.util.spec_from_file_location(
@@ -349,18 +373,9 @@ def phase_spectral(seed: int, n: int, f: int, k: int, m: int):
     centres = 0.35 * rng.standard_normal((k, f))
     host = (centres[np.arange(n) % k] + 0.15 * rng.standard_normal((n, f))).astype(np.float32)
     X = ht.array(host, split=0)
-    was_on = telemetry.is_enabled()
-    telemetry.enable()
-    first = len(telemetry.events())
-    try:
+    with launch_spans("jit:lanczos.start", "jit:lanczos.segment") as spans:
         sp = ht.cluster.Spectral(n_clusters=k, gamma=1.0, n_lanczos=m).fit(X)
-        routes = sorted({
-            e["matvec"] for e in telemetry.events()[first:]
-            if e["type"] == "span" and e["site"] in ("jit:lanczos.start", "jit:lanczos.segment")
-        })
-    finally:
-        if not was_on:
-            telemetry.disable()
+    routes = sorted({e["matvec"] for e in spans})
     out = {"labels": sp.labels_.larray, "embedding": sp.embedding_.larray, "eigenvalues": sp.eigenvalues_}
     numbers = plain.judge(jnp.asarray(host), out, k, 1.0, m)
     plain.forget()

@@ -150,7 +150,9 @@ def _named(fn: Callable, name: str) -> Callable:
     return named
 
 
-def jitted(key: Tuple, make_fn: Callable[[], Callable], jit_kwargs=None) -> Callable:
+def jitted(
+    key: Tuple, make_fn: Callable[[], Callable], jit_kwargs=None, fields=None
+) -> Callable:
     """Return a cached ``jax.jit`` of ``make_fn()`` memoized under ``key``.
 
     ``make_fn`` is only invoked on a cache miss; it should return a function
@@ -161,6 +163,11 @@ def jitted(key: Tuple, make_fn: Callable[[], Callable], jit_kwargs=None) -> Call
     form matters (the redistribution planner pins its output layout so it
     compares EQUAL to the monolithic reshard's).  The key must determine the
     kwargs, exactly as it determines the traced function.
+
+    ``fields`` (a dict, used only on a miss) joins every launch span of the
+    entry, to say which of a program's forms the key stands for
+    (``jitted:dist.euclidean`` carries ``form``); the key must determine it
+    too.
 
     The cached entry is a thin wrapper that goes through :func:`launch`: one
     device dispatch per eager invocation (see :mod:`heat_tpu.core._tracing`)
@@ -188,14 +195,15 @@ def jitted(key: Tuple, make_fn: Callable[[], Callable], jit_kwargs=None) -> Call
         jfn = jax.jit(made, **(jit_kwargs or {}))
         span_site = f"jitted:{site}"
         fresh = [True]  # the entry has not been called yet
+        fields = dict(fields or ())
 
         def fn(*args, _jfn=jfn, **kwargs):
             if _traced(args, kwargs):
                 return _jfn(*args, **kwargs)
             if fresh[0]:
                 fresh[0] = False
-                return launch(span_site, _jfn, args, kwargs, miss=True)
-            return launch(span_site, _jfn, args, kwargs)
+                return launch(span_site, _jfn, args, kwargs, miss=True, **fields)
+            return launch(span_site, _jfn, args, kwargs, **fields)
 
         fn.lower = jfn.lower  # HLO inspection passthrough (tests)
         fn.jitted = jfn

@@ -8,10 +8,14 @@ symmetry (:244-345).
 
 TPU-first formulation: the distance matrix is one global computation and
 GSPMD schedules the inter-shard movement; row-sharding of X propagates to
-row-sharding of D.  The default (``quadratic_expansion=False``) is the exact
-form ``sqrt(sum((x - y)²))`` on the vector units: one program, bound by the
-vector units and not by the write of D (``job_ms`` 87.2, ``roofline_pct``
-9.08 at 40 000 x 18; ledger, PR 29, ``cdist_40k_c1``).
+row-sharding of D (the programs here lay their result out so themselves:
+``_rows_of``).  The default (``quadratic_expansion=False``) is the exact form
+``sqrt(sum((x - y)²))`` on the vector units: one program and, at up to
+``_UNROLL_MAX_FEATURES`` features, one pass that writes D once
+(``_pairwise_sum``).  It is bound by the vector units' three operations a
+feature and pair, not by the write of D (see ledger, PR 31, ``cdist_40k_c1``;
+``job_ms`` 22.1 against 87.6 before, ``roofline_pct`` 37.0 at 40 000 x 18: my
+chip run, PR 31).
 ``quadratic_expansion=True`` is the MXU form ``|x|² + |y|² − 2xy``
 (reference :28-72 uses the same trick locally): one large matmul, paid for
 in cancellation error: at that size its largest difference to the exact
@@ -74,13 +78,61 @@ def _wrap(x: DNDarray, garr, dtype) -> DNDarray:
     return DNDarray(garr, tuple(garr.shape), dtype, split, x.device, x.comm, True)
 
 
+def _rows_of(x: DNDarray):
+    """The layout :func:`_wrap` gives the result where X lies by rows over
+    several devices (else ``None``), for the program to produce it so.  Left
+    to itself GSPMD lays the (n, m) result of two row-sharded operands out
+    through an all-to-all of n x m intermediates: 114 GB of temporaries a chip
+    at 80 000 x 18 on four chips, either form (``tests/test_tpu_compile.py``)."""
+    return x.comm.sharding(2, 0) if x.split == 0 and x.comm.size > 1 else None
+
+
+def _laid(d, rows):
+    return d if rows is None else jax.lax.with_sharding_constraint(d, rows)
+
+
+#: Most features the exact forms unroll.  One program at 40 000 rows on a TPU
+#: v5e, reduce / unrolled (my chip run, PR 31, ``PERF.md`` §6): 86.9 / 22.0 ms
+#: at 18 features, 98.4 / 39.3 at 32, 225.4 / 84.9 at 64 (9.8 s to compile),
+#: 362.3 / 158.3 at 96 (13.6 s); at 128 the unrolled form falls off a cliff,
+#: 428.2 / 1 201 ms, 33 MB of temporaries, 26 s to compile.  The compiler's own
+#: estimate (no chip) shows the same cliff: 128 M cycles at 64, 966 M at 128.
+_UNROLL_MAX_FEATURES = 64
+
+
+def _form(features: int) -> str:
+    """The loop order :func:`_pairwise_sum` takes at this feature count: the
+    launch spans of the exact forms carry it as their ``form`` field."""
+    return "unrolled" if 0 < features <= _UNROLL_MAX_FEATURES else "reduce"
+
+
+def _pairwise_sum(xa, ya, term):
+    """``sum_k term(xa[i, k] - ya[j, k])`` for every pair: (n, f), (m, f) ->
+    (n, m), every feature, in the operands' own precision.
+
+    One sum in one of two loop orders, chosen from the static feature count.
+    Few features (``_form``: ``unrolled``): feature by feature over the
+    (n, m) result, so the compiler makes each output tile in registers from
+    ``f`` subtract-``term``-adds on two broadcast vectors, fuses what the
+    caller does next (``sqrt``, ``exp``) into the same pass and writes the
+    result once.  Wide operands (``reduce``): the (n, m, f) broadcast reduced
+    over its minor axis, which the compiler pads to the tile width at few
+    features and finishes with a second pass over the result."""
+    if _form(xa.shape[1]) == "reduce":
+        return jnp.sum(term(xa[:, None, :] - ya[None, :, :]), axis=-1)
+    xt, yt = xa.T, ya.T
+    acc = term(xt[0][:, None] - yt[0][None, :])
+    for k in range(1, xa.shape[1]):
+        acc = acc + term(xt[k][:, None] - yt[k][None, :])
+    return acc
+
+
 def _euclidean(xa, ya, quadratic_expansion: bool):
     if quadratic_expansion:
         with jax.named_scope("cdist.quadratic"):
             return jnp.sqrt(quadratic_d2(xa, ya))
     with jax.named_scope("cdist.exact"):
-        diff = xa[:, None, :] - ya[None, :, :]
-        return jnp.sqrt(jnp.sum(diff * diff, axis=-1))
+        return jnp.sqrt(_pairwise_sum(xa, ya, jnp.square))
 
 
 from ..core._split_semantics import split_semantics as _split_semantics
@@ -114,9 +166,12 @@ def cdist(X: DNDarray, Y: Optional[DNDarray] = None, quadratic_expansion: bool =
     default, like the reference's torch.cdist.
     """
     xa, ya, dtype = _prep(X, Y)
+    form = None if quadratic_expansion else _form(xa.shape[1])
+    rows = _rows_of(X)
     fn = jitted(
-        ("dist.euclidean", quadratic_expansion),
-        lambda: lambda a, b: _euclidean(a, b, quadratic_expansion),
+        ("dist.euclidean", quadratic_expansion, form, rows),
+        lambda: lambda a, b: _laid(_euclidean(a, b, quadratic_expansion), rows),
+        fields=form and {"form": form},
     )
     return _wrap(X, fn(xa, ya), dtype)
 
@@ -137,6 +192,8 @@ def rbf(
     set otherwise), not the one bf16 pass ``cdist``'s expansion runs."""
     xa, ya, dtype = _prep(X, Y)
     precision = _linalg._precision() if quadratic_expansion else None
+    form = None if quadratic_expansion else _form(xa.shape[1])
+    rows = _rows_of(X)
 
     def _make():
         def _rbf(a, b, sig):
@@ -145,13 +202,16 @@ def rbf(
                     d2 = quadratic_d2(a, b, precision)
             else:
                 with jax.named_scope("rbf.exact"):
-                    diff = a[:, None, :] - b[None, :, :]
-                    d2 = jnp.sum(diff * diff, axis=-1)
-            return jnp.exp(-d2 / (2.0 * sig * sig))
+                    d2 = _pairwise_sum(a, b, jnp.square)
+            return _laid(jnp.exp(-d2 / (2.0 * sig * sig)), rows)
 
         return _rbf
 
-    fn = jitted(("dist.rbf", quadratic_expansion, precision), _make)
+    fn = jitted(
+        ("dist.rbf", quadratic_expansion, precision, form, rows),
+        _make,
+        fields=form and {"form": form},
+    )
     return _wrap(X, fn(xa, ya, jnp.asarray(sigma, xa.dtype)), dtype)
 
 
@@ -160,8 +220,11 @@ def manhattan(X: DNDarray, Y: Optional[DNDarray] = None, expand: bool = False) -
     """Pairwise L1 distances (reference distance.py:180-186)."""
     xa, ya, dtype = _prep(X, Y)
     del expand  # accepted for API parity; one formulation here
+    form = _form(xa.shape[1])
+    rows = _rows_of(X)
     fn = jitted(
-        ("dist.manhattan",),
-        lambda: lambda a, b: jnp.sum(jnp.abs(a[:, None, :] - b[None, :, :]), axis=-1),
+        ("dist.manhattan", form, rows),
+        lambda: lambda a, b: _laid(_pairwise_sum(a, b, jnp.abs), rows),
+        fields={"form": form},
     )
     return _wrap(X, fn(xa, ya), dtype)

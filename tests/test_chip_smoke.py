@@ -105,6 +105,12 @@ def test_spectral_phase_says_which_matvec_route_its_fit_took():
     }
 
 
+@pytest.mark.parametrize("f,form", [(18, "unrolled"), (65, "reduce")])
+def test_cdist_phase_says_which_form_its_program_took(f, form):
+    line, _ = chip_smoke.phase_cdist(SEED, n=256, f=f, block=32)
+    assert line["exact_form"] == [form] and _failed(line) == []
+
+
 def test_io_phase_round_trip_under_the_given_directory(tmp_path):
     line, _ = chip_smoke.phase_io(SEED, n=1024, f=32, work=str(tmp_path))
     _complete(line)

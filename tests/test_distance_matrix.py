@@ -8,11 +8,14 @@ through one GSPMD plan."""
 
 from __future__ import annotations
 
+import jax
 import numpy as np
 import pytest
 from scipy.spatial.distance import cdist as scipy_cdist
 
 import heat_tpu as ht
+from heat_tpu import telemetry
+from heat_tpu.spatial import distance
 
 RNG = np.random.default_rng(31)
 A = RNG.normal(size=(11, 3)).astype(np.float32)  # 11, 7: ragged on 2/4/7/8
@@ -84,3 +87,59 @@ def test_big_ragged_cdist_matches():
     d = ht.spatial.cdist(ht.array(x, split=0), ht.array(y, split=0))
     np.testing.assert_allclose(d.numpy(), scipy_cdist(x, y), atol=5e-3)
     assert d.split == 0
+
+
+@pytest.fixture
+def tel():
+    """``telemetry.enable()`` around one test, the record empty at both ends."""
+    was = telemetry.is_enabled()
+    telemetry.enable()
+    telemetry.reset()
+    yield telemetry
+    telemetry.reset()
+    if not was:
+        telemetry.disable()
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("with_y", [False, True])
+@pytest.mark.parametrize("split", [None, 0, 1])
+@pytest.mark.parametrize("f", [1, 3, 18, 64, 65])
+def test_exact_form_in_either_loop_order(f, split, with_y, dtype, tel, monkeypatch):
+    """The exact form's two loop orders (``distance._pairwise_sum``) are one
+    sum: unrolled over the features up to ``_UNROLL_MAX_FEATURES`` of them,
+    reduced over the broadcast beyond; both agree with each other and with
+    scipy to rounding, every pair, and the launch span says which ran."""
+    if dtype is np.float64 and not jax.config.jax_enable_x64:
+        pytest.skip("x64 is off")
+    x = RNG.normal(size=(37, f)).astype(dtype)  # 37, 23: n != m, ragged on 2/4/8
+    y = RNG.normal(size=(23, f)).astype(dtype) if with_y else None
+    X = ht.array(x, split=split)
+    Y = ht.array(y, split=split) if with_y else None
+    want = scipy_cdist(x.astype(np.float64), (y if with_y else x).astype(np.float64))
+    eps = np.finfo(dtype).eps
+    tol = 4 * eps * np.sqrt(f) * want.max()  # sqrt of a sum of f rounded squares
+
+    def launched():
+        d = ht.spatial.cdist(X, Y)
+        (span,) = [e for e in tel.events() if e.get("site") == "jitted:dist.euclidean"]
+        tel.reset()
+        assert d.split == (0 if split == 0 else None) and d.gshape == want.shape
+        assert d.dtype == ht.types.canonical_heat_type(dtype)
+        return d.numpy(), span["form"]
+
+    taken, form = launched()
+    assert form == ("reduce" if f == 65 else "unrolled") == distance._form(f)
+    monkeypatch.setattr(distance, "_UNROLL_MAX_FEATURES", 0)  # the reduce, whatever the width
+    reduced, form = launched()
+    assert form == "reduce"
+    np.testing.assert_allclose(taken, reduced, rtol=0, atol=tol)
+    np.testing.assert_allclose(taken, want, rtol=0, atol=tol)
+    if not with_y:  # x - x is exactly 0 feature by feature, in either order
+        assert not np.diag(taken).any() and not np.diag(reduced).any()
+    monkeypatch.undo()
+
+    inlined = ht.fuse(ht.spatial.cdist)(X, Y).numpy()
+    # inside the fused program the call is no launch and no entry of its own
+    assert not {"jitted:dist.euclidean", "spatial:cdist"} & {e.get("site") for e in tel.events()}
+    np.testing.assert_allclose(inlined, taken, rtol=0, atol=tol)

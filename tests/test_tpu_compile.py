@@ -279,20 +279,75 @@ def test_kmeanspp_compiles_at_the_sources_1200_rows_on_four_chips(four_chips):
     assert m.temp_size_in_bytes < 1 << 30
 
 
+def _cdist_entry(quadratic: bool, rows=None):
+    """The cached program ``ht.spatial.cdist`` issues at 18 features: for an
+    operand on one device, or (``rows``) for one laid out by rows."""
+    from heat_tpu.core import _compile
+
+    return next(  # the key: site, expansion or not, form, the result's layout
+        fn for k, fn in _compile._CACHE.items() if k[:2] == ("dist.euclidean", quadratic) and k[3] == rows
+    )
+
+
+def _entry_instructions(compiled):
+    """``(name, shape, opcode)`` of the entry computation's instructions."""
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    return re.findall(r"^\s*(?:ROOT )?%?(\S+) = (\S+?)(?:\{\S*)? ([\w-]+)\(", entry, re.M)
+
+
 def test_cdist_compiles_under_its_sites_name_with_its_scope(one_chip):
     """``cdist_40k_c1``: the ``jitted`` entry is named after its key's site,
     so the device's ``XLA Modules`` line reads ``jit_dist.euclidean`` where
-    it read ``jit__lambda_``."""
-    from heat_tpu.core._compile import jitted
-    from heat_tpu.spatial import distance
-
+    it read ``jit__lambda_``.  The exact form at 18 features is ONE pass:
+    one fusion makes the 6.4 GB result, ``sqrt`` inside it (the reduce over
+    the padded feature axis was followed by a second pass for the ``sqrt``)."""
     x = _shape((40_000, 18), one_chip)
+    small = ht.array(jnp.zeros((8, 18), jnp.float32))
     for quadratic, scope in ((False, "cdist.exact"), (True, "cdist.quadratic")):
-        fn = jitted(
-            ("dist.euclidean", quadratic),
-            lambda: lambda a, b: distance._euclidean(a, b, quadratic),
-        )
-        _assert_scopes(fn.lower(x, x).compile(), "jit_dist.euclidean", [scope])
+        ht.spatial.cdist(small, quadratic_expansion=quadratic)  # makes the cached entry
+        compiled = _cdist_entry(quadratic).lower(x, x).compile()
+        _assert_scopes(compiled, "jit_dist.euclidean", [scope])
+        if quadratic:
+            continue
+        whole = [(name, op) for name, shape, op in _entry_instructions(compiled) if shape == "f32[40000,40000]"]
+        assert [op for _, op in whole] == ["fusion"], whole
+        assert "sqrt" in whole[0][0] and "reduce" not in whole[0][0], whole
+        assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_cdist_on_rows_over_four_chips_writes_each_chips_rows_and_moves_no_n_by_n(four_chips):
+    """80 000 x 18 by rows over the described 2x2, through the public entry
+    (traced over a described operand to make its cached program: no array can
+    lie on a described device).  The program lays its result out by rows
+    itself; left to GSPMD, either form moves n x n intermediates through an
+    all-to-all and needs 114 GB a chip."""
+    from heat_tpu.core.dndarray import DNDarray
+
+    comm, n = four_chips, 80_000
+    rows = comm.sharding(2, 0)
+
+    def call(a):
+        X = DNDarray(a, tuple(a.shape), ht.float32, 0, ht.get_device(), comm, True)
+        return ht.spatial.cdist(X).larray
+
+    jax.eval_shape(call, _shape((64, 18), rows))
+    x = _shape((n, 18), rows)
+    compiled = _cdist_entry(False, rows).lower(x, x).compile()
+    assert compiled.output_shardings.is_equivalent_to(rows, 2)
+    text = compiled.as_text()
+    whole = [(name, op) for name, shape, op in _entry_instructions(compiled) if shape == f"f32[{n // 4},{n}]"]
+    assert [op for _, op in whole] == ["fusion"] and "sqrt" in whole[0][0], whole
+    # Y's 18 columns are assembled on every chip; nothing of D's size moves
+    moved = [
+        line[: found.start()]  # "%name = <shape, or a tuple of shapes>"
+        for line in text.splitlines()
+        if (found := re.search(r" " + _COLLECTIVE + r"(-start)?\(", line))
+    ]
+    assert moved, "Y has to reach every chip"
+    for shapes in moved:
+        for dims in re.findall(r"f32\[([\d,]*)\]", shapes):
+            assert math.prod(int(d) for d in dims.split(",") if d) <= 18 * n, shapes[:200]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
 
 
 # --------------------------------------------------------------------- #
@@ -319,10 +374,10 @@ def test_rbf_compiles_at_the_cells_size_with_its_scope(one_chip, quadratic, scop
     that size beside the result."""
     from heat_tpu.core import _compile
 
-    x = ht.array(jnp.zeros((8, SPEC_F), jnp.float32), split=0)
+    x = ht.array(jnp.zeros((8, SPEC_F), jnp.float32))  # not split: the program of one device
     ht.spatial.rbf(x, sigma=1.0, quadratic_expansion=quadratic)  # makes the cached entry
     key = ("dist.rbf", quadratic, "highest" if quadratic else None)
-    entry = next(fn for k, fn in _compile._CACHE.items() if k[:3] == key)
+    entry = next(fn for k, fn in _compile._CACHE.items() if k[:3] == key and k[4] is None)
     shape = _shape((SPEC_N, SPEC_F), one_chip)
     compiled = entry.lower(shape, shape, _shape((), one_chip)).compile()
     _assert_scopes(compiled, "jit_dist.rbf", [scope])
