@@ -2,8 +2,10 @@
 statistical_moments/heat-cpu.py — mean/std along axis 0, 10 trials).
 
 A port of the reference's harness script, kept as the origin of the
-benchmark's data and settings.  It prints wall time on whatever device it
-runs on: under ``--devices N`` (a virtual CPU mesh) that checks the
+benchmark's data and settings: ``perf/configs/moments-cityscapes-1chip.json``
+(cell ``moments_300_c1``) cites it for the job, ``ht.mean`` then ``ht.std``
+along axis 0 of a ``split=0`` array.  It prints wall time on whatever device
+it runs on: under ``--devices N`` (a virtual CPU mesh) that checks the
 distributed code path and is no rate.  The repo's benchmark is
 ``BENCHMARK.json`` + ``perf/``; its numbers are in ``PERF_LEDGER.jsonl``.
 """
@@ -44,7 +46,9 @@ def main():
         s.larray.block_until_ready()
         times.append(time.perf_counter() - t0)
     best = min(times)
-    gb = x.nbytes * 2 / 1e9  # two passes over the data
+    # one read of the data for each of the two calls is what the job requires;
+    # the code reads it three times (ht.std makes its mean in a pass of its own)
+    gb = x.nbytes * 2 / 1e9
     print(f"moments: n={args.n} f={args.f} best={best:.4f}s → {gb / best:.2f} GB/s")
 
 

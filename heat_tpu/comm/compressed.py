@@ -808,6 +808,7 @@ def reduce_q(
     mean_n: Optional[int] = None,
     out_dtype=None,
     block: Optional[int] = None,
+    fields: Optional[dict] = None,
 ):
     """Compressed engine for ``sum``/``mean`` over axes covering the split.
 
@@ -816,6 +817,7 @@ def reduce_q(
     is exact and the cross-device combine rides the compressed ring.
     ``mean_n`` (the TRUE element count, pads excluded) turns the sum into
     a mean.  One compiled dispatch; result comes back replicated.
+    ``fields`` joins the launch spans of the entry (:func:`jitted`).
     """
     p = comm.size
     mesh, name = comm._mesh, comm.axis_name
@@ -844,7 +846,7 @@ def reduce_q(
         return _f
 
     key = ("commq.reduce", comm, mode, blk, split, axes, keepdims, mean_n, shape, dt, odt.name)
-    return jitted(key, make)(buffer)
+    return jitted(key, make, fields=fields)(buffer)
 
 
 def moments_q(
@@ -861,6 +863,7 @@ def moments_q(
     finalize: str = "var",
     out_dtype=None,
     block: Optional[int] = None,
+    fields: Optional[dict] = None,
 ):
     """Compressed var/std engine with CENTERED second moments.
 
@@ -920,7 +923,7 @@ def moments_q(
         "commq.moments", comm, mode, blk, split, axes, keepdims, true_n,
         split_valid, ddof, finalize, shape, dt, odt.name,
     )
-    return jitted(key, make)(buffer)
+    return jitted(key, make, fields=fields)(buffer)
 
 
 def class_moments_q(arr, member, *, comm, mode: str, block: Optional[int] = None):

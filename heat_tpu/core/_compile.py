@@ -34,6 +34,7 @@ from ._tracing import in_trace, record_dispatch
 _tel.install_profiler(jax.profiler.TraceAnnotation)
 
 __all__ = [
+    "entry",
     "jitted",
     "launch",
     "cache_stable",
@@ -130,6 +131,24 @@ def launch(site: str, fn: Callable, args: Tuple = (), kwargs=None, kind: str = "
         with _tel.span(site, kind, **fields):
             return fn(*args, **kwargs)
     return fn(*args, **kwargs)
+
+
+def entry(site: str):
+    """A public entry as a span of kind ``entry`` (one predicate a call when
+    nothing records).  Inside an ``ht.fuse`` trace the call inlines into the
+    surrounding program and is no entry of its own."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            if in_trace() or not _tel.recording():
+                return fn(*args, **kwargs)
+            with _tel.span(site, "entry"):
+                return fn(*args, **kwargs)
+
+        return wrapper
+
+    return deco
 
 
 def _named(fn: Callable, name: str) -> Callable:

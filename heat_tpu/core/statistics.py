@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from . import _operations, factories, types
-from ._compile import jitted
+from ._compile import entry as _entry, jitted
 from .dndarray import DNDarray
 from .fuse import fuse
 from .sanitation import merge_keepdims, sanitize_in
@@ -162,15 +162,16 @@ def _compressed_moment(x: DNDarray, axis, keepdims: bool, kind: str, ddof: int =
     true_n = 1
     for a in axes:
         true_n *= int(x.gshape[a])
+    fields = {"route": "compressed", "axis": axes}  # axes, as the engines' keys have them
     if kind == "mean":
         return _cq.reduce_q(
             buf, comm=x.comm, split=x.split, axes=axes, keepdims=keepdims,
-            mode=mode, mean_n=true_n, out_dtype=buf.dtype,
+            mode=mode, mean_n=true_n, out_dtype=buf.dtype, fields=fields,
         )
     return _cq.moments_q(
         buf, comm=x.comm, split=x.split, axes=axes, keepdims=keepdims,
         mode=mode, true_n=true_n, split_valid=int(x.gshape[x.split]),
-        ddof=ddof, finalize=kind, out_dtype=buf.dtype,
+        ddof=ddof, finalize=kind, out_dtype=buf.dtype, fields=fields,
     )
 
 
@@ -344,6 +345,13 @@ def maximum(x1, x2, out=None):
     return _operations.__binary_op(jnp.maximum, x1, x2, out)
 
 
+def _mean(a, axis, keepdims):
+    """The program of :func:`mean`: one read of the operand."""
+    with jax.named_scope("stat.mean"):
+        return jnp.mean(a, axis=axis, keepdims=keepdims)
+
+
+@_entry("stat:mean")
 def mean(x, axis=None, keepdims=None, keepdim=None):
     """Arithmetic mean (reference statistics.py:728-869; cross-shard moment
     combination is XLA's).  ``axis`` may be an int or a tuple of ints;
@@ -358,9 +366,8 @@ def mean(x, axis=None, keepdims=None, keepdim=None):
     if res is None:
         fn = jitted(
             ("stat.mean", axis, cast, keepdims),
-            lambda: lambda a: jnp.mean(
-                a.astype(cast) if cast else a, axis=axis, keepdims=keepdims
-            ),
+            lambda: lambda a: _mean(a.astype(cast) if cast else a, axis, keepdims),
+            fields={"reads": 1, "route": "exact", "axis": axis},
         )
         res = fn(x.larray)
     return _wrap_reduced(x, res, axis, keepdims=keepdims)
@@ -530,6 +537,20 @@ def _interp_sorted(svals, qa, method: str):
     return res
 
 
+def _var(a, axis, ddof, keepdims):
+    """The program of :func:`var` and :func:`std`: ``jnp.var``'s own
+    arithmetic with its mean made apart, so that each of its two reads of the
+    operand carries a scope of its own.  The mean is taken off before the
+    squares are summed (the raw form ``E[x**2] - mean**2`` cancels where a
+    mean is large beside its deviation)."""
+    # jnp.var works on 16-bit floats in float32: its mean is made so too
+    wide = jnp.float32 if a.dtype.itemsize < 4 else a.dtype
+    with jax.named_scope("stat.var.mean"):
+        mu = jnp.mean(a, axis=axis, dtype=wide, keepdims=True)
+    with jax.named_scope("stat.var.centred"):
+        return jnp.var(a, axis=axis, ddof=ddof, keepdims=keepdims, mean=mu)
+
+
 def _moment2(x, axis, ddof, kwargs, name, finalize):
     """Shared var/std engine: ddof/bessel semantics + one fused executable
     (``finalize`` is identity for var, sqrt for std)."""
@@ -549,14 +570,14 @@ def _moment2(x, axis, ddof, kwargs, name, finalize):
     if res is None:
         fn = jitted(
             ("stat.moment2", name, axis, ddof, cast, keepdims),
-            lambda: lambda a: finalize(
-                jnp.var(a.astype(cast) if cast else a, axis=axis, ddof=ddof, keepdims=keepdims)
-            ),
+            lambda: lambda a: finalize(_var(a.astype(cast) if cast else a, axis, ddof, keepdims)),
+            fields={"reads": 2, "route": "exact", "axis": axis},
         )
         res = fn(x.larray)
     return _wrap_reduced(x, res, axis, keepdims=keepdims)
 
 
+@_entry("stat:std")
 def std(x, axis=None, ddof: int = 0, **kwargs):
     """Standard deviation (reference statistics.py:1466-1558) — one fused
     sqrt(var) executable rather than two dispatches.  Accepts numpy's
@@ -564,6 +585,7 @@ def std(x, axis=None, ddof: int = 0, **kwargs):
     return _moment2(x, axis, ddof, kwargs, "stat.std", jnp.sqrt)
 
 
+@_entry("stat:var")
 def var(x, axis=None, ddof: int = 0, **kwargs):
     """Variance with ddof semantics (reference statistics.py:1559-1705;
     single-pass merged moments are XLA's reduction plan).

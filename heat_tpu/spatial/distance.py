@@ -25,19 +25,16 @@ form reads 0.535 where the cell allows 5e-5 (``dist_err_all``, ``PERF.md``
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..core import types
-from ..core._compile import jitted
-from ..core._tracing import in_trace
+from ..core._compile import entry as _entry, jitted
 from ..core.dndarray import DNDarray
 from ..core.linalg import basics as _linalg
 from ..core.sanitation import sanitize_in
-from ..telemetry import _core as _tel
 
 __all__ = ["cdist", "manhattan", "rbf", "quadratic_d2"]
 
@@ -136,24 +133,6 @@ def _euclidean(xa, ya, quadratic_expansion: bool):
 
 
 from ..core._split_semantics import split_semantics as _split_semantics
-
-
-def _entry(site: str):
-    """A public entry as a span of kind ``entry`` (one predicate a call when
-    nothing records).  Inside an ``ht.fuse`` trace the call inlines into the
-    surrounding program and is no entry of its own."""
-
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            if in_trace() or not _tel.recording():
-                return fn(*args, **kwargs)
-            with _tel.span(site, "entry"):
-                return fn(*args, **kwargs)
-
-        return wrapper
-
-    return deco
 
 
 @_split_semantics("entry_split0")

@@ -495,3 +495,55 @@ def test_lanczos_start_and_the_embedding_compile(one_chip, on_one_chip):
         _shape((SPEC_N, SPEC_M), one_chip), _shape((SPEC_M, SPEC_K), one_chip)
     ).compile()
     assert any("/spectral.embed/" in n for n in _op_names(embed))
+
+
+# --------------------------------------------------------------------- #
+# moments_300_c1: the two programs of ht.mean and ht.std                 #
+# --------------------------------------------------------------------- #
+MOM_ROWS, MOM_F = 300, 6_291_456  # CELL_F: the cityscapes row
+
+
+def _reads_of_the_operand(compiled) -> int:
+    """How many instructions of the compiled entry computation take its
+    parameter: each is one pass over the operand, whatever is fused into it."""
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    (param,) = re.findall(r"^\s*%?(\S+) = \S+ parameter\(0\)", entry, re.M)
+    return len(re.findall(r"^\s*(?:ROOT )?\S+ = \S+ [\w-]+\((?:[^\n]*, )?%?" + re.escape(param) + r"[,)]", entry, re.M))
+
+
+@pytest.mark.parametrize(
+    "call,site,scopes",
+    [
+        ("mean", "stat.mean", ["stat.mean"]),
+        ("std", "stat.moment2", ["stat.var.mean", "stat.var.centred"]),
+    ],
+)
+def test_moments_compile_at_the_cells_size_and_read_the_operand_as_their_field_says(one_chip, call, site, scopes):
+    """``ht.mean`` / ``ht.std`` along axis 0 of 300 x 6 291 456 float32 on one
+    chip: the 7.55 GB operand (304 rows with the tile's padding) and nothing
+    of its size beside it (no centred copy), each read under its scope, and
+    as many reads as the launch span's ``reads`` field says."""
+    from heat_tpu import telemetry
+    from heat_tpu.core import _compile
+
+    was = telemetry.is_enabled()
+    telemetry.enable()
+    try:
+        getattr(ht, call)(ht.array(jnp.zeros((8, 16), jnp.float32), split=0), axis=0)  # makes the cached entry
+        span = [e for e in telemetry.events() if e.get("site") == f"jitted:{site}"][-1]
+    finally:
+        if not was:
+            telemetry.disable()
+    # the keys: (site, axis, cast, keepdims) and (site, name, axis, ddof, cast, keepdims)
+    key = {"mean": ("stat.mean", 0, None, False), "std": ("stat.moment2", "stat.std", 0, 0, None, False)}[call]
+    entry = next(fn for k, fn in _compile._CACHE.items() if k[: len(key)] == key)
+    compiled = entry.lower(_shape((MOM_ROWS, MOM_F), one_chip)).compile()
+    _assert_scopes(compiled, f"jit_{site}", scopes)
+    m = compiled.memory_analysis()
+    assert 4 * MOM_ROWS * MOM_F <= m.argument_size_in_bytes < 7.7e9
+    assert m.temp_size_in_bytes < 1 << 26 and m.output_size_in_bytes == 4 * MOM_F
+    _fits_the_chip(compiled, site)
+    reads = _reads_of_the_operand(compiled)
+    assert reads == span["reads"] == {"mean": 1, "std": 2}[call]
+    # the compiler's own count of the operand's bytes says the same
+    assert round(compiled.cost_analysis()["bytes accessed0{}"] / m.argument_size_in_bytes) == reads
