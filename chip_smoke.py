@@ -47,7 +47,7 @@ LINES = os.path.join(ROOT, "chiprun_out", "chip_smoke.jsonl")
 #: full sizes: the reference's own harness settings (BASELINE.md) scaled to
 #: fill a useful part of one 16 GB chip
 FULL = {
-    "moments": dict(n=8_000_000, f=32),
+    "moments": dict(n=8_000_000, f=32, wide=(300, 1_048_576)),
     "kmeans": dict(k=8, iters=30, n_ref=1_000_000),
     "cdist": dict(n=40_000, f=18, block=512),
     "spectral": dict(n=8_192, f=18, k=8, m=300),
@@ -172,16 +172,22 @@ def timed(counter: CompileCounter, name: str, fn, *args, **kwargs):
 # --------------------------------------------------------------------- #
 # one-chip phases                                                        #
 # --------------------------------------------------------------------- #
-def phase_moments(seed: int, n: int, f: int):
+def phase_moments(seed: int, n: int, f: int, wide):
+    """Moments of the tall ``n x f`` array every later phase uses, and the
+    deviation of a ``wide`` one (rows, columns): the operand whose variance one
+    chip makes from one read (``core/_colvar.py``); the line says which form
+    each took."""
     import heat_tpu as ht
+    from heat_tpu.core import _colvar
 
     ht.random.seed(seed)
     X = ht.random.randn(n, f, split=0)
-    got = {
-        "mean0": ht.mean(X, axis=0).numpy(), "std0": ht.std(X, axis=0).numpy(),
-        "var0": ht.var(X, axis=0).numpy(),
-        "mean": float(ht.mean(X)), "std": float(ht.std(X)), "var": float(ht.var(X)),
-    }
+    with launch_spans("jitted:stat.moment2") as tall_spans:
+        got = {
+            "mean0": ht.mean(X, axis=0).numpy(), "std0": ht.std(X, axis=0).numpy(),
+            "var0": ht.var(X, axis=0).numpy(),
+            "mean": float(ht.mean(X)), "std": float(ht.std(X)), "var": float(ht.var(X)),
+        }
     host = X.numpy()
     s1 = np.zeros(f)
     s2 = np.zeros(f)
@@ -192,8 +198,16 @@ def phase_moments(seed: int, n: int, f: int):
     var0 = s2 / n - mean0 * mean0
     mean = s1.sum() / (n * f)
     var = s2.sum() / (n * f) - mean * mean
+    # a far mean on the wide operand: 100 deviations, where the raw form in
+    # float32 has lost four of its seven digits
+    W = ht.random.randn(*wide, split=0) + 100.0
+    with launch_spans("jitted:stat.moment2") as wide_spans:
+        wide_std, wide_var1 = ht.std(W, axis=0).numpy(), ht.var(W, axis=0, ddof=1).numpy()
+    wide64 = W.numpy().astype(np.float64)
+    del W
     # f32 sums of n standard-normal values against float64: 1e-4 absolute on a
-    # mean (whose own size is ~1/sqrt(n)), 1e-4 relative on spread
+    # mean (whose own size is ~1/sqrt(n)), 1e-4 relative on spread; the wide
+    # operand's few hundred rows by the limit of tests/test_moments_reference.py
     checks = {
         "mean_axis0_abs": check(np.abs(got["mean0"] - mean0).max(), 1e-4),
         "var_axis0_rel": check(np.abs(got["var0"] / var0 - 1).max(), 1e-4),
@@ -201,9 +215,13 @@ def phase_moments(seed: int, n: int, f: int):
         "mean_abs": check(abs(got["mean"] - mean), 1e-4),
         "var_rel": check(abs(got["var"] / var - 1), 1e-4),
         "std_rel": check(abs(got["std"] / np.sqrt(var) - 1), 1e-4),
+        "wide_std_axis0_rel": check(np.abs(wide_std / wide64.std(0) - 1).max(), 2e-5),
+        "wide_var_ddof1_axis0_rel": check(np.abs(wide_var1 / wide64.var(0, ddof=1) - 1).max(), 4e-5),
     }
     line = {
-        "sizes": {"rows": n, "features": f, "bytes": n * f * 4},
+        "sizes": {"rows": n, "features": f, "bytes": n * f * 4, "wide": list(wide), "wide_bytes": wide[0] * wide[1] * 4},
+        "variance_form": {"tall": sorted({e["form"] for e in tall_spans}), "wide": sorted({e["form"] for e in wide_spans}),
+                          "kernel_from_bytes": _colvar.MIN_BYTES, "kernel_from_columns": _colvar.MIN_TILE},
         "reference": "numpy float64 on the same data, full size",
         "checks": checks, "device_dtypes": device_dtypes(),
     }

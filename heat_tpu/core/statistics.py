@@ -3,10 +3,13 @@
 Reference: heat/core/statistics.py:41-1705.  The reference's hardest
 machinery — custom MPI reduction ops over packed (value‖index) buffers for
 ``argmax``/``argmin`` (:1124-1168) and Bennett-style pairwise moment merging
-for ``mean``/``var``/``skew``/``kurtosis`` (:870-945) — is exactly what XLA's
-reduction lowering performs natively (variadic reduce with value/index
-pairs; tree reductions over shards), so every function here is its jnp
-formulation plus the reference's split/keepdims/ddof semantics.
+for ``mean``/``var``/``skew``/``kurtosis`` (:870-945) — is left to XLA's
+reduction lowering (variadic reduce with value/index pairs; tree reductions
+over shards; the centred moments as a mean pass and a pass over the centred
+values), so every function here is its jnp formulation plus the reference's
+split/keepdims/ddof semantics.  One exception: the variance of a wide float32
+matrix along axis 0 on one TPU reads its operand once (:func:`_var`,
+:mod:`._colvar`).
 """
 
 from __future__ import annotations
@@ -18,7 +21,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import _operations, factories, types
+from . import _colvar, _operations, factories, types
 from ._compile import entry as _entry, jitted
 from .dndarray import DNDarray
 from .fuse import fuse
@@ -537,12 +540,34 @@ def _interp_sorted(svals, qa, method: str):
     return res
 
 
+def _form(a, axis) -> str:
+    """How :func:`_var` makes the variance of ``a`` along ``axis``, by how many
+    times it reads ``a``: ``one_pass`` where :func:`_colvar.conforms` holds,
+    ``two_pass`` for every other operand.  The launch spans of ``var`` and
+    ``std`` carry it as their ``form`` field."""
+    return "one_pass" if _colvar.conforms(a, axis) else "two_pass"
+
+
 def _var(a, axis, ddof, keepdims):
-    """The program of :func:`var` and :func:`std`: ``jnp.var``'s own
-    arithmetic with its mean made apart, so that each of its two reads of the
-    operand carries a scope of its own.  The mean is taken off before the
-    squares are summed (the raw form ``E[x**2] - mean**2`` cancels where a
-    mean is large beside its deviation)."""
+    """The program of :func:`var` and :func:`std`, in one of two forms chosen
+    from the operand alone (:func:`_form`); exact types are worked on in
+    float32, 16-bit floats summed in it.  In both the mean is taken off before
+    the squares are summed (the raw form ``E[x**2] - mean**2`` cancels where a
+    mean is large beside its deviation).
+
+    ``two_pass``: ``jnp.var``'s own arithmetic with its mean made apart, so
+    that each of its two reads of the operand carries a scope of its own.
+    ``one_pass`` (a float32 matrix reduced over its rows that fills a good
+    part of the one chip the process drives): the Pallas kernel of
+    :mod:`._colvar` keeps a tile of columns, all rows of it, on the chip
+    between the two sums, and reads the operand once."""
+    if _form(a, axis) == "one_pass":
+        with jax.named_scope("stat.var.onepass"):
+            m2 = _colvar.centred_squares(a, interpret=_colvar._interpret())
+        res = m2 / (a.shape[0] - ddof)  # 0 / 0 where no row is left, as jnp.var
+        return res[None, :] if keepdims else res
+    if not jnp.issubdtype(a.dtype, jnp.inexact):
+        a = a.astype(jnp.float32)
     # jnp.var works on 16-bit floats in float32: its mean is made so too
     wide = jnp.float32 if a.dtype.itemsize < 4 else a.dtype
     with jax.named_scope("stat.var.mean"):
@@ -563,32 +588,41 @@ def _moment2(x, axis, ddof, kwargs, name, finalize):
     keepdims = merge_keepdims(kwargs.pop("keepdims", None), kwargs.pop("keepdim", None))
     if kwargs:
         raise TypeError(f"unexpected keyword arguments: {sorted(kwargs)}")
-    cast = jnp.float32 if types.heat_type_is_exact(x.dtype) else None
     res = _compressed_moment(
         x, axis, keepdims, kind=("std" if name == "stat.std" else "var"), ddof=ddof
     )
     if res is None:
+        arr = x.larray
+        form = _form(arr, axis)
         fn = jitted(
-            ("stat.moment2", name, axis, ddof, cast, keepdims),
-            lambda: lambda a: finalize(_var(a.astype(cast) if cast else a, axis, ddof, keepdims)),
-            fields={"reads": 2, "route": "exact", "axis": axis},
+            ("stat.moment2", name, axis, ddof, form, keepdims),
+            lambda: lambda a: finalize(_var(a, axis, ddof, keepdims)),
+            fields={"reads": 1 if form == "one_pass" else 2, "form": form, "route": "exact", "axis": axis},
         )
-        res = fn(x.larray)
+        res = fn(arr)
     return _wrap_reduced(x, res, axis, keepdims=keepdims)
 
 
 @_entry("stat:std")
 def std(x, axis=None, ddof: int = 0, **kwargs):
     """Standard deviation (reference statistics.py:1466-1558) — one fused
-    sqrt(var) executable rather than two dispatches.  Accepts numpy's
-    ``keepdims`` and tuple axes like :func:`var`."""
+    sqrt(var) executable rather than two dispatches, its operand read as
+    :func:`var` reads it.  Accepts numpy's ``keepdims`` and tuple axes like
+    :func:`var`."""
     return _moment2(x, axis, ddof, kwargs, "stat.std", jnp.sqrt)
 
 
 @_entry("stat:var")
 def var(x, axis=None, ddof: int = 0, **kwargs):
-    """Variance with ddof semantics (reference statistics.py:1559-1705;
-    single-pass merged moments are XLA's reduction plan).
+    """Variance with ddof semantics (reference statistics.py:1559-1705).
+
+    The mean is taken off before the squares are summed, whatever the operand
+    (:func:`_var`).  That is two reads of the operand (the reference's merged
+    single-pass moments are not what XLA makes of ``jnp.var``), except for a
+    float32 matrix reduced over axis 0 that fills a good part of the one TPU
+    the process drives: there a kernel keeps a tile of columns on the chip
+    between the two sums and the operand is read once.  The launch span's
+    ``reads`` and ``form`` fields say which was compiled.
 
     Note: like the reference, ``ddof`` ∈ {0, 1} (bessel correction via
     ``bessel=True`` kwarg is also accepted)."""

@@ -32,3 +32,14 @@ import jax  # noqa: E402  (after the XLA_FLAGS setup above, by design)
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", _DEVICES)
 jax.config.update("jax_enable_compilation_cache", False)
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def one_tpu(monkeypatch):
+    """What the kernels' route predicates (``_symv.conforms``,
+    ``_colvar.conforms``) ask of the process, answered as a one-chip machine
+    would: a TPU backend with one device."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)

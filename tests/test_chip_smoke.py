@@ -53,7 +53,7 @@ def moments():
     ``test_procfleet.py`` or ``test_stream.py``."""
     gc.collect()
     inherited = chip_smoke.device_dtypes()
-    line, X = chip_smoke.phase_moments(SEED, n=4096, f=32)
+    line, X = chip_smoke.phase_moments(SEED, n=4096, f=32, wide=(300, 640))
     return line, X, inherited
 
 
@@ -67,6 +67,8 @@ def test_moments_phase(moments):
     _complete(line)
     assert _failed(line) == []
     assert X.shape == (4096, 32) and X.split == 0
+    # the CPU mesh: the one-read kernel is a one-TPU process's (tests/test_colvar.py)
+    assert line["variance_form"]["tall"] == line["variance_form"]["wide"] == ["two_pass"]
     # what the PHASE put on the device, whatever the worker held before it
     added = {k for k, v in line["device_dtypes"].items() if v > inherited.get(k, 0)}
     assert added == {"float32"}

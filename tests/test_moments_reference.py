@@ -6,7 +6,9 @@ The reference is the benchmark's (``perf/references/moments_plain.py``:
 ``moments_300_c1`` holds the chip's results to it at 300 x 6 291 456, this
 file holds small ones to it on the CPU mesh by the same four numbers.  Beside
 it: the variance is ``jnp.var``'s own arithmetic, bit for bit, with a scope
-on each of its two reads; the three entries and their launches are spans
+on each of its two reads (everywhere but on a wide float32 matrix on one TPU,
+whose one-read kernel ``tests/test_colvar.py`` holds to the same numbers);
+the three entries and their launches are spans
 while something records, with the fields the benchmark's
 ``operand_reads_per_job`` reads, and nothing inside an ``ht.fuse`` trace.
 (``tests/test_tpu_compile.py`` holds the ``reads`` field to the program
@@ -121,8 +123,9 @@ def test_the_judge_fails_what_the_cell_must_fail(plain):
 @pytest.mark.parametrize("axis", [None, 0, 1, (0, 1)])
 @pytest.mark.parametrize("ddof", [0, 1])
 def test_the_variance_is_jnp_vars_own_bit_for_bit(dtype, axis, ddof):
-    """``statistics._var`` makes ``jnp.var``'s mean apart (for the scope on
-    each read) and hands it back: the same operations in the same order."""
+    """``statistics._var`` in its two-pass form makes ``jnp.var``'s mean apart
+    (for the scope on each read) and hands it back: the same operations in the
+    same order."""
     host = np.random.default_rng(5).standard_normal((37, 24)) * 3.0 + 50.0
     a = jnp.asarray(host).astype(dtype)
     want = jax.jit(lambda v: jnp.var(v.astype(jnp.float32) if dtype is jnp.int32 else v, axis=axis, ddof=ddof))(a)
@@ -150,18 +153,20 @@ def _spans(tel):
     return [e for e in tel.events() if e.get("type") == "span"]
 
 
-#: public entry -> (its entry span, its launch span, the reads its program makes)
+#: public entry -> (its entry span, its launch span, the reads its program
+#: makes on this mesh, the form the launch names: ``two_pass`` wherever the
+#: one-read kernel does not conform, ``tests/test_colvar.py`` has the other)
 ENTRIES = {
-    "mean": ("stat:mean", "jitted:stat.mean", 1),
-    "var": ("stat:var", "jitted:stat.moment2", 2),
-    "std": ("stat:std", "jitted:stat.moment2", 2),
+    "mean": ("stat:mean", "jitted:stat.mean", 1, None),
+    "var": ("stat:var", "jitted:stat.moment2", 2, "two_pass"),
+    "std": ("stat:std", "jitted:stat.moment2", 2, "two_pass"),
 }
 
 
 @pytest.mark.parametrize("split", [None, 0])
 @pytest.mark.parametrize("name", sorted(ENTRIES))
 def test_an_entry_and_its_launch_are_recorded_with_their_fields(tel, name, split):
-    entry_site, launch_site, reads = ENTRIES[name]
+    entry_site, launch_site, reads, form = ENTRIES[name]
     X = ht.array(_blobs(6, 48, 16), split=split)
     for axis in (0, (0, 1)):
         tel.reset()
@@ -172,6 +177,7 @@ def test_an_entry_and_its_launch_are_recorded_with_their_fields(tel, name, split
         assert entry["site"] == entry_site and entry["launches"] == 1 and entry["syncs"] == 0
         assert launch["site"] == launch_site and launch["parent"] == entry["id"]
         assert (launch["reads"], launch["route"], launch["axis"]) == (reads, "exact", axis)
+        assert launch.get("form") == form
 
 
 def test_nothing_is_recorded_inside_a_fuse_trace_or_when_nothing_records(tel):

@@ -88,12 +88,7 @@ def test_the_tile_walk_is_the_block_triangle_row_major():
 # --------------------------------------------------------------------- #
 # the route                                                              #
 # --------------------------------------------------------------------- #
-@pytest.fixture
-def one_tpu(monkeypatch):
-    """What ``conforms`` asks of the process, answered as the cell's machine
-    would: a TPU backend with one device."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(jax, "device_count", lambda: 1)
+# ``one_tpu`` (a TPU backend with one device, as the cell's machine) is conftest.py's
 
 
 def _shape(n, dtype=jnp.float32, m=None):

@@ -416,6 +416,13 @@ def on_one_chip(on_the_chip, monkeypatch):
     monkeypatch.setattr(jax, "device_count", lambda: 1)
 
 
+def _granted_vmem(custom_call: str) -> int:
+    """The VMEM the compiler granted a Pallas kernel, off its custom call's
+    line (it refuses a kernel that needs more than it states)."""
+    (granted,) = re.findall(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"\}\]', custom_call)
+    return int(granted)
+
+
 def _assert_the_matvec_is_the_kernel(compiled) -> None:
     """The step's product with the operator is the Pallas kernel's custom
     call, granted exactly the VMEM the kernel states (the compiler refuses a
@@ -426,8 +433,7 @@ def _assert_the_matvec_is_the_kernel(compiled) -> None:
     matvec = [line for line in compiled.as_text().splitlines() if "/lanczos.matvec/" in line]
     calls = [line for line in matvec if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 1, [line[:200] for line in matvec]
-    (granted,) = re.findall(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"\}\]', calls[0])
-    assert int(granted) == _symv._VMEM_LIMIT <= 32 << 20
+    assert _granted_vmem(calls[0]) == _symv._VMEM_LIMIT <= 32 << 20
     assert f"f32[{SPEC_N},{SPEC_N}]" in calls[0], "the operator is not the kernel's own operand"
     for line in compiled.as_text().splitlines():
         if re.search(r"= \S+ (convolution|dot)\(", line):
@@ -511,39 +517,84 @@ def _reads_of_the_operand(compiled) -> int:
     return len(re.findall(r"^\s*(?:ROOT )?\S+ = \S+ [\w-]+\((?:[^\n]*, )?%?" + re.escape(param) + r"[,)]", entry, re.M))
 
 
-@pytest.mark.parametrize(
-    "call,site,scopes",
-    [
-        ("mean", "stat.mean", ["stat.mean"]),
-        ("std", "stat.moment2", ["stat.var.mean", "stat.var.centred"]),
-    ],
-)
-def test_moments_compile_at_the_cells_size_and_read_the_operand_as_their_field_says(one_chip, call, site, scopes):
-    """``ht.mean`` / ``ht.std`` along axis 0 of 300 x 6 291 456 float32 on one
-    chip: the 7.55 GB operand (304 rows with the tile's padding) and nothing
-    of its size beside it (no centred copy), each read under its scope, and
-    as many reads as the launch span's ``reads`` field says."""
+def _moments_entry(call: str, comm=None):
+    """The cached entry behind ``ht.<call>(x, axis=0)`` and the launch span it
+    records, made by one small call where ``_colvar.conforms`` is asked no
+    least size and answered by the interpreter, so that a process that drives
+    one TPU (``on_one_chip``) makes the one-read form's entry here too."""
     from heat_tpu import telemetry
-    from heat_tpu.core import _compile
+    from heat_tpu.core import _colvar, _compile
 
     was = telemetry.is_enabled()
     telemetry.enable()
     try:
-        getattr(ht, call)(ht.array(jnp.zeros((8, 16), jnp.float32), split=0), axis=0)  # makes the cached entry
+        with pytest.MonkeyPatch.context() as small:
+            small.setattr(_colvar, "_interpret", lambda: True)
+            small.setattr(_colvar, "MIN_BYTES", 0)
+            x = ht.array(jnp.zeros((8, 512), jnp.float32), split=0, comm=comm)
+            getattr(ht, call)(x, axis=0)
+        site = "stat.mean" if call == "mean" else "stat.moment2"
         span = [e for e in telemetry.events() if e.get("site") == f"jitted:{site}"][-1]
     finally:
         if not was:
             telemetry.disable()
-    # the keys: (site, axis, cast, keepdims) and (site, name, axis, ddof, cast, keepdims)
-    key = {"mean": ("stat.mean", 0, None, False), "std": ("stat.moment2", "stat.std", 0, 0, None, False)}[call]
-    entry = next(fn for k, fn in _compile._CACHE.items() if k[: len(key)] == key)
+    # the keys: (site, axis, cast, keepdims) and (site, name, axis, ddof, form, keepdims)
+    key = ("stat.mean", 0, None, False) if call == "mean" else ("stat.moment2", f"stat.{call}", 0, 0, span["form"], False)
+    return next(fn for k, fn in _compile._CACHE.items() if k[: len(key)] == key), span
+
+
+@pytest.mark.parametrize(
+    "call,reads,scopes",
+    [
+        ("mean", 1, ["stat.mean"]),
+        ("std", 1, ["stat.var.onepass"]),
+        ("var", 1, ["stat.var.onepass"]),
+    ],
+)
+def test_moments_compile_at_the_cells_size_and_read_the_operand_as_their_field_says(one_chip, on_one_chip, call, reads, scopes):
+    """``ht.mean`` / ``ht.std`` / ``ht.var`` along axis 0 of 300 x 6 291 456
+    float32 on one chip: the 7.55 GB operand (304 rows with the tile's
+    padding) and nothing of its size beside it (no centred copy), each read
+    under its scope, and as many reads as the launch span's ``reads`` field
+    says: ONE each, the variance's by the kernel of ``core/_colvar.py``."""
+    from heat_tpu.core import _colvar
+
+    entry, span = _moments_entry(call, ht.XlaCommunication(jax.devices()[:1]))
+    site = span["site"].split(":", 1)[1]
     compiled = entry.lower(_shape((MOM_ROWS, MOM_F), one_chip)).compile()
     _assert_scopes(compiled, f"jit_{site}", scopes)
     m = compiled.memory_analysis()
     assert 4 * MOM_ROWS * MOM_F <= m.argument_size_in_bytes < 7.7e9
     assert m.temp_size_in_bytes < 1 << 26 and m.output_size_in_bytes == 4 * MOM_F
     _fits_the_chip(compiled, site)
-    reads = _reads_of_the_operand(compiled)
-    assert reads == span["reads"] == {"mean": 1, "std": 2}[call]
-    # the compiler's own count of the operand's bytes says the same
+    assert _reads_of_the_operand(compiled) == span["reads"] == reads
+    # the compiler's own count of the operand's bytes says the same (a custom
+    # call's operand counts once, whatever the kernel does with it inside)
     assert round(compiled.cost_analysis()["bytes accessed0{}"] / m.argument_size_in_bytes) == reads
+    if call == "mean":
+        assert "form" not in span
+        return
+    assert span["form"] == "one_pass"
+    # the one read is the kernel's, of the operand itself (not a copy laid out
+    # anew), granted exactly the VMEM it states
+    (kernel,) = [line for line in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert "/stat.var.onepass/" in kernel and f"f32[{MOM_ROWS},{MOM_F}]" in kernel
+    assert _granted_vmem(kernel) == _colvar._VMEM_LIMIT <= 32 << 20
+
+
+def test_std_on_the_cpu_mesh_and_on_rows_over_four_chips_keeps_two_passes(four_chips, on_the_chip):
+    """Every process that is not the cell's compiles the parent's program:
+    two reads under their scopes, no kernel; over four chips (112 of the
+    four-chip cell's 448 rows a chip) each chip reads its own rows twice and
+    the partial sums meet in all-reduces."""
+    entry, span = _moments_entry("std")
+    assert (span["form"], span["reads"]) == ("two_pass", 2)
+    comm = four_chips
+    rows = NamedSharding(comm.mesh, PartitionSpec(comm.axis_name, None))
+    compiled = entry.lower(_shape((448, MOM_F), rows)).compile()
+    _assert_scopes(compiled, "jit_stat.moment2", ["stat.var.mean", "stat.var.centred"])
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and "all-reduce" in text
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes < 4 * 448 * MOM_F // 4 + (1 << 26) and m.temp_size_in_bytes < 1 << 28
+    assert _reads_of_the_operand(compiled) == 2
