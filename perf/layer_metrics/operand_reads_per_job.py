@@ -4,8 +4,9 @@ Reads of the resident operand that the array layer's statistics programs
 issued in the traced window, over the jobs traced: the sum of the ``reads``
 field of the launch spans at ``jitted:stat.*`` (``core/statistics.py`` says of
 each program how many times it reads its operand: ``stat.mean`` 1,
-``stat.moment2`` 2; ``tests/test_tpu_compile.py`` holds the field to the
-program compiled for the chip).  The job entry's ``work`` counts one read a
+``stat.moment2`` 1 on the form ``one_pass`` and 2 on ``two_pass``;
+``tests/test_tpu_compile.py`` holds the field to the program compiled for the
+chip).  The job entry's ``work`` counts one read a
 public call; what this reads above that is what a pass saved would take off
 ``job_ms``.  Nothing to read where the program records no such field.
 """

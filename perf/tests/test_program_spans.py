@@ -102,8 +102,8 @@ def test_found_by_name_in_every_cell(window):
 
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
-    added = [m for m in bench["per_layer"] if m["name"] in NAMES]
-    assert [m["name"] for m in added] == list(NAMES) == [m["name"] for m in bench["per_layer"][-5:]]
+    added = [m for m in bench["per_layer"] if m["name"] in NAMES]  # by name: later PRs append theirs
+    assert sorted(m["name"] for m in added) == sorted(NAMES)
     assert all("workloads" not in m and m["moves"] == "job_ms" and m["better"] == "lower" for m in added)
     for cell in ("kmeans_300_c1", "cdist_40k_c1", "kmeans_448_c4"):
         loaded = run.load_cell(cell)

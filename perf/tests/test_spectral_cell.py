@@ -231,8 +231,8 @@ def test_found_by_name_in_their_cell_alone(window):
     import run
 
     bench = _read(os.path.join(REPO, "BENCHMARK.json"))
-    added = [m for m in bench["per_layer"] if m["name"] in NAMES]
-    assert [m["name"] for m in added] == list(NAMES) == [m["name"] for m in bench["per_layer"][-3:]]
+    added = [m for m in bench["per_layer"] if m["name"] in NAMES]  # by name: later PRs append theirs
+    assert sorted(m["name"] for m in added) == sorted(NAMES)
     assert all(m["workloads"] == [LIKE_CELL] and m["moves"] == "job_ms" and m["layer"] == "solvers" for m in added)
     loaded = run.load_cell(LIKE_CELL)
     loaded["bench"] = dict(bench, per_layer=added)

@@ -5,6 +5,15 @@ median, ``statistics.quantiles(values, n=4)``), as the bounds in
 
     python perf/tools/sets.py --workload <cell> [--runs 6] [--traced 3] [--seconds S]
 
+One run on a seed of its own goes ahead of the sets and counts in neither:
+in a fresh checkout it is the one that compiles, as the driver keeps each
+side's first run apart (``lead`` in the summary).  The summary then gives,
+for every end-to-end metric and for every stage of set-up (``stages``, from
+the lines' ``setup_marks_s`` and ``backend_start_s``; ``setup_s_with_backend``
+is what ``setup_s`` was before PR 35), each set's median and spread and how
+far apart the two medians lie as a share of the first: what ``setup_s``'s
+bound is set from, by the rule in ``PERF.md`` section 2.
+
 Every run is a child process of its own (this parent never touches jax, so
 the child is the chip's one holder).  Result lines go to standard output and,
 where that directory exists, to ``chiprun_out/sets_<cell>.jsonl``.
@@ -26,6 +35,28 @@ ROOT = os.path.dirname(HERE)
 def spread(values):
     q1, _, q3 = statistics.quantiles(values, n=4)
     return (q3 - q1) / statistics.median(values)
+
+
+def stages(line: dict) -> dict:
+    """Seconds each stage of set-up took in one untraced run, in their order;
+    ``setup_s`` itself comes with the metrics."""
+    marks, backend = line["setup_marks_s"], line["backend_start_s"]
+    return {
+        "imports": marks["imports"],
+        "backend_start": backend,
+        "data": marks["data"] - marks["backend"],
+        "warm_up": marks["warm_up"] - marks["data"],
+        "setup_s_with_backend": line["metrics"]["setup_s"]["value"] + backend,
+    }
+
+
+def two_sets(per_set: list) -> dict:
+    medians = [statistics.median(v) for v in per_set]
+    return {
+        "medians": medians,
+        "spreads": [spread(v) for v in per_set],
+        "medians_apart": abs(medians[1] - medians[0]) / medians[0],
+    }
 
 
 def main(argv=None) -> int:
@@ -60,19 +91,19 @@ def main(argv=None) -> int:
             log.flush()
         return line
 
-    sets = []
-    for s in range(2):
-        sets.append([one(seed, 0) for seed in seeds])
-    for i in range(args.traced):
-        one(seeds[-1] + 17 * (i + 1), 1)
-    summary = {"cell": args.workload, "seconds": seconds, "all_correct": all(l["correct"] for st in sets for l in st)}
-    for name in sets[0][0]["metrics"]:
-        per_set = [[l["metrics"][name]["value"] for l in st] for st in sets]
-        summary[name] = {
-            "medians": [statistics.median(v) for v in per_set],
-            "spreads": [spread(v) for v in per_set],
-            "first_run": per_set[0][0],
-        }
+    lead = one(args.first_seed - 7, 0)
+    sets = [[one(seed, 0) for seed in seeds] for _ in range(2)]
+    traced = [one(seeds[-1] + 17 * (i + 1), 1) for i in range(args.traced)]
+    summary = {
+        "cell": args.workload,
+        "seconds": seconds,
+        "all_correct": all(l["correct"] for l in [lead] + sets[0] + sets[1] + traced),
+        "lead": {name: m["value"] for name, m in lead["metrics"].items()},
+    }
+    for name in lead["metrics"]:
+        summary[name] = two_sets([[l["metrics"][name]["value"] for l in st] for st in sets])
+    staged = [[stages(l) for l in st] for st in sets]
+    summary["stages"] = {name: two_sets([[s[name] for s in st] for st in staged]) for name in staged[0][0]}
     print(json.dumps({"summary": summary}), flush=True)
     if log:
         log.write(json.dumps({"summary": summary}) + "\n")
