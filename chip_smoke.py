@@ -49,6 +49,7 @@ LINES = os.path.join(ROOT, "chiprun_out", "chip_smoke.jsonl")
 FULL = {
     "moments": dict(n=8_000_000, f=32, wide=(300, 1_048_576)),
     "kmeans": dict(k=8, iters=30, n_ref=1_000_000),
+    "kmedians": dict(n=300, f=6_291_456, k=8, iters=3, sample=512),
     "cdist": dict(n=40_000, f=18, block=512),
     "spectral": dict(n=8_192, f=18, k=8, m=300),
     "lasso": dict(n=10_000_000, f=32, sweeps=10),
@@ -328,6 +329,66 @@ def phase_kmeans(X, seed: int, k: int, iters: int, n_ref: int):
         "checks": checks, "device_dtypes": device_dtypes(),
     }
     return line, km
+
+
+def phase_kmedians(seed: int, n: int, f: int, k: int, iters: int, sample: int):
+    """``ht.cluster.KMedians`` at the shape of the benchmark's cell
+    (``kmedians_300_c1``: 300 x 6 291 456 float32, 7.55 GB beside which a
+    sorted copy does not fit): ``k`` blobs far apart, row ``i`` in blob
+    ``i % k``, the fit started from one row of each, so that every sweep's
+    partition is the blobs' and the served centres must be numpy's medians of
+    each blob's rows, bit for bit, on ``sample`` columns read back.  The line
+    names the routes the program took: the medians' (``column_select`` on one
+    chip, ``rank_bisection`` on the CPU mesh) and the L1 sum's loop order."""
+    import jax
+    import jax.numpy as jnp
+
+    import heat_tpu as ht
+
+    rows = max(b for b in range(1, n + 1) if n % b == 0 and b * f * 4 <= 1 << 28)
+
+    @jax.jit
+    def blobs(key):  # a block of rows at a time: the bits of the whole array would be its size again
+        centres = 10.0 * jax.random.normal(jax.random.fold_in(key, n), (k, f), jnp.float32)
+
+        def block(i, out):
+            index = i * rows + jnp.arange(rows)
+            noise = jax.vmap(lambda r: jax.random.normal(jax.random.fold_in(key, r), (f,), jnp.float32))(index)
+            return jax.lax.dynamic_update_slice(out, centres[index % k] + noise, (i * rows, 0))
+
+        return jax.lax.fori_loop(0, n // rows, block, jnp.zeros((n, f), jnp.float32))
+
+    X = ht.array(blobs(jax.random.key(seed)), split=0, copy=False)
+    start = ht.array(X.larray[:k])  # row c lies in blob c
+    with launch_spans("jit:kmedians.fit", "jitted:dist.manhattan") as spans:
+        km = ht.cluster.KMedians(k, init=start, max_iter=iters, tol=-1.0).fit(X)
+        n_iter = km.n_iter_
+        to_centres = ht.spatial.manhattan(X, km.cluster_centers_).numpy()
+    (fit_span,) = [e for e in spans if e["site"] == "jit:kmedians.fit"]
+    (sum_span,) = [e for e in spans if e["site"] == "jitted:dist.manhattan"]
+    labels = km.labels_.numpy()
+    cols = np.sort(np.random.default_rng(seed).choice(f, size=min(sample, f), replace=False))
+    host = np.concatenate(
+        [np.asarray(jax.lax.dynamic_slice_in_dim(X.larray, int(c), 1, axis=1)) for c in cols], axis=1)
+    served = np.asarray(km.cluster_centers_.larray[:, jnp.asarray(cols)])
+    blob = np.arange(n) % k
+    want = np.stack([np.median(host[blob == c], axis=0) for c in range(k)])
+    checks = {
+        "iterations": check(abs(n_iter - iters), 0),
+        "labels_vs_blobs_mismatch": check((labels != blob).mean(), 0),
+        "labels_vs_manhattan_argmin_mismatch": check((labels != to_centres.argmin(1)).mean(), 0),
+        "medians_vs_numpy_on_sample_abs": check(np.abs(served - want).max(), 0),
+    }
+    stats = jax.devices()[0].memory_stats() or {}
+    line = {
+        "sizes": {"rows": n, "features": f, "bytes": n * f * 4, "clusters": k, "sweeps": iters, "sample_columns": len(cols)},
+        "routes": {"medians": fit_span["medians"], "assign": fit_span["assign"],
+                   "x_passes": fit_span.get("x_passes"), "manhattan_form": sum_span["form"]},
+        "reference": "numpy median of each blob's rows on the sampled columns; the blobs' own partition",
+        "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+        "checks": checks, "device_dtypes": device_dtypes(),
+    }
+    return line, None
 
 
 def phase_cdist(seed: int, n: int, f: int, block: int):
@@ -914,7 +975,7 @@ def run_one_chip(counter, seed: int) -> None:
     X = timed(counter, "moments", phase_moments, seed, **FULL["moments"])
     km = timed(counter, "kmeans", phase_kmeans, X, seed, **FULL["kmeans"])
     del X
-    for name, fn in (("cdist", phase_cdist), ("spectral", phase_spectral), ("lasso", phase_lasso),
+    for name, fn in (("kmedians", phase_kmedians), ("cdist", phase_cdist), ("spectral", phase_spectral), ("lasso", phase_lasso),
                      ("qr_svd", phase_qr_svd), ("attention", phase_attention),
                      ("io", phase_io)):
         timed(counter, name, fn, seed, **FULL[name])

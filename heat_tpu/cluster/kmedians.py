@@ -1,34 +1,66 @@
 """K-Medians clustering.
 
 Reference: heat/cluster/kmedians.py:5-130 — the KMeans skeleton with the
-centroid update replaced by a per-cluster **median** (masked rows →
-``balance_`` → distributed median, :43-86) and a random-restart failsafe
-for empty clusters (:67-80).
+assignment by **Manhattan** distance (``ht.spatial.distance.manhattan``,
+:5-42) and the centroid update replaced by a per-cluster **median** (masked
+rows → ``balance_`` → distributed median, :43-86): the coordinate-wise
+median is what minimises the sum of L1 distances within a cluster.  The
+reference re-draws the centre of an empty cluster (:67-80); here it keeps
+its place.
 
-TPU formulation: the data matrix never changes across Lloyd iterations, so
-each feature column is value-sorted ONCE; every iteration then finds all
-k·f exact medians by rank-space bisection whose rank counts are MXU
-matmuls over the cluster one-hot (:func:`_cluster_medians`) — no
-per-iteration sort, no O(n·f) gather, no scatter — and the ENTIRE fit is
-one jitted ``lax.while_loop`` (the KMeans pattern, kmeans.py:61-102): one
-dispatch, zero per-epoch host syncs.
+TPU formulation: the ENTIRE fit is one jitted ``lax.while_loop`` (the KMeans
+pattern, kmeans.py:61-102): one dispatch, zero per-epoch host syncs.  A
+sweep is an assignment, ``argmin_c sum_j |x_ij - c_cj|`` in float32 through
+``spatial/distance.py:_pairwise_sum`` (the one pairwise sum, in its order for
+wide operands against few rows), and the exact medians of every cluster and
+feature, by one of two routes the operand decides (:func:`_medians_route`):
+
+* ``column_select``: where ``core/_colmedian.py:conforms`` (a float32 matrix
+  of few rows that fills a chip, one TPU in the process), a Pallas kernel
+  that brings a column tile of ALL rows into VMEM once and selects each
+  cluster's two middle members there: one read of X a sweep, nothing of X's
+  size beside it.
+* ``rank_bisection``: every other operand (the CPU mesh, several chips, many
+  rows).  The data matrix never changes across sweeps, so each feature
+  column is value-sorted ONCE; every sweep then finds all k·f medians by
+  rank-space bisection whose rank counts are matmuls over the cluster
+  one-hot (:func:`_cluster_medians`).  It holds a sorted copy of X and
+  (n, f) temporaries: an operand near a chip's memory does not fit it.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Union
-
-import numpy as np
 
 import jax
 import jax.numpy as jnp
 
+from ..core._compile import launch
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
 from ..spatial import distance
-from ._kcluster import _KCluster, _quadratic_cdist
+from ._kcluster import _KCluster
 
 __all__ = ["KMedians"]
+
+
+def _manhattan(x: DNDarray, y: DNDarray) -> DNDarray:
+    """The estimator's metric for ``predict``: pairwise L1 distances.
+    Module-level, so its identity is call-stable and the fused assignment
+    program caches across estimators (``_kcluster._fused_assign``)."""
+    return distance.manhattan(x, y)
+
+
+def _medians_route(arr, k: int) -> str:
+    """Which route the medians of this operand take: ``column_select`` where
+    the kernel's own predicate holds, else ``rank_bisection``.  Asked once a
+    fit, ahead of the program: the route is part of its key and the
+    ``medians`` field of its launch span.  The kernel's module is imported
+    here, at the first fit: ``import heat_tpu`` does not bring it."""
+    from ..core import _colmedian
+
+    return "column_select" if _colmedian.conforms(arr, k) else "rank_bisection"
 
 
 def _presort_values(arr):
@@ -38,8 +70,9 @@ def _presort_values(arr):
     as the stable ``argsort`` had) and the ONLY sort in the whole KMedians
     fit.  The clamp range is computed HERE because it is loop-invariant:
     inside the Lloyd while_loop it is two full-matrix reduces an
-    iteration (XLA does not hoist out of while bodies).  No cell runs
-    KMedians: its time on the chip is not measured."""
+    iteration (XLA does not hoist out of while bodies).  The route of
+    every operand the kernel of ``core/_colmedian.py`` does not take; its
+    time on the chip is not measured."""
     svals = jax.lax.sort(arr, dimension=0, is_stable=False)
     finite = jnp.isfinite(svals)
     fmax = jnp.max(jnp.where(finite, svals, -jnp.inf), axis=0)
@@ -84,10 +117,10 @@ def _cluster_medians(arr, svals, fmin, fmax, onehot, counts, k, prev_pos=None):
     (reference kmedians.py:43-66).  Replaces a per-cluster
     ``nanmedian``, which is k full sorts per step."""
     n, f = arr.shape
+    from ..core._colmedian import _middle_ranks
+
     # 1-indexed member ranks of the two middles (equal when count is odd)
-    t = jnp.maximum(
-        jnp.stack([(counts - 1) // 2 + 1, counts // 2 + 1], axis=-1), 1
-    )  # (k, 2)
+    t = jnp.maximum(jnp.stack(_middle_ranks(counts), axis=-1), 1)  # (k, 2)
     onehot8 = onehot.astype(jnp.int8)
     # fmin/fmax: the per-column finite clamp for PROBE thresholds (from
     # _presort_values — loop-invariant).  A probe landing in a column's
@@ -166,7 +199,9 @@ def _cluster_medians(arr, svals, fmin, fmax, onehot, counts, k, prev_pos=None):
 
 
 class KMedians(_KCluster):
-    """K-Medians estimator (reference kmedians.py:5-42)."""
+    """K-Medians estimator (reference kmedians.py:5-42): Manhattan assignment,
+    exact per-cluster medians (numpy's: the mean of the two middle members at
+    an even count).  A cluster without members keeps its centre."""
 
     _init_plus_plus_alias = "kmedians++"
 
@@ -179,9 +214,7 @@ class KMedians(_KCluster):
         random_state: Optional[int] = None,
     ):
         super().__init__(
-            # quadratic expansion: assignment is one MXU matmul instead of an
-            # (n, k, f) broadcast temporary
-            metric=_quadratic_cdist,  # module-level: fused-assign cache hit
+            metric=_manhattan,  # module-level: fused-assign cache hit
             n_clusters=n_clusters,
             init=init,
             max_iter=max_iter,
@@ -190,65 +223,97 @@ class KMedians(_KCluster):
         )
 
     @staticmethod
-    @jax.jit
-    def _fit_loop(arr, centers, tol, max_iter):
+    @partial(jax.jit, static_argnames=("route",))
+    def _fit_loop(arr, centers, tol, max_iter, route="rank_bisection"):
         """The whole fit as one compiled ``lax.while_loop`` (the KMeans
-        pattern, kmeans.py:61-102): fused assign + rank-selection median
-        update per step, convergence decided on device.  Replaces the
-        per-epoch ``float(shift)`` host sync of the reference's loop
-        (kmedians.py:87-130) — that round trip dwarfs the step kernel.  |x|² is dropped from the assignment (constant across
-        candidates, see kmeans.py:70-76).  The feature columns are
-        pre-sorted ONCE before the loop; every iteration's medians are
-        sort-free (:func:`_cluster_medians`)."""
+        pattern, kmeans.py:61-102): Manhattan assignment and exact medians
+        per sweep, convergence decided on device.  Replaces the per-epoch
+        ``float(shift)`` host sync of the reference's loop
+        (kmedians.py:87-130).  ``route`` is :func:`_medians_route`'s answer:
+        ``column_select`` reads X once for the assignment and once for the
+        medians of a sweep and holds nothing else of its size;
+        ``rank_bisection`` sorts the feature columns ONCE before the loop and
+        warm-starts each sweep's bisection from the last
+        (:func:`_cluster_medians`)."""
         k = centers.shape[0]
-        svals, fmin, fmax = _presort_values(arr)
 
         def assign(c):
-            c2 = jnp.sum(c * c, axis=1)[None, :]
-            return jnp.argmin(c2 - 2.0 * jnp.matmul(arr, c.T), axis=1)
+            return jnp.argmin(distance._pairwise_sum(arr, c, jnp.abs), axis=1)
 
-        def update(labels, c, prev_pos):
-            member = labels[:, None] == jnp.arange(k)
-            onehot = member.astype(jnp.float32)
-            counts = jnp.sum(member, axis=0, dtype=jnp.int32)
-            med, pos = _cluster_medians(
-                arr, svals, fmin, fmax, onehot, counts, k, prev_pos
-            )
-            # keep the previous coordinate for empty clusters AND for NaN
-            # medians (a NaN-feature member): a NaN center would poison
-            # shift, silently end the loop, and NaN every distance
-            return jnp.where((counts > 0)[:, None] & ~jnp.isnan(med), med, c), pos
+        if route == "column_select":
+            from ..core import _colmedian
 
-        def cond(state):
-            it, _, shift, _ = state
+            def medians(labels, state):
+                med, counts = _colmedian.group_medians(
+                    arr, labels, k, interpret=_colmedian._interpret()
+                )
+                return med, counts, state
+
+            state0 = jnp.int32(0)
+        else:
+            svals, fmin, fmax = _presort_values(arr)
+
+            def medians(labels, prev_pos):
+                member = labels[:, None] == jnp.arange(k)
+                counts = jnp.sum(member, axis=0, dtype=jnp.int32)
+                med, pos = _cluster_medians(
+                    arr, svals, fmin, fmax, member.astype(jnp.float32), counts, k, prev_pos
+                )
+                return med, counts, pos
+
+            # sentinel start: an impossible previous position makes the warm
+            # brackets collapse to [0, 0], whose exact validation widens every
+            # slot back to the full range — iteration 1 is a full bisection
+            # with no special-casing, later iterations warm-start (the answer
+            # rarely moves more than a few ranks once labels stabilize)
+            state0 = jnp.full((k, arr.shape[1], 2), -2 * _WARM_WINDOW, jnp.int32)
+
+        def cond(carry):
+            it, _, shift, _ = carry
             return jnp.logical_and(it < max_iter, shift > tol)
 
-        def body(state):
-            it, c, _, pos = state
-            nc, pos = update(assign(c), c, pos)
-            return it + 1, nc, jnp.sum((nc - c) ** 2), pos
+        def body(carry):
+            it, c, _, state = carry
+            with jax.named_scope("kmedians.sweep.assign"):
+                labels = assign(c)
+            with jax.named_scope("kmedians.sweep.medians"):
+                med, counts, state = medians(labels, state)
+                # keep the previous coordinate for empty clusters AND for NaN
+                # medians (a NaN-feature member): a NaN center would poison
+                # shift, silently end the loop, and NaN every distance
+                nc = jnp.where((counts > 0)[:, None] & ~jnp.isnan(med), med, c)
+            return it + 1, nc, jnp.sum((nc - c) ** 2), state
 
-        # sentinel start: an impossible previous position makes the warm
-        # brackets collapse to [0, 0], whose exact validation widens every
-        # slot back to the full range — iteration 1 is a full bisection
-        # with no special-casing, later iterations warm-start (the answer
-        # rarely moves more than a few ranks once labels stabilize)
-        pos0 = jnp.full((k, arr.shape[1], 2), -2 * _WARM_WINDOW, jnp.int32)
-        init = (jnp.int32(0), centers, jnp.float32(jnp.inf), pos0)
+        init = (jnp.int32(0), centers, jnp.float32(jnp.inf), state0)
         n_iter, centers, _, _ = jax.lax.while_loop(cond, body, init)
-        return centers, assign(centers), n_iter
+        with jax.named_scope("kmedians.finalize"):
+            labels = assign(centers)
+        return centers, labels, n_iter
 
     def fit(self, x: DNDarray) -> "KMedians":
-        """(reference kmedians.py:87-130), as a single on-device loop."""
+        """(reference kmedians.py:87-130), as a single on-device loop, issued
+        through ``launch`` at ``jit:kmedians.fit``.  The span's fields:
+        ``sweeps`` (``max_iter``: the most the loop runs, and what it runs
+        where ``tol`` is negative), ``assign`` (``manhattan``), ``medians``
+        (the route) and, on ``column_select``, ``x_passes``: how many times
+        that many sweeps read X (two a sweep, one for the last assignment)."""
         sanitize_in(x)
         if x.ndim != 2:
             raise ValueError(f"input needs to be 2D, but was {x.ndim}D")
         self._initialize_cluster_centers(x)
         arr = x.larray.astype(jnp.float32)
         centers = self._cluster_centers.larray.astype(jnp.float32)
+        route = _medians_route(arr, self.n_clusters)
+        fields = {"sweeps": int(self.max_iter), "assign": "manhattan", "medians": route}
+        if route == "column_select":
+            fields["x_passes"] = 2 * int(self.max_iter) + 1
 
-        centers, labels, n_iter = KMedians._fit_loop(
-            arr, centers, jnp.float32(self.tol), jnp.int32(self.max_iter)
+        centers, labels, n_iter = launch(
+            "jit:kmedians.fit",
+            KMedians._fit_loop,
+            (arr, centers, jnp.float32(self.tol), jnp.int32(self.max_iter)),
+            {"route": route},
+            **fields,
         )
         self._finalize_fit(x, centers, labels, n_iter)
         return self

@@ -12,7 +12,9 @@ row-sharding of D (the programs here lay their result out so themselves:
 ``_rows_of``).  The default (``quadratic_expansion=False``) is the exact form
 ``sqrt(sum((x - y)²))`` on the vector units: one program and, at up to
 ``_UNROLL_MAX_FEATURES`` features, one pass that writes D once
-(``_pairwise_sum``).  It is bound by the vector units' three operations a
+(``_pairwise_sum``, which has two more loop orders for wider operands:
+``rows`` where one operand has few rows, as ``KMedians``' centres have, and
+the broadcast-and-``reduce``).  It is bound by the vector units' three operations a
 feature and pair, not by the write of D (see ledger, PR 31, ``cdist_40k_c1``;
 ``job_ms`` 22.1 against 87.6 before, ``roofline_pct`` 37.0 at 40 000 x 18: my
 chip run, PR 31).
@@ -97,26 +99,58 @@ def _laid(d, rows):
 _UNROLL_MAX_FEATURES = 64
 
 
-def _form(features: int) -> str:
-    """The loop order :func:`_pairwise_sum` takes at this feature count: the
-    launch spans of the exact forms carry it as their ``form`` field."""
-    return "unrolled" if 0 < features <= _UNROLL_MAX_FEATURES else "reduce"
+#: Fewest rows (of the operand that has fewer) up to which a wide operand takes
+#: the ``rows`` order.  One L1 program at 300 x 6 291 456 against 8 rows on a
+#: TPU v5e (my chip run, PR 36, ``PERF.md`` §6): ``reduce`` 60.8 ms, ``rows``
+#: 14.3 ms, one multi-output fusion over X (10.5 ms a pass inside ``KMedians``'
+#: fit, the read of X alone being 9.2).  Beyond 16 rows: not measured.
+_ROWS_MAX = 16
+
+
+def _form(features: int, fewest_rows: Optional[int] = None) -> str:
+    """The loop order :func:`_pairwise_sum` takes at this feature count and at
+    this many rows of the operand that has fewer (``None``: not known, many):
+    the launch spans of the exact forms carry it as their ``form`` field."""
+    if 0 < features <= _UNROLL_MAX_FEATURES:
+        return "unrolled"
+    if fewest_rows is not None and 0 < fewest_rows <= _ROWS_MAX:
+        return "rows"
+    return "reduce"
+
+
+def _form_of(xa, ya) -> str:
+    """:func:`_form` of a pair of operands."""
+    return _form(xa.shape[1], min(xa.shape[0], ya.shape[0]))
 
 
 def _pairwise_sum(xa, ya, term):
     """``sum_k term(xa[i, k] - ya[j, k])`` for every pair: (n, f), (m, f) ->
     (n, m), every feature, in the operands' own precision.
 
-    One sum in one of two loop orders, chosen from the static feature count.
-    Few features (``_form``: ``unrolled``): feature by feature over the
+    One sum in one of three loop orders, chosen from the static shapes
+    (:func:`_form`).  Few features (``unrolled``): feature by feature over the
     (n, m) result, so the compiler makes each output tile in registers from
     ``f`` subtract-``term``-adds on two broadcast vectors, fuses what the
     caller does next (``sqrt``, ``exp``) into the same pass and writes the
-    result once.  Wide operands (``reduce``): the (n, m, f) broadcast reduced
+    result once.  Wide operands, one of them of few rows (``rows``: a
+    clusterer's data against its centres): row by row of the smaller operand,
+    each a reduce of ``term(x - y[j])`` along the features with nothing of the
+    broadcast's size in between; the reduces share one pass over the larger
+    operand.  Everything else (``reduce``): the (n, m, f) broadcast reduced
     over its minor axis, which the compiler pads to the tile width at few
-    features and finishes with a second pass over the result."""
-    if _form(xa.shape[1]) == "reduce":
+    features and, on wide operands, tiles by the row count (a cliff at 300
+    rows, ``PERF.md`` §6)."""
+    form = _form_of(xa, ya)
+    if form == "reduce":
         return jnp.sum(term(xa[:, None, :] - ya[None, :, :]), axis=-1)
+    if form == "rows":
+        if ya.shape[0] <= xa.shape[0]:
+            return jnp.stack(
+                [jnp.sum(term(xa - ya[j][None, :]), axis=1) for j in range(ya.shape[0])], axis=1
+            )
+        return jnp.stack(
+            [jnp.sum(term(xa[i][None, :] - ya), axis=1) for i in range(xa.shape[0])], axis=0
+        )
     xt, yt = xa.T, ya.T
     acc = term(xt[0][:, None] - yt[0][None, :])
     for k in range(1, xa.shape[1]):
@@ -145,7 +179,7 @@ def cdist(X: DNDarray, Y: Optional[DNDarray] = None, quadratic_expansion: bool =
     default, like the reference's torch.cdist.
     """
     xa, ya, dtype = _prep(X, Y)
-    form = None if quadratic_expansion else _form(xa.shape[1])
+    form = None if quadratic_expansion else _form_of(xa, ya)
     rows = _rows_of(X)
     fn = jitted(
         ("dist.euclidean", quadratic_expansion, form, rows),
@@ -171,7 +205,7 @@ def rbf(
     set otherwise), not the one bf16 pass ``cdist``'s expansion runs."""
     xa, ya, dtype = _prep(X, Y)
     precision = _linalg._precision() if quadratic_expansion else None
-    form = None if quadratic_expansion else _form(xa.shape[1])
+    form = None if quadratic_expansion else _form_of(xa, ya)
     rows = _rows_of(X)
 
     def _make():
@@ -199,7 +233,7 @@ def manhattan(X: DNDarray, Y: Optional[DNDarray] = None, expand: bool = False) -
     """Pairwise L1 distances (reference distance.py:180-186)."""
     xa, ya, dtype = _prep(X, Y)
     del expand  # accepted for API parity; one formulation here
-    form = _form(xa.shape[1])
+    form = _form_of(xa, ya)
     rows = _rows_of(X)
     fn = jitted(
         ("dist.manhattan", form, rows),

@@ -113,6 +113,17 @@ def test_cdist_phase_says_which_form_its_program_took(f, form):
     assert line["exact_form"] == [form] and _failed(line) == []
 
 
+@pytest.mark.parametrize("n,f,k", [(40, 2048, 4), (37, 300, 8)])
+def test_kmedians_phase_names_its_routes(n, f, k):
+    """At toy size on the CPU mesh: the bisection's route and the row order of
+    the wide L1 sum, the medians numpy's own on the sampled columns."""
+    line, _ = chip_smoke.phase_kmedians(SEED, n=n, f=f, k=k, iters=3, sample=64)
+    _complete(line)
+    assert _failed(line) == []
+    assert line["routes"] == {"medians": "rank_bisection", "assign": "manhattan", "x_passes": None, "manhattan_form": "rows"}
+    assert line["checks"]["medians_vs_numpy_on_sample_abs"]["value"] == 0.0
+
+
 def test_io_phase_round_trip_under_the_given_directory(tmp_path):
     line, _ = chip_smoke.phase_io(SEED, n=1024, f=32, work=str(tmp_path))
     _complete(line)

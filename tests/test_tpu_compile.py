@@ -598,3 +598,89 @@ def test_std_on_the_cpu_mesh_and_on_rows_over_four_chips_keeps_two_passes(four_c
     m = compiled.memory_analysis()
     assert m.argument_size_in_bytes < 4 * 448 * MOM_F // 4 + (1 << 26) and m.temp_size_in_bytes < 1 << 28
     assert _reads_of_the_operand(compiled) == 2
+
+
+# --------------------------------------------------------------------- #
+# kmedians_300_c1: the whole fit, one program                            #
+# --------------------------------------------------------------------- #
+def _passes_over(compiled, shape: str):
+    """``(in the loop's body, outside it)``: how many fusions and custom calls
+    of the compiled module's own computations (the fused ones apart) take an
+    operand of ``shape``: each is one pass over it, whatever is fused in."""
+    text = compiled.as_text()
+    (body,) = set(re.findall(r"\bwhile\([^\n]*body=%?([\w.\-]+)", text))
+    counts = {}
+    for head, block in re.findall(r"^(?:ENTRY )?%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S):
+        if "fused_computation" in head or head.startswith("region"):
+            continue
+        holders = set(re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = " + re.escape(shape), block, re.M))
+        counts[head] = sum(
+            1
+            for operands in re.findall(r"^[^\n]* = [^\n]*? (?:fusion|custom-call)\(([^)]*)\)", block, re.M)
+            if holders & {o.strip().lstrip("%") for o in operands.split(",")}
+        )
+    return counts.pop(body), sum(counts.values())
+
+
+def test_kmedians_fit_compiles_at_the_cells_size_and_reads_x_as_its_field_says(one_chip, on_one_chip):
+    """``KMedians.fit`` at 300 x 6 291 456 float32 on one chip, the program the
+    cell times: X, two sets of centres and nothing of X's size beside them (no
+    sorted copy, no (n, f) temporary), the three scopes, the medians by the
+    kernel of ``core/_colmedian.py`` on X itself, and as many passes over X as
+    the launch span's ``x_passes`` says: two a sweep, one after the loop."""
+    from heat_tpu import telemetry
+    from heat_tpu.cluster import kmedians
+    from heat_tpu.core import _colmedian
+
+    x = _shape((CELL_ROWS["one_chip"], CELL_F), one_chip)
+    route = kmedians._medians_route(x, CELL_K)
+    assert route == "column_select"
+    compiled = kmedians.KMedians._fit_loop.lower(
+        x, _shape((CELL_K, CELL_F), one_chip), _shape((), one_chip), _shape((), one_chip, jnp.int32), route=route
+    ).compile()
+    _assert_scopes(compiled, "jit__fit_loop", ["kmedians.sweep.assign", "kmedians.sweep.medians", "kmedians.finalize"])
+    m = compiled.memory_analysis()
+    assert 4 * (300 + CELL_K) * CELL_F <= m.argument_size_in_bytes < 7.9e9
+    assert m.temp_size_in_bytes < 1e9, m  # a second set of centres and the kernel's result, not a copy of X
+    _fits_the_chip(compiled, "kmedians.fit")
+    (kernel,) = [line for line in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert "/kmedians.sweep.medians/" in kernel and f"f32[300,{CELL_F}]" in kernel
+    assert _granted_vmem(kernel) == _colmedian._VMEM_LIMIT <= 48 << 20
+    assert " sort(" not in compiled.as_text()  # nothing is sorted: the rows' places in cluster order are prefix counts
+
+    # the field, from a small fit through the interpreted route
+    was = telemetry.is_enabled()
+    telemetry.enable()
+    try:
+        with pytest.MonkeyPatch.context() as small:
+            small.setattr(_colmedian, "_interpret", lambda: True)
+            small.setattr(_colmedian, "MIN_BYTES", 0)
+            comm = ht.XlaCommunication(jax.devices()[:1])
+            data = ht.array(jnp.arange(16 * 1024, dtype=jnp.float32).reshape(16, 1024) % 37, split=0, comm=comm)
+            ht.cluster.KMedians(n_clusters=2, max_iter=30, tol=-1.0, random_state=1).fit(data)
+        span = [e for e in telemetry.events() if e.get("site") == "jit:kmedians.fit"][-1]
+    finally:
+        if not was:
+            telemetry.disable()
+        jax.clear_caches()
+    in_body, outside = _passes_over(compiled, f"f32[300,{CELL_F}]")
+    assert (in_body, outside) == (2, 1)
+    assert span["medians"] == route and span["x_passes"] == in_body * span["sweeps"] + outside == 61
+
+
+def test_kmedians_on_rows_over_four_chips_keeps_the_bisection(four_chips, on_the_chip):
+    """Every process that is not the cell's compiles the rank bisection, with
+    the Manhattan assignment: no kernel, the sorted copy, the three scopes."""
+    from heat_tpu.cluster import kmedians
+
+    comm = four_chips
+    rows = NamedSharding(comm.mesh, PartitionSpec(comm.axis_name, None))
+    rep = NamedSharding(comm.mesh, PartitionSpec())
+    x = _shape((448, 1 << 16), rows)
+    route = kmedians._medians_route(x, CELL_K)
+    assert route == "rank_bisection"
+    compiled = kmedians.KMedians._fit_loop.lower(
+        x, _shape((CELL_K, 1 << 16), rep), _shape((), rep), _shape((), rep, jnp.int32), route=route
+    ).compile()
+    _assert_scopes(compiled, "jit__fit_loop", ["kmedians.sweep.assign", "kmedians.sweep.medians", "kmedians.finalize"])
+    assert "tpu_custom_call" not in compiled.as_text() and "sort(" in compiled.as_text()
