@@ -339,7 +339,10 @@ def phase_kmedians(seed: int, n: int, f: int, k: int, iters: int, sample: int):
     partition is the blobs' and the served centres must be numpy's medians of
     each blob's rows, bit for bit, on ``sample`` columns read back.  The line
     names the routes the program took: the medians' (``column_select`` on one
-    chip, ``rank_bisection`` on the CPU mesh) and the L1 sum's loop order."""
+    chip, ``rank_bisection`` on the CPU mesh), how many of the fit's
+    selections the kernel made by its comparison network (every sweep's ``k``
+    on the chip: a blob's rows are fewer than ``network_max``) and the L1
+    sum's loop order."""
     import jax
     import jax.numpy as jnp
 
@@ -363,6 +366,7 @@ def phase_kmedians(seed: int, n: int, f: int, k: int, iters: int, sample: int):
     with launch_spans("jit:kmedians.fit", "jitted:dist.manhattan") as spans:
         km = ht.cluster.KMedians(k, init=start, max_iter=iters, tol=-1.0).fit(X)
         n_iter = km.n_iter_
+        by_network = km.selections_by_network_
         to_centres = ht.spatial.manhattan(X, km.cluster_centers_).numpy()
     (fit_span,) = [e for e in spans if e["site"] == "jit:kmedians.fit"]
     (sum_span,) = [e for e in spans if e["site"] == "jitted:dist.manhattan"]
@@ -383,7 +387,8 @@ def phase_kmedians(seed: int, n: int, f: int, k: int, iters: int, sample: int):
     line = {
         "sizes": {"rows": n, "features": f, "bytes": n * f * 4, "clusters": k, "sweeps": iters, "sample_columns": len(cols)},
         "routes": {"medians": fit_span["medians"], "assign": fit_span["assign"],
-                   "x_passes": fit_span.get("x_passes"), "manhattan_form": sum_span["form"]},
+                   "x_passes": fit_span.get("x_passes"), "network_max": fit_span.get("network_max"),
+                   "selections_by_network": by_network, "manhattan_form": sum_span["form"]},
         "reference": "numpy median of each blob's rows on the sampled columns; the blobs' own partition",
         "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
         "checks": checks, "device_dtypes": device_dtypes(),

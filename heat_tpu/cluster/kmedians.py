@@ -18,8 +18,10 @@ feature, by one of two routes the operand decides (:func:`_medians_route`):
 * ``column_select``: where ``core/_colmedian.py:conforms`` (a float32 matrix
   of few rows that fills a chip, one TPU in the process), a Pallas kernel
   that brings a column tile of ALL rows into VMEM once and selects each
-  cluster's two middle members there: one read of X a sweep, nothing of X's
-  size beside it.
+  cluster's two middle members there (a cluster of at most
+  ``_colmedian._NETWORK_MAX`` members by a comparison network, a larger one by
+  counting passes; ``selections_by_network_`` says how many took the first):
+  one read of X a sweep, nothing of X's size beside it.
 * ``rank_bisection``: every other operand (the CPU mesh, several chips, many
   rows).  The data matrix never changes across sweeps, so each feature
   column is value-sorted ONCE; every sweep then finds all k·f medians by
@@ -40,6 +42,7 @@ from ..core._compile import launch
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
 from ..spatial import distance
+from ..telemetry import _core as _tel
 from ._kcluster import _KCluster
 
 __all__ = ["KMedians"]
@@ -221,6 +224,18 @@ class KMedians(_KCluster):
             tol=tol,
             random_state=random_state,
         )
+        self._selections = None
+
+    @property
+    def selections_by_network_(self) -> int:
+        """How many of the fit's median selections (one a sweep and cluster
+        with members) the kernel of ``core/_colmedian.py`` made by its
+        comparison network; 0 on the ``rank_bisection`` route.  A device
+        scalar until asked for: the read is a host sync of its own (site
+        ``sync:kmedians.selections``), which a fit alone does not make."""
+        if self._selections is not None and not isinstance(self._selections, int):
+            self._selections = _tel.host_read("sync:kmedians.selections", self._selections, int)
+        return self._selections
 
     @staticmethod
     @partial(jax.jit, static_argnames=("route",))
@@ -231,7 +246,9 @@ class KMedians(_KCluster):
         ``float(shift)`` host sync of the reference's loop
         (kmedians.py:87-130).  ``route`` is :func:`_medians_route`'s answer:
         ``column_select`` reads X once for the assignment and once for the
-        medians of a sweep and holds nothing else of its size;
+        medians of a sweep and holds nothing else of its size, and counts in
+        the loop's state how many of its selections the kernel made by its
+        comparison network (the fourth result; 0 on the other route);
         ``rank_bisection`` sorts the feature columns ONCE before the loop and
         warm-starts each sweep's bisection from the last
         (:func:`_cluster_medians`)."""
@@ -247,7 +264,7 @@ class KMedians(_KCluster):
                 med, counts = _colmedian.group_medians(
                     arr, labels, k, interpret=_colmedian._interpret()
                 )
-                return med, counts, state
+                return med, counts, state + _colmedian.by_network(counts)
 
             state0 = jnp.int32(0)
         else:
@@ -285,10 +302,10 @@ class KMedians(_KCluster):
             return it + 1, nc, jnp.sum((nc - c) ** 2), state
 
         init = (jnp.int32(0), centers, jnp.float32(jnp.inf), state0)
-        n_iter, centers, _, _ = jax.lax.while_loop(cond, body, init)
+        n_iter, centers, _, state = jax.lax.while_loop(cond, body, init)
         with jax.named_scope("kmedians.finalize"):
             labels = assign(centers)
-        return centers, labels, n_iter
+        return centers, labels, n_iter, state if route == "column_select" else jnp.int32(0)
 
     def fit(self, x: DNDarray) -> "KMedians":
         """(reference kmedians.py:87-130), as a single on-device loop, issued
@@ -296,7 +313,9 @@ class KMedians(_KCluster):
         ``sweeps`` (``max_iter``: the most the loop runs, and what it runs
         where ``tol`` is negative), ``assign`` (``manhattan``), ``medians``
         (the route) and, on ``column_select``, ``x_passes``: how many times
-        that many sweeps read X (two a sweep, one for the last assignment)."""
+        that many sweeps read X (two a sweep, one for the last assignment),
+        and ``network_max``: the most members of a cluster the kernel selects
+        by its comparison network."""
         sanitize_in(x)
         if x.ndim != 2:
             raise ValueError(f"input needs to be 2D, but was {x.ndim}D")
@@ -306,9 +325,12 @@ class KMedians(_KCluster):
         route = _medians_route(arr, self.n_clusters)
         fields = {"sweeps": int(self.max_iter), "assign": "manhattan", "medians": route}
         if route == "column_select":
-            fields["x_passes"] = 2 * int(self.max_iter) + 1
+            from ..core import _colmedian
 
-        centers, labels, n_iter = launch(
+            fields["x_passes"] = 2 * int(self.max_iter) + 1
+            fields["network_max"] = _colmedian._NETWORK_MAX
+
+        centers, labels, n_iter, self._selections = launch(
             "jit:kmedians.fit",
             KMedians._fit_loop,
             (arr, centers, jnp.float32(self.tol), jnp.int32(self.max_iter)),

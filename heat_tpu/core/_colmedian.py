@@ -12,16 +12,28 @@ once (the layout of ``core/_colvar.py``), and selects there:
    tiles it, 8 rows to a register.  Each row is copied into a slab of its own,
    ``(8, W)`` with ``8 * W`` the tile's columns, at the place the row has in the
    order of the labels (``dest``, from the caller), as an int32 **key** whose
-   signed order is the float's (NaN, either sign, last).  A cluster's members
-   are then the slabs ``starts[c] .. starts[c + 1]``, and everything below is
+   signed order is the float's (NaN, either sign, last).  A register of a
+   row's slab is one sublane each of 8 registers of its row group: the 8 are
+   transposed in registers and stored whole.  A cluster's members are then
+   the slabs ``starts[c] .. starts[c + 1]``, and everything below is
    elementwise between whole registers of ONE row: no reduction across
    sublanes or lanes anywhere.
-2. *Select by radix.*  The ``t``-th smallest key of a cluster is the largest
-   ``v`` with ``|{key < v}| <= t - 1``: built bit by bit from the top, 32 counts
-   over the cluster's slabs (a compare, a select and an add a register), the
-   same 32 whatever the values and however the rows split.  One more pass
-   finds the upper middle of an even count: the lower one again if it is a
-   duplicate, else the smallest key above it.
+2. *Select the two middle members*, by the method the cluster's own member
+   count ``m`` takes (a scalar the kernel reads; :data:`_NETWORK_MAX`):
+
+   - up to ``_NETWORK_MAX`` members, *by a comparison network in registers*:
+     register by register of the slab, the members' registers (the missing
+     ones ``_INT_MAX``, which sorts last) go through the exchanges of Batcher's
+     merge exchange for ``_NETWORK_MAX`` items (Knuth, TAOCP 5.2.2, Algorithm
+     M) that the lower half's outputs can hear of: a ``min`` and a ``max`` an
+     exchange, nothing loaded or stored between them.  The middles are the
+     outputs at their ranks.
+   - more members, *by radix*: the ``t``-th smallest key of a cluster is the
+     largest ``v`` with ``|{key < v}| <= t - 1``: built bit by bit from the
+     top, 32 counts over the cluster's slabs (a load, a compare, a select and
+     an add a register), the same 32 whatever the values.  One more pass
+     finds the upper middle of an even count: the lower one again if it is a
+     duplicate, else the smallest key above it.
 3. The median is the member's own value, or ``(a + b) / 2`` of the two middle
    members in float32, as ``numpy.median`` makes it: exact.  A cluster whose
    median position lies among NaN members gets NaN (they sort last); an empty
@@ -49,18 +61,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["group_medians", "conforms"]
+__all__ = ["group_medians", "conforms", "by_network"]
 
 _SUBLANES = 8
 _LANES = 128
 #: lanes of a row's slab: the tile is ``8 * _SLAB`` columns wide.  Eight
 #: registers a row; the threshold, the count and the answer of the selection
-#: stay in registers beside them.  At 300 x 6 291 456 on a v5e, a pass
-#: (my chip run, PR 36): 160 ms at 256 lanes, 96 at 512, 69-71 at 1024: what a
-#: trip of the count's loop costs beside its compares is shared by more lanes
+#: stay in registers beside them.  At 300 x 6 291 456 on a v5e, a pass of
+#: counting alone (my chip run, PR 36, on that PR's lay-out, 12 ms slower than
+#: this one): 160 ms at 256 lanes, 96 at 512, 69-71 at 1024: what a trip of
+#: the count's loop costs beside its compares is shared by more lanes
 _SLAB = 1024
-#: rows one trip of the count's loop takes (8: 69 ms, 4: 71, at 1024 lanes)
+#: rows one trip of the count's loop takes (8: 69 ms, 4: 71, at 1024 lanes, there)
 _UNROLL = 8
+#: most members of a cluster whose middles come from the comparison network;
+#: a larger cluster keeps the counting passes.  The network is made for this
+#: many items whatever the cluster has, and all of them are in registers at
+#: once (the chip has 64).  A pass over 300 x 6 291 456 on a v5e
+#: (``scripts/time_colmedian.py``; my chip runs, PR 37), counting / 40 / 48 /
+#: 56: the cell's 8 blobs of 37-38 rows 55.6 / 28.0 / 32.7 / 37.6 ms (258,
+#: 334 and 418 exchanges a cluster, 1.9 cycles each, against 33 x 37 counting
+#: steps of 0.9); 6 clusters of 50: 51.7 / 52.8 / - / 37.8; 113 + 38 + 38 + 37 +
+#: 37 + 14 + 14 + 9 (a random start's merged and split blobs): 55.0 / 40.5 /
+#: 45.7 / 50.2; 4 x 75, 2 x 150 and 1 x 300 count at 50.7, 49.6 and 48.4
+#: whichever it is.  The cell's clusters are blobs (at most 38), pieces of one
+#: or several merged (74 and more): 40 serves the first two at the least cost
+_NETWORK_MAX = 40
 #: bytes of VMEM the kernel may be granted: two tiles of the operand in
 #: flight, the tile again as keys, the results (30 MB of it at the cell's 300
 #: rows)
@@ -129,6 +155,42 @@ def _middle_ranks(m):
     return (m + 1) // 2, m // 2 + 1
 
 
+def by_network(counts):
+    """How many of the clusters with these member counts take the comparison
+    network in :func:`group_medians`: those of 1 to :data:`_NETWORK_MAX`
+    members (an empty one selects nothing)."""
+    return jnp.sum((counts > 0) & (counts <= _NETWORK_MAX), dtype=jnp.int32)
+
+
+def _merge_exchange(n: int):
+    """Batcher's merge exchange for ``n`` items (Knuth, TAOCP 5.2.2, Algorithm
+    M) as its exchanges ``(i, j)``, ``i < j``, in order: after them item ``i``
+    is the ``i``-th smallest, whatever ``n``."""
+    pairs = []
+    top = 1 << ((n - 1).bit_length() - 1) if n > 1 else 0  # the largest power of two under n
+    p = top
+    while p > 0:
+        q, r, d = top, 0, p
+        while True:
+            pairs += [(i, i + d) for i in range(n - d) if i & p == r]
+            if q == p:
+                break
+            d, q, r = q - p, q >> 1, p
+        p >>= 1
+    return pairs
+
+
+def _selection(n: int, outputs):
+    """The exchanges of :func:`_merge_exchange` that ``outputs`` can hear of,
+    in order: the others move no value into those places."""
+    heard, kept = set(outputs), []
+    for i, j in reversed(_merge_exchange(n)):
+        if i in heard or j in heard:
+            kept.append((i, j))
+            heard |= {i, j}
+    return kept[::-1]
+
+
 def _kernel(dest_ref, starts_ref, x_ref, out_ref, keys_ref, *, n, k, slab):
     full, ragged = divmod(n, _SUBLANES)
 
@@ -137,12 +199,23 @@ def _kernel(dest_ref, starts_ref, x_ref, out_ref, keys_ref, *, n, k, slab):
         first = g * _SUBLANES
         if not isinstance(g, int):
             first = pl.multiple_of(first, _SUBLANES)
-        for s in range(_SUBLANES):
-            x = x_ref[pl.ds(first, _SUBLANES), pl.ds(s * slab, slab)]
+        to = [dest_ref[first + u] for u in range(rows)]
+
+        def registers(v, carry):
+            """The ``v``-th register of each row's slab: its sublane ``s`` is
+            the row's 128 lanes at ``s * slab + v * 128`` of the tile, which
+            arrive as one sublane each of 8 registers of the row group."""
+            at = pl.multiple_of(v * _LANES, _LANES)
+            x = jnp.stack(
+                [x_ref[pl.ds(first, _SUBLANES), pl.ds(s * slab + at, _LANES)] for s in range(_SUBLANES)]
+            )
             key = _flip(jax.lax.bitcast_convert_type(x, jnp.int32))
-            key = jnp.where(x != x, _INT_MAX, key)
+            key = jnp.swapaxes(jnp.where(x != x, _INT_MAX, key), 0, 1)  # (row, s, lane)
             for u in range(rows):
-                keys_ref[dest_ref[first + u], pl.ds(s, 1), :] = key[u:u + 1, :]
+                keys_ref[to[u], :, pl.ds(at, _LANES)] = key[u]
+            return carry
+
+        jax.lax.fori_loop(0, slab // _LANES, registers, 0)
 
     def groups(g, carry):
         lay_out(g, _SUBLANES)
@@ -154,9 +227,37 @@ def _kernel(dest_ref, starts_ref, x_ref, out_ref, keys_ref, *, n, k, slab):
 
     zeros = jnp.zeros((_SUBLANES, slab), jnp.int32)
 
-    def cluster(c, carry):
-        lo, hi = starts_ref[c], starts_ref[c + 1]
-        m = hi - lo
+    def median(lower, higher):
+        a = jax.lax.bitcast_convert_type(_flip(lower), jnp.float32)
+        b = jax.lax.bitcast_convert_type(_flip(higher), jnp.float32)
+        return jnp.where(lower == higher, a, (a + b) * 0.5)
+
+    def by_a_network(c, lo, m):
+        """The medians of a cluster of at most ``_NETWORK_MAX`` members."""
+        lower_rank, upper_rank = _middle_ranks(m)
+        ranks = _NETWORK_MAX // 2 + 1  # the middles of that many members lie under it
+        exchanges = _selection(_NETWORK_MAX, range(ranks))
+
+        def registers(v, carry):
+            at = pl.multiple_of(v * _LANES, _LANES)
+            key = [  # a member past the cluster's last reads some slab of the tile, and is not taken
+                jnp.where(j < m, keys_ref[jnp.minimum(lo + j, n - 1), :, pl.ds(at, _LANES)], _INT_MAX)
+                for j in range(_NETWORK_MAX)
+            ]
+            for i, j in exchanges:
+                key[i], key[j] = jnp.minimum(key[i], key[j]), jnp.maximum(key[i], key[j])
+            lower = higher = key[0]
+            for j in range(1, ranks):
+                lower = jnp.where(lower_rank - 1 == j, key[j], lower)
+                higher = jnp.where(upper_rank - 1 == j, key[j], higher)
+            out_ref[c, 0, :, pl.ds(at, _LANES)] = median(lower, higher)
+            return carry
+
+        jax.lax.fori_loop(0, slab // _LANES, registers, 0)
+
+    def by_counting(c, lo, m):
+        """The medians of a larger cluster, by radix."""
+        hi = lo + m
         trips = m // _UNROLL
 
         def over_members(term, init):
@@ -198,10 +299,16 @@ def _kernel(dest_ref, starts_ref, x_ref, out_ref, keys_ref, *, n, k, slab):
             )
 
         upto, above = over_members(upper, (zeros, jnp.full_like(zeros, _INT_MAX)))
-        higher = jnp.where(upto >= upper_rank, lower, above)
-        a = jax.lax.bitcast_convert_type(_flip(lower), jnp.float32)
-        b = jax.lax.bitcast_convert_type(_flip(higher), jnp.float32)
-        out_ref[c, 0] = jnp.where(lower == higher, a, (a + b) * 0.5)
+        out_ref[c, 0] = median(lower, jnp.where(upto >= upper_rank, lower, above))
+
+    def cluster(c, carry):
+        lo = starts_ref[c]
+        m = starts_ref[c + 1] - lo
+
+        @pl.when(m > 0)  # an empty cluster's row is unspecified: nothing is selected for it
+        def _():
+            jax.lax.cond(m <= _NETWORK_MAX, by_a_network, by_counting, c, lo, m)
+
         return carry
 
     jax.lax.fori_loop(0, k, cluster, 0)

@@ -120,7 +120,10 @@ def test_kmedians_phase_names_its_routes(n, f, k):
     line, _ = chip_smoke.phase_kmedians(SEED, n=n, f=f, k=k, iters=3, sample=64)
     _complete(line)
     assert _failed(line) == []
-    assert line["routes"] == {"medians": "rank_bisection", "assign": "manhattan", "x_passes": None, "manhattan_form": "rows"}
+    assert line["routes"] == {
+        "medians": "rank_bisection", "assign": "manhattan", "x_passes": None, "network_max": None,
+        "selections_by_network": 0, "manhattan_form": "rows",
+    }
     assert line["checks"]["medians_vs_numpy_on_sample_abs"]["value"] == 0.0
 
 

@@ -82,7 +82,8 @@ def _medians_nan_last(members: np.ndarray) -> np.ndarray:
     ordered = np.sort(members, axis=0)
     m = len(members)
     a, b = ordered[(m - 1) // 2], ordered[m // 2]
-    return np.where(a == b, a, (a + b) / np.float32(2))
+    with np.errstate(invalid="ignore"):  # the mean of -inf and inf is NaN, as numpy's median has it
+        return np.where(a == b, a, (a + b) / np.float32(2))
 
 
 def _numpy_fit(x: np.ndarray, start: np.ndarray, sweeps: int):
@@ -263,25 +264,149 @@ SIZES = [
 ]
 
 
-def _kernel_medians(x, labels, k, slab):
-    med, counts = _colmedian.group_medians(jnp.asarray(x), jnp.asarray(labels), k, interpret=True, slab=slab)
+#: ``_NETWORK_MAX`` as the interpreter runs it: every cluster of two or more on
+#: the counting passes, clusters on both sides of a small threshold in one
+#: kernel, and the module's own (the cell's blobs, 37 and 38 rows, by the
+#: network)
+ARMS = {"counting": 1, "both": 5, "as_it_stands": None}
+
+
+def _kernel_medians(x, labels, k, slab, network_max=None):
+    """The kernel in the interpreter; ``network_max`` stands for the module's
+    ``_NETWORK_MAX`` in a program of its own (the constant is read when the
+    kernel is traced, so the module's cached program is passed by)."""
+    with pytest.MonkeyPatch.context() as patch:
+        if network_max is not None:
+            patch.setattr(_colmedian, "_NETWORK_MAX", network_max)
+        run = jax.jit(lambda a, lab: _colmedian.group_medians.__wrapped__(a, lab, k, interpret=True, slab=slab))
+        med, counts = run(jnp.asarray(x), jnp.asarray(labels))
     return np.asarray(med), np.asarray(counts)
 
 
+def _assert_numpys_medians(x, labels, k, med, counts):
+    assert med.shape == (k, x.shape[1]) and med.dtype == np.float32
+    assert np.array_equal(counts, np.bincount(labels, minlength=k))
+    for c in range(k):
+        if counts[c]:
+            want = _medians_nan_last(x[labels == c])
+            assert np.array_equal(med[c], want, equal_nan=True), (c, counts[c], np.abs(med[c] - want).max())
+            if not np.isnan(x[labels == c]).any():
+                with np.errstate(invalid="ignore"):
+                    assert np.array_equal(want, np.median(x[labels == c], axis=0), equal_nan=True)
+
+
+@pytest.mark.parametrize("arm", sorted(ARMS))
 @pytest.mark.parametrize("rows,cols,k,slab", SIZES)
-def test_the_kernel_is_numpys_median_of_every_cluster_bit_for_bit(rows, cols, k, slab):
+def test_the_kernel_is_numpys_median_of_every_cluster_bit_for_bit(rows, cols, k, slab, arm):
     rng = np.random.default_rng(rows * cols)
     x = rng.standard_normal((rows, cols)).astype(np.float32)
     x[:, : cols // 4] = np.round(x[:, : cols // 4])  # columns full of ties
     x[:, cols // 4] = np.float32(1e30)  # a constant column, far out
     labels = rng.integers(0, k, size=rows).astype(np.int32)
-    med, counts = _kernel_medians(x, labels, k, slab)
-    assert med.shape == (k, cols) and med.dtype == np.float32
-    assert np.array_equal(counts, np.bincount(labels, minlength=k))
-    for c in range(k):
-        if counts[c]:
-            want = np.median(x[labels == c], axis=0)
-            assert np.array_equal(med[c], want), (c, counts[c], np.abs(med[c] - want).max())
+    med, counts = _kernel_medians(x, labels, k, slab, ARMS[arm])
+    _assert_numpys_medians(x, labels, k, med, counts)
+
+
+#: members of each cluster, in label order: none, one, two, three, the cell's
+#: 37 and 38; every row in one cluster; rows short of and over a row group
+MEMBERS = {
+    "0_1_2_3_37_38": (0, 1, 2, 3, 37, 38),
+    "all_rows_in_the_last": (0, 0, 45),
+    "all_rows_in_the_first": (300, 0),
+    "38_37_0_2": (38, 37, 0, 2),
+}
+
+
+@pytest.mark.parametrize("network_max", [1, 2, 37, None])
+@pytest.mark.parametrize("name", sorted(MEMBERS))
+def test_the_kernel_on_either_side_of_the_networks_threshold(name, network_max):
+    """Clusters of the sizes the cell sees, each by the method its member
+    count takes at this threshold (at 37: 37 members by the network, 38 by
+    counting, in one kernel; ``None``: the module's own), on columns with
+    duplicates across the middle, NaN and infinite members."""
+    sizes = MEMBERS[name]
+    rng = np.random.default_rng(sum(sizes))
+    labels = rng.permutation(np.repeat(np.arange(len(sizes)), sizes)).astype(np.int32)
+    rows, cols = len(labels), 1024
+    x = rng.standard_normal((rows, cols)).astype(np.float32)
+    x[:, :256] = np.round(x[:, :256])  # ties across the middle
+    x[:, 256:320] = np.float32(7.0)  # every member the same
+    nan = rng.random((rows, 192)) < 0.3
+    x[:, 320:512][nan] = np.nan  # some middles among NaN members, some not
+    x[:, 512:640][rng.random((rows, 128)) < 0.4] = np.inf
+    x[:, 512:640][rng.random((rows, 128)) < 0.4] = -np.inf
+    med, counts = _kernel_medians(x, labels, len(sizes), 128, network_max)
+    assert counts.tolist() == list(sizes)
+    assert int(_colmedian.by_network(jnp.asarray(counts))) == sum(0 < m <= _colmedian._NETWORK_MAX for m in sizes)
+    _assert_numpys_medians(x, labels, len(sizes), med, counts)
+
+
+@pytest.mark.parametrize("members", range(1, 11))
+def test_the_network_passes_the_zero_one_principle_through_the_kernel(members):
+    """A comparison network sorts every input if it sorts every input of
+    zeros and ones: all ``2 ** members`` of them as the columns of one
+    operand, one cluster, its two middles by the network made for that many
+    members, and by the module's own with the members it lacks filled in."""
+    cols = 1024
+    bits = (np.arange(cols)[None, :] >> np.arange(members)[:, None]) & 1  # column j: the bits of j
+    x = bits.astype(np.float32)
+    for network_max in (members, None):
+        med, counts = _kernel_medians(x, np.zeros(members, np.int32), 1, 128, network_max)
+        assert counts.tolist() == [members]
+        assert np.array_equal(med[0], np.median(x, axis=0))
+
+
+def _through(exchanges, x):
+    """The rows of ``x`` after the compare-exchanges ``(i, j)``, ``i < j``."""
+    y = x.copy()
+    for i, j in exchanges:
+        assert 0 <= i < j < len(x)
+        y[i], y[j] = np.minimum(y[i], y[j]), np.maximum(y[i], y[j])
+    return y
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 5, 8, 12, 16])
+def test_the_selection_keeps_the_exchanges_the_lower_half_hears_of(n):
+    """The list the kernel unrolls, by the zero-one principle in numpy: merge
+    exchange sorts all ``2 ** n`` inputs, and the pruned list leaves the
+    lower half's outputs (and the one above) as the whole list does."""
+    x = ((np.arange(1 << n)[None, :] >> np.arange(n)[:, None]) & 1).astype(np.int8)
+    whole, outputs = _colmedian._merge_exchange(n), range(n // 2 + 1)
+    kept = _colmedian._selection(n, outputs)
+    assert np.array_equal(_through(whole, x), np.sort(x, axis=0))
+    assert len(kept) <= len(whole)
+    assert np.array_equal(_through(kept, x)[: n // 2 + 1], np.sort(x, axis=0)[: n // 2 + 1])
+
+
+def test_the_modules_own_selection_on_random_members_and_its_size():
+    """``_NETWORK_MAX`` items are too many for every input of zeros and ones:
+    random thresholds of random orders instead, every member count up to it
+    (the missing ones filled with ones, as the kernel fills them with the
+    largest key), and the count of exchanges the comment of the constant
+    gives."""
+    n = _colmedian._NETWORK_MAX
+    kept = _colmedian._selection(n, range(n // 2 + 1))
+    assert (n, len(_colmedian._merge_exchange(n)), len(kept)) == (40, 283, 258)
+    rng = np.random.default_rng(40)
+    for m in range(1, n + 1):
+        order = rng.permuted(np.tile(np.arange(m)[:, None], (1, 64)), axis=0)
+        x = np.ones((n, 64 * m), np.int8)
+        x[:m] = np.concatenate([order >= t for t in range(m)], axis=1)  # each order cut at every rank
+        y, want = _through(kept, x), np.sort(x[:m], axis=0)
+        assert np.array_equal(y[(m - 1) // 2], want[(m - 1) // 2]) and np.array_equal(y[m // 2], want[m // 2]), m
+
+
+@pytest.mark.parametrize("rank", [1, 2, 10, 19, 20])
+def test_the_network_leaves_every_rank_of_the_lower_half_in_its_place(rank, monkeypatch):
+    """Not only the middle: the ``rank``-th of a cluster of 37 asked for in
+    the middles' place, among 45 rows (a probe plants its faults so)."""
+    monkeypatch.setattr(_colmedian, "_middle_ranks", lambda m: (jnp.minimum(rank, m), jnp.minimum(rank, m)))
+    rng = np.random.default_rng(rank)
+    x = np.round(3 * rng.standard_normal((45, 1024))).astype(np.float32)
+    labels = rng.permutation(np.repeat([0, 1], [8, 37])).astype(np.int32)
+    med, _ = _kernel_medians(x, labels, 2, 128)
+    assert np.array_equal(med[1], np.sort(x[labels == 1], axis=0)[rank - 1])
+    assert np.array_equal(med[0], np.sort(x[labels == 0], axis=0)[min(rank, 8) - 1])
 
 
 def test_the_kernel_sorts_nan_last_keeps_inf_in_order_and_leaves_an_empty_cluster_alone():
@@ -362,14 +487,16 @@ def test_the_kernel_route_through_the_estimator_is_the_bisections_fit(interprete
     def fit():
         km = ht.cluster.KMedians(n_clusters=4, init=ht.array(start, comm=comm), max_iter=3, tol=-1.0)
         km.fit(ht.array(x, split=0, comm=comm))
-        return km.cluster_centers_.numpy(), km.labels_.numpy(), km.n_iter_
+        return km.cluster_centers_.numpy(), km.labels_.numpy(), km.n_iter_, km.selections_by_network_
 
     got = fit()
     (span,) = [e for e in tel.events() if e.get("site") == "jit:kmedians.fit"]
     assert span["kind"] == "launch"
     assert (span["medians"], span["assign"], span["sweeps"], span["x_passes"]) == ("column_select", "manhattan", 3, 7)
+    assert span["network_max"] == _colmedian._NETWORK_MAX >= 13
     want_centres, want_labels = _numpy_fit(x, start, 3)
     assert np.array_equal(got[0], want_centres) and np.array_equal(got[1], want_labels) and got[2] == 3
+    assert got[3] == 3 * 4  # every sweep's four clusters have members, few enough for the network
 
 
 # --------------------------------------------------------------------- #
@@ -387,7 +514,14 @@ def test_the_fit_is_one_launch_with_its_fields_and_one_sync(tel):
     assert span["kind"] == "launch"
     assert (span["medians"], span["assign"], span["sweeps"]) == ("rank_bisection", "manhattan", SWEEPS)
     assert "x_passes" not in span  # the bisection's reads depend on the data: no count is stated
+    assert "network_max" not in span  # the kernel's field: the interpreted route's test holds it
     assert sum(1 for e in events if e.get("site") == "sync:kcluster.n_iter") == 1
+    # the selections by the network are read only when asked: a sync of their own, once
+    assert not [e for e in events if e.get("site") == "sync:kmedians.selections"]
+    syncs = telemetry.host_sync_count()
+    assert km.selections_by_network_ == 0 == km.selections_by_network_  # the bisection makes none
+    assert telemetry.host_sync_count() == syncs + 1
+    assert sum(1 for e in tel.events() if e.get("site") == "sync:kmedians.selections") == 1
     (entry,) = [e for e in events if e.get("site") == "fit:KMedians"]
     assert entry["kind"] == "entry"
     assert count.count >= 1  # the fit's one program, and what lays the given start out

@@ -666,6 +666,7 @@ def test_kmedians_fit_compiles_at_the_cells_size_and_reads_x_as_its_field_says(o
     in_body, outside = _passes_over(compiled, f"f32[300,{CELL_F}]")
     assert (in_body, outside) == (2, 1)
     assert span["medians"] == route and span["x_passes"] == in_body * span["sweeps"] + outside == 61
+    assert span["network_max"] == _colmedian._NETWORK_MAX
 
 
 def test_kmedians_on_rows_over_four_chips_keeps_the_bisection(four_chips, on_the_chip):
