@@ -126,27 +126,6 @@ def emit(line: dict) -> None:
         sys.exit(f"chip_smoke: phase {line['phase']!r} failed: {failed}")
 
 
-class CompileCounter:
-    """Counts compile requests and persistent-cache hits from jax's own
-    monitoring events; ``requests - hits`` programs were compiled afresh."""
-
-    def __init__(self):
-        import jax.monitoring
-
-        self.requests = 0
-        self.hits = 0
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event: str, **_):
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            self.requests += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def read(self):
-        return self.requests, self.hits
-
-
 def float64_chunks(host: np.ndarray, rows: int = 1_000_000):
     """``(start, float64 copy)`` of ``host`` a million rows at a time: the
     numpy references run at full size without a second copy of the data."""
@@ -154,14 +133,25 @@ def float64_chunks(host: np.ndarray, rows: int = 1_000_000):
         yield lo, host[lo:lo + rows].astype(np.float64)
 
 
-def timed(counter: CompileCounter, name: str, fn, *args, **kwargs):
+def compile_counts() -> tuple:
+    """Programs compiled or loaded so far and how many of them the persistent
+    cache served, from the program's own start-up record (jax's monitoring
+    events, ``heat_tpu/core/_compile.py``); ``requests - hits`` were compiled
+    afresh."""
+    from heat_tpu import telemetry
+
+    loads = [r for r in telemetry.startup() if r["site"] == "compile:backend"]
+    return len(loads), sum(1 for r in loads if r["cache_hit"])
+
+
+def timed(name: str, fn, *args, **kwargs):
     """Run one phase function; complete its line with the cold wall time and
     the compile counts; emit it.  Returns what the phase passes on."""
-    r0, h0 = counter.read()
+    r0, h0 = compile_counts()
     t0 = time.perf_counter()
     line, passed_on = fn(*args, **kwargs)
     wall = time.perf_counter() - t0
-    r1, h1 = counter.read()
+    r1, h1 = compile_counts()
     emit({
         "phase": name, **line,
         "cold_wall_s": round(wall, 3),
@@ -976,25 +966,25 @@ def sharded_ring_attention(seed: int, comm, S: int, H: int, D: int):
 # --------------------------------------------------------------------- #
 # drivers                                                                #
 # --------------------------------------------------------------------- #
-def run_one_chip(counter, seed: int) -> None:
-    X = timed(counter, "moments", phase_moments, seed, **FULL["moments"])
-    km = timed(counter, "kmeans", phase_kmeans, X, seed, **FULL["kmeans"])
+def run_one_chip(seed: int) -> None:
+    X = timed("moments", phase_moments, seed, **FULL["moments"])
+    km = timed("kmeans", phase_kmeans, X, seed, **FULL["kmeans"])
     del X
     for name, fn in (("kmedians", phase_kmedians), ("cdist", phase_cdist), ("spectral", phase_spectral), ("lasso", phase_lasso),
                      ("qr_svd", phase_qr_svd), ("attention", phase_attention),
                      ("io", phase_io)):
-        timed(counter, name, fn, seed, **FULL[name])
-    timed(counter, "serve", phase_serve, km, seed, **FULL["serve"])
+        timed(name, fn, seed, **FULL[name])
+    timed("serve", phase_serve, km, seed, **FULL["serve"])
 
 
-def run_sharded(counter, seed: int, comm, n, f, k, iters, mm, S, H, D, cut=None) -> None:
-    XX = timed(counter, "sharded_moments", sharded_moments, seed, comm, n, f)
-    timed(counter, "sharded_kmeans", sharded_kmeans, XX, seed, comm, k, iters)
-    timed(counter, "resplit", sharded_resplit, XX, seed, comm)
-    timed(counter, "tsqr", sharded_qr, XX, comm)
+def run_sharded(seed: int, comm, n, f, k, iters, mm, S, H, D, cut=None) -> None:
+    XX = timed("sharded_moments", sharded_moments, seed, comm, n, f)
+    timed("sharded_kmeans", sharded_kmeans, XX, seed, comm, k, iters)
+    timed("resplit", sharded_resplit, XX, seed, comm)
+    timed("tsqr", sharded_qr, XX, comm)
     del XX
-    timed(counter, "ring_summa_matmul", sharded_matmul, seed, comm, mm)
-    timed(counter, "ring_attention", sharded_ring_attention, seed, comm, S, H, D)
+    timed("ring_summa_matmul", sharded_matmul, seed, comm, mm)
+    timed("ring_attention", sharded_ring_attention, seed, comm, S, H, D)
     if cut:
         emit({"phase": "sharded_cut", "cut": cut})
 
@@ -1006,7 +996,6 @@ def worker(args) -> int:
     from heat_tpu.core._compile_cache import place_compile_cache
 
     cache_dir = place_compile_cache()
-    counter = CompileCounter()
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         sys.exit(f"chip_smoke: jax found no TPU (platform {dev.platform!r}); "
@@ -1021,9 +1010,9 @@ def worker(args) -> int:
     if args.chips == 4:
         import heat_tpu as ht
 
-        run_sharded(counter, args.seed, ht.get_comm(), **FULL_SHARDED, cut=SHARDED_CUT)
+        run_sharded(args.seed, ht.get_comm(), **FULL_SHARDED, cut=SHARDED_CUT)
     else:
-        run_one_chip(counter, args.seed)
+        run_one_chip(args.seed)
     return 0
 
 

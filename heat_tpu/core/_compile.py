@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import functools
 import sys
+import threading
 import types as _types
 from typing import Any, Callable, Dict, Tuple
 
@@ -32,6 +33,40 @@ from ._tracing import in_trace, record_dispatch
 # the profiler is the sink of every recorded span and, through
 # ``TraceAnnotation.is_enabled``, the switch (telemetry/_core.py imports no jax)
 _tel.install_profiler(jax.profiler.TraceAnnotation)
+
+#: jax's own report of each stage of a program's compilation, by the site it
+#: takes in the start-up record (``jax/_src/dispatch.py``); ``backend`` wraps
+#: the persistent cache's read, whose duration jax reports just before it
+_COMPILE_STAGE = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile:trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile:lower",
+    "/jax/core/compile/backend_compile_duration": "compile:backend",
+}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_retrieval = threading.local()  # .s: the read of this thread's compile request, on a hit
+
+
+def _on_compile_stage(event: str, duration_secs: float, fun_name: str = "", **_) -> None:
+    """The package's one listener on jax's compile events: each stage lands
+    in the start-up record under the program's name (``jit(stat.moment2)``
+    reads ``stat.moment2``).  Called only when jax traces, lowers or
+    compiles, never on a replay; starts no backend."""
+    site = _COMPILE_STAGE.get(event)
+    if site is None:
+        if event == _CACHE_RETRIEVAL:
+            _retrieval.s = duration_secs
+        return
+    fields = {"fun": fun_name[4:-1] if fun_name.startswith("jit(") and fun_name.endswith(")") else fun_name}
+    if site == "compile:backend":
+        read_s = getattr(_retrieval, "s", None)
+        _retrieval.s = None
+        fields["cache_hit"] = read_s is not None
+        if read_s is not None:
+            fields["retrieval_s"] = read_s
+    _tel.record_compile_stage(site, duration_secs, **fields)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_stage)
 
 __all__ = [
     "entry",
@@ -192,7 +227,8 @@ def jitted(
     device dispatch per eager invocation (see :mod:`heat_tpu.core._tracing`)
     and, when recording, one ``jitted:<key[0]>`` span of kind ``launch`` (the
     entry's first call carries ``miss=True``: its duration holds trace, lower
-    and compile).  Calls made while a trace is active — an enclosing
+    and compile, each of which the start-up record names apart as a
+    ``compile:*`` child of the span).  Calls made while a trace is active — an enclosing
     ``ht.fuse`` program or any jax trace — inline into the surrounding
     program and are neither counted nor spanned.  The compiled function is
     named after the key's site (and the operation, where the key's second

@@ -23,6 +23,15 @@ One registry for everything the runtime can tell you about itself:
   the device planes, and the trace itself switches recording on
   (:func:`recording`): ``jax.profiler.start_trace(dir)`` … ``stop_trace()``
   then :func:`profiled_spans` for the same spans in memory;
+- **the start-up record** — always on, like the two counters: what each
+  import statement of the package took (kind ``import``) and every
+  program's trace, lower and compile-or-load seconds by name (kind
+  ``compile``, fed by jax's own monitoring events), because neither can
+  wait for ``enable()``.  :func:`startup` gives the records,
+  :func:`startup_report` the text an operator reads after a script's first
+  fit to see why seconds passed before it; it costs a clock read an import
+  statement and three short calls a compiled program, nothing on a warm
+  replay;
 - **exporters** — ``events()`` / ``snapshot()`` (in memory) and a JSONL
   sink (``set_jsonl(path)``);
 - **request tracing** — ``trace_ctx("req-1")`` tags every span and
@@ -87,6 +96,8 @@ from ._core import (
     snapshot,
     span,
     spanned,
+    startup,
+    startup_report,
     trace_ctx,
 )
 from .hist import Histogram
@@ -120,6 +131,8 @@ __all__ = [
     "spanned",
     "self_times",
     "profiled_spans",
+    "startup",
+    "startup_report",
     "host_read",
     "host_sync_count",
     "trace_ctx",

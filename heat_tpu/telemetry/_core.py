@@ -30,12 +30,41 @@ module-level flag; nothing is registered with the compile-cache key
 context, so toggling telemetry can never change what a cached program
 means or force a retrace (asserted by tests/test_telemetry.py).
 
-The always-on state is two counters: the *dispatch counter* (it predates
-telemetry; tier-1 dispatch-count gates consume it through the
-:mod:`heat_tpu.core._tracing` shim) and the *host-sync counter*
-(:func:`host_read`: blocking device-to-host reads).  Both keep counting
-with telemetry disabled and are guarded by the registry lock, so threaded
-serving does not lose increments.
+The always-on state is two counters and the start-up record.  The
+*dispatch counter* (it predates telemetry; tier-1 dispatch-count gates
+consume it through the :mod:`heat_tpu.core._tracing` shim) and the
+*host-sync counter* (:func:`host_read`: blocking device-to-host reads) keep
+counting with telemetry disabled and are guarded by the registry lock, so
+threaded serving does not lose increments.
+
+The start-up record
+-------------------
+What a process does before its first result is mostly not the program's
+own work: importing, and tracing, lowering and compiling (or loading from
+the persistent cache) every program once.  Neither can wait for
+:func:`enable`, so one bounded list (:func:`startup`) keeps both, in the
+shape of a span event and with telemetry disabled:
+
+- kind ``import``: one record a statement of ``heat_tpu/__init__.py`` and
+  ``heat_tpu/core/__init__.py`` (``import:jax``, ``import:core.io``, ...),
+  the latter's as children of ``import:core``, all under the root
+  ``import:heat_tpu``.  The two files stamp ``time.monotonic()`` after each
+  statement and hand the stamps over once (:func:`record_imports`);
+- kind ``compile``: one record each time jax reports that it traced
+  (``compile:trace``), lowered (``compile:lower``) or compiled or loaded
+  (``compile:backend``, with ``cache_hit``) a program, named by ``fun``
+  (:func:`record_compile_stage`, called by the one ``jax.monitoring``
+  listener of ``core/_compile.py``).  Traces nest (a ``jax.numpy`` function
+  traced inside a program's trace reports its own), so a total is the union
+  of the intervals (:func:`covered_s`), never the sum.
+
+It is written when an import statement of the package finishes or jax
+compiles, never on a warm replay: the one-predicate contract of the span
+sites stands as it was.  :func:`reset` leaves it (like the counters);
+overflow is counted (``snapshot()["startup"]["dropped"]``).  While
+:func:`recording`, a ``compile`` record is also an event on the stream,
+the child of the ``launch`` span that caused it.  :func:`startup_report`
+is the operator's reading: call it after a script's first fit.
 
 Determinism
 -----------
@@ -78,6 +107,11 @@ __all__ = [
     "spanned",
     "self_times",
     "profiled_spans",
+    "covered_s",
+    "record_imports",
+    "record_compile_stage",
+    "startup",
+    "startup_report",
     "host_read",
     "host_sync_count",
     "inc",
@@ -155,8 +189,15 @@ _span_var: "contextvars.ContextVar[Optional[Tuple[int, int]]]" = contextvars.Con
 )
 _next_id = 0
 
-#: the layer boundary a span stands at; readers select by it
-KINDS = ("entry", "launch", "sync", "comm", "io", "other")
+#: the layer boundary a span stands at; readers select by it (``import``
+#: and ``compile`` are the start-up record's, see :func:`startup`)
+KINDS = ("entry", "launch", "sync", "comm", "io", "other", "import", "compile")
+
+#: the start-up record (module docstring): always on, bounded, kept by reset()
+_startup: List[dict] = []
+_MAX_STARTUP = 1 << 12
+_startup_dropped = 0
+_COMPILE_SITES = ("compile:trace", "compile:lower", "compile:backend")
 
 #: thread ids -> small stable indices (first-seen order), so exported
 #: ``tid`` values are deterministic in single-threaded runs
@@ -260,7 +301,9 @@ def reset() -> None:
     """Drop all recorded counters, gauges, span aggregates, and events,
     and rewind the deterministic sequence and the span ids.  The dispatch
     and host-sync counters are NOT touched — tests scope them with
-    :func:`counting_dispatches` or a difference of :func:`host_sync_count`."""
+    :func:`counting_dispatches` or a difference of :func:`host_sync_count` —
+    and neither is the start-up record (:func:`startup`): what the process
+    imported and compiled does not happen again."""
     global _det_seq, _next_id, _prof_lo, _prof_hi
     with _lock:
         _counters.clear()
@@ -613,17 +656,23 @@ def self_times(spans) -> Dict[int, float]:
     for ev in spans:
         if ev.get("parent") is not None:
             children.setdefault(ev["parent"], []).append((ev["ts"], ev["ts"] + ev["dur"]))
-    out = {}
-    for ev in spans:
-        lo, hi = ev["ts"], ev["ts"] + ev["dur"]
-        covered, end = 0.0, lo
-        for a, b in sorted(children.get(ev["id"], ())):
-            a, b = max(a, end), min(b, hi)
-            if b > a:
-                covered += b - a
-                end = b
-        out[ev["id"]] = ev["dur"] - covered
-    return out
+    return {
+        ev["id"]: ev["dur"] - covered_s(children.get(ev["id"], ()), ev["ts"], ev["ts"] + ev["dur"])
+        for ev in spans
+    }
+
+
+def covered_s(intervals, lo: float = float("-inf"), hi: float = float("inf")) -> float:
+    """Seconds of ``[lo, hi]`` that the ``(start, end)`` pairs of
+    ``intervals`` cover together: what two that overlap or nest cover both
+    is counted once."""
+    covered, end = 0.0, lo
+    for a, b in sorted(intervals):
+        a, b = max(a, end), min(b, hi)
+        if b > a:
+            covered += b - a
+            end = b
+    return covered
 
 
 def profiled_spans() -> Tuple[dict, ...]:
@@ -639,6 +688,151 @@ def profiled_spans() -> Tuple[dict, ...]:
 
 
 # --------------------------------------------------------------------- #
+# the start-up record                                                   #
+# --------------------------------------------------------------------- #
+def _startup_append(rec: dict) -> None:
+    """Under the lock: keep ``rec``, or count it as dropped."""
+    global _startup_dropped
+    if len(_startup) < _MAX_STARTUP:
+        _startup.append(rec)
+    else:
+        _startup_dropped += 1
+
+
+def record_imports(t0: float, stages, nested) -> None:
+    """The hand-over of the package's import stamps, once, at the end of
+    ``heat_tpu/__init__.py``: ``t0`` is the clock at the file's first line,
+    ``stages`` the ``(what, end)`` of each import statement in file order (a
+    statement runs from the end of the one before it), ``nested`` the same
+    list of a subpackage by the ``what`` of the statement that first
+    imported it.  Records of kind ``import`` whose ``id`` / ``parent`` number
+    the rows of this hand-over alone (0 is the root ``import:heat_tpu``)."""
+    rows = [("import:heat_tpu", None, t0, stages[-1][1])]
+    nested = dict(nested)
+
+    def walk(prefix, parent, start, stages):
+        for what, end in stages:
+            rows.append((f"import:{prefix}{what}", parent, start, end))
+            inner = nested.pop(what, None)
+            if inner:
+                walk(f"{prefix}{what}.", len(rows) - 1, start, inner)
+            start = end
+
+    walk("", 0, t0, stages)
+    with _lock:
+        tid = _tid()
+        for i, (site, parent, start, end) in enumerate(rows):
+            _startup_append({
+                "type": "span", "site": site, "kind": "import", "id": i, "parent": parent,
+                "root": 0, "ts": start, "dur": end - start, "tid": tid,
+            })
+
+
+def record_compile_stage(site: str, dur: float, **fields) -> None:
+    """One stage of one program's compilation, as jax reported it when the
+    stage ended: a record of kind ``compile`` that began ``dur`` ago.  While
+    :func:`recording` it is also an event on the stream, with an id of its
+    own, under the span open on this context (the ``launch`` whose first
+    call traced the program).  Not in deterministic mode: whether a program
+    compiles depends on what the process ran before, which a replay of the
+    event order does not repeat."""
+    global _next_id
+    rec = {
+        "type": "span", "site": site, "kind": "compile", "id": None, "parent": None,
+        "root": None, "ts": _wall() - dur, "dur": dur,
+    }
+    rec.update(fields)
+    on_stream = recording() and not _deterministic
+    with _lock:
+        rec["tid"] = _tid()
+        if on_stream:
+            up = _span_var.get()
+            rec["id"] = _next_id
+            _next_id += 1
+            rec["parent"], rec["root"] = (None, rec["id"]) if up is None else up
+            _emit(rec)
+        _startup_append(rec)
+
+
+def startup() -> Tuple[dict, ...]:
+    """The start-up record (module docstring), oldest first: what the
+    package's import statements took and every program's trace, lower and
+    compile-or-load, kept whether or not telemetry is enabled."""
+    with _lock:
+        return tuple(_startup)
+
+
+def _startup_totals(records) -> dict:
+    """The four totals of ``records``: the package's import, the union of
+    the trace and lower intervals, the union of the compile-or-load
+    intervals, and how many programs were compiled or loaded."""
+    by_site: Dict[str, List[Tuple[float, float]]] = {}
+    for r in records:
+        by_site.setdefault(r["site"], []).append((r["ts"], r["ts"] + r["dur"]))
+    trace, lower, backend = (by_site.get(site, []) for site in _COMPILE_SITES)
+    return {
+        "import_s": sum(b - a for a, b in by_site.get("import:heat_tpu", ())),
+        "trace_lower_s": covered_s(trace + lower),
+        "compile_load_s": covered_s(backend),
+        "programs": len(backend),
+    }
+
+
+def startup_report() -> str:
+    """The start-up record as text, for whoever asks why seconds passed
+    before a script's first result: the import stages largest first, each
+    with its share of ``import:heat_tpu`` (a subpackage's own statements
+    indented under it), then one row a program in the order they first ran
+    (trace, lower, compile-or-load seconds over all its shapes, how many of
+    its loads the persistent cache served), then the totals, in which nested
+    traces count once."""
+    records = startup()
+    totals = _startup_totals(records)
+    lines = [f"import heat_tpu {totals['import_s']:.3f} s"]
+    stages: Dict[Any, Dict[str, float]] = {}  # parent id -> site -> seconds
+    ids: Dict[str, int] = {}  # site -> id of its first record
+    for r in records:
+        if r["kind"] == "import" and r["parent"] is not None:
+            under = stages.setdefault(r["parent"], {})
+            under[r["site"]] = under.get(r["site"], 0.0) + r["dur"]
+            ids.setdefault(r["site"], r["id"])
+
+    def rows(parent, indent):
+        small = []
+        for site, secs in sorted(stages.get(parent, {}).items(), key=lambda kv: -kv[1]):
+            share = 100.0 * secs / totals["import_s"] if totals["import_s"] else 0.0
+            if share < 0.1:
+                small.append(secs)
+                continue
+            lines.append(f"{indent}{site:<34}{secs:9.3f} s {share:5.1f} %")
+            rows(ids[site], indent + "  ")
+        if small:
+            lines.append(f"{indent}{f'({len(small)} more, each under 0.1 %)':<34}{sum(small):9.3f} s")
+
+    rows(0, "  ")
+    programs: Dict[str, dict] = {}  # fun -> the program's row, in the order they first ran
+    for r in records:
+        if r["kind"] == "compile":
+            row = programs.setdefault(r["fun"], {site: [] for site in _COMPILE_SITES} | {"hits": 0})
+            row[r["site"]].append((r["ts"], r["ts"] + r["dur"]))
+            row["hits"] += bool(r.get("cache_hit"))
+    lines.append(f"programs {totals['programs']}: trace s, lower s, compile-or-load s, from the cache / loads")
+    for fun, row in programs.items():
+        trace, lower, backend = (row[site] for site in _COMPILE_SITES)
+        if lower or backend:  # else traced inside another program's trace: its seconds are in that row
+            lines.append(
+                f"  {fun:<34}{covered_s(trace):9.3f}{covered_s(lower):9.3f}{covered_s(backend):9.3f}"
+                f"  {row['hits']}/{len(backend)}"
+            )
+    lines.append(
+        f"totals: import {totals['import_s']:.3f} s, trace+lower {totals['trace_lower_s']:.3f} s, "
+        f"compile-or-load {totals['compile_load_s']:.3f} s, programs {totals['programs']}, "
+        f"records dropped {_startup_dropped}"
+    )
+    return "\n".join(lines)
+
+
+# --------------------------------------------------------------------- #
 # reading                                                               #
 # --------------------------------------------------------------------- #
 def events() -> Tuple[dict, ...]:
@@ -648,7 +842,9 @@ def events() -> Tuple[dict, ...]:
 
 
 def snapshot() -> dict:
-    """The in-memory export: counters, gauges, and per-site span totals.
+    """The in-memory export: counters, gauges, per-site span totals and
+    the start-up record's totals (``startup``: ``import_s``,
+    ``trace_lower_s``, ``compile_load_s``, ``programs``, ``dropped``).
 
     Empty dict while telemetry is disabled — the cheap way for callers
     to branch on "was anything collected"."""
@@ -664,6 +860,7 @@ def snapshot() -> dict:
             },
             "hists": {name: _hists[name].state() for name in sorted(_hists)},
             "events": len(_events),
+            "startup": dict(_startup_totals(_startup), dropped=_startup_dropped),
         }
 
 

@@ -141,15 +141,23 @@ def test_disabled_records_nothing():
             telemetry.enable()
 
 
-def test_toggling_telemetry_never_changes_cache_keys(tmp_path):
+def test_toggling_telemetry_never_changes_cache_keys(tmp_path, monkeypatch):
     """Enabling telemetry must not register a key context or retrace:
-    the same op replayed across toggles adds zero cache entries."""
+    the same op replayed across toggles adds zero cache entries.  And the
+    always-on start-up record is written when jax compiles, never on a
+    replay: a warm program makes no call into it, recording or not."""
     from heat_tpu.core import _compile
 
     was = _core.is_enabled()
     x = ht.arange(8, split=0)
     (x + 1).larray.block_until_ready()  # populate the cache
     n0 = _compile.cache_size()
+
+    def no_call(*args, **kwargs):
+        raise AssertionError(f"a warm replay wrote to the start-up record: {args}")
+
+    monkeypatch.setattr(_core, "record_compile_stage", no_call)
+    monkeypatch.setattr(_core, "_startup_append", no_call)
     traces0 = {k: f.jitted._cache_size() for k, f in _compile._CACHE.items()}
     try:
         telemetry.enable()
