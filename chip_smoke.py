@@ -276,7 +276,8 @@ def phase_kmeans(X, seed: int, k: int, iters: int, n_ref: int):
     # centres, has not risen since the first step, and ends where an
     # independent float64 loop on the first n_ref rows ends (the host takes
     # minutes for the whole loop at full size).
-    km = fit(X, iters)
+    with launch_spans("jit:kmeans.fit_segment") as spans:
+        km = fit(X, iters)
     own_labels, _, _, own_inertia = _numpy_assign(
         host, km.cluster_centers_.numpy().astype(np.float64))
     n_ref = min(n_ref, n)
@@ -316,6 +317,9 @@ def phase_kmeans(X, seed: int, k: int, iters: int, n_ref: int):
                      f"final centres; the whole loop in numpy on the first {n_ref} "
                      f"rows, for its inertia (subsample: the host cannot run "
                      f"{iters} iterations at full size in a minute)",
+        # the layout of the sweep operand the fit's segment took
+        # (``cluster/kmeans.py:_feature_layout``): one chip keeps its rows
+        "routes": {"layout": [e["layout"] for e in spans]},
         "checks": checks, "device_dtypes": device_dtypes(),
     }
     return line, km
@@ -818,7 +822,8 @@ def sharded_kmeans(XX, seed: int, comm, k: int, iters: int):
         return (a.labels_.numpy() == b.labels_.numpy()).mean()
 
     step, step1 = fit_both(1)
-    km, km1 = fit_both(iters)
+    with launch_spans("jit:kmeans.fit_segment") as spans:
+        km, km1 = fit_both(iters)
     # One Lloyd step from the same centres is the same arithmetic with the
     # (k, f) partial sums combined across devices in another order.  An f32
     # sum of n/k rows carries about 2^-24 * sqrt(n/k) of its mean (8e-5 at 16M
@@ -840,6 +845,9 @@ def sharded_kmeans(XX, seed: int, comm, k: int, iters: int):
         "sizes": {"rows": X.shape[0], "clusters": k, "iterations": iters},
         "reference": "the same fit on a one-device communicator, after one "
                      "iteration and after all",
+        # the sweep operand's layout, the sharded fit's then its twin's: tall,
+        # narrow rows keep the row layout (``cluster/kmeans.py:_feature_layout``)
+        "routes": {"layout": [e["layout"] for e in spans]},
         "checks": checks, "device_dtypes": device_dtypes(),
     }
     return line, None

@@ -7,17 +7,24 @@ the centroid-shift inertia.
 
 TPU formulation: the update's masked sums are written as
 ``one_hot(labels).T @ X`` — a single MXU matmul — and the whole
-assign+update step is one fused XLA computation over the row-sharded data;
-the per-cluster Allreduce pairs of the reference (2k collectives per epoch,
-kmeans.py:58-86) become one all-reduce of the (k, f) partial sums.
+assign+update step is one fused XLA computation.  The per-cluster Allreduce
+pairs of the reference (2k collectives per epoch, kmeans.py:58-86) become,
+in the layout the compiled segment picks for its sweep operand
+(:func:`_feature_layout`): over rows split across the mesh, one all-reduce
+of the (k, f) partial sums a sweep; over feature columns (wide, short data,
+where that moves fewer bytes: the rows go to columns in one all-to-all a
+segment), one all-reduce of the (n, k) distance partials a sweep.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..core._compile import launch
 from ..core.dndarray import DNDarray
@@ -92,50 +99,20 @@ class KMeans(_KCluster):
         self.mini_batch = None if mini_batch is None else int(mini_batch)
 
     @staticmethod
-    @jax.jit
     def _fit_segment(arr, tol, stop, carry):
-        """Lloyd iterations as ONE compiled ``lax.while_loop`` program,
-        re-enterable: the carry ``(it, centers, shift)`` comes in
-        explicitly and steps run while ``it < stop`` — the whole fit is
-        one segment with ``stop = max_iter``; checkpointed fits replay
-        THIS program segment by segment, which is what makes resume
-        bitwise-exact.  One dispatch, zero host syncs per segment — the
-        host never sees intermediate state (the reference's per-epoch
-        convergence check, kmeans.py:106-118, costs a device round trip
-        per iteration).  The |x|² row norms are omitted from the
-        assignment entirely: they are constant across the k candidates,
-        so ``argmin_k(|x|² + |c|² − 2x·c) == argmin_k(|c|² − 2x·c)``
-        exactly, which saves a pass over ``arr``.  On the chip a sweep is
-        still TWO passes over a bf16 copy of ``arr``: XLA emits the
-        distance matmul with its argmin and the one-hot masked-sum
-        matmul as two fusions, 160 + 169 ms of a 496.6 ms job at
-        300 x 6 291 456 (``roofline_pct`` 75.5; ledger, PR 29,
-        ``kmeans_300_c1``; breakdown in ``PERF.md`` §5).  The one-pass
-        row-blocked sweep is ROADMAP Speed 2, open."""
-
-        def step(c):
-            with jax.named_scope("kmeans.sweep.assign"):
-                c2 = jnp.sum(c * c, axis=1)[None, :]  # (1, k)
-                d2 = c2 - 2.0 * jnp.matmul(arr, c.T)  # shifted by the const |x|²
-                labels = jnp.argmin(d2, axis=1)
-            with jax.named_scope("kmeans.sweep.update"):
-                sel = jax.nn.one_hot(labels, c.shape[0], dtype=arr.dtype)
-                sums = jnp.matmul(sel.T, arr)  # (k, f) masked sum on the MXU
-                counts = jnp.sum(sel, axis=0)[:, None]
-                nc = jnp.where(counts > 0, sums / jnp.maximum(counts, 1), c)
-            return labels, nc
-
-        def cond(state):
-            it, _, shift = state
-            return jnp.logical_and(it < stop, shift > tol)
-
-        def body(state):
-            it, c, _ = state
-            _, nc = step(c)
-            shift = jnp.sum((nc - c) ** 2)
-            return it + 1, nc, shift
-
-        return jax.lax.while_loop(cond, body, carry)
+        """One segment of the fit, issued at ``jit:kmeans.fit_segment``: the
+        sweeps ``carry[0] .. stop`` of the compiled program
+        :func:`_fit_segment`, in the layout :func:`_feature_layout` picks
+        for this operand, mesh and sweep count, which the span's ``layout``
+        field names.  ``stop`` and ``carry[0]`` are read on the host, where
+        ``fit`` holds both already (``np.int32(stop)``, ``sync:kmeans.it0``);
+        the carry ``(it, centers, shift)`` is replicated in and out in
+        either layout."""
+        cols = _feature_layout(arr, carry[1].shape[0], int(stop) - int(carry[0]))
+        return launch(
+            "jit:kmeans.fit_segment", _fit_segment, (arr, tol, stop, carry), {"cols": cols},
+            layout="rows" if cols is None else "features",
+        )
 
     @staticmethod
     @jax.jit
@@ -231,10 +208,7 @@ class KMeans(_KCluster):
                         arr, tol, jnp.int32(stop), carry, comm=comm, mode=mode
                     )
                 else:
-                    carry = launch(
-                        "jit:kmeans.fit_segment", KMeans._fit_segment,
-                        (arr, tol, jnp.int32(stop), carry),
-                    )
+                    carry = KMeans._fit_segment(arr, tol, np.int32(stop), carry)
             it = _tel.host_read("sync:kmeans.it", carry[0], int)
             if use_q and _tel.enabled and it > it0:
                 from ..comm import compressed as _cq
@@ -375,6 +349,130 @@ class KMeans(_KCluster):
             "mini-batch/streaming fits support init='random' or an explicit "
             f"DNDarray of centroids, got {self.init!r}"
         )
+
+
+def _sweep_dtype(mesh):
+    """The dtype the sweeps' products read X in: bfloat16 on the TPU at the
+    default matmul precision, where XLA makes every float32 product one bf16
+    pass on the MXU (and the row program a bf16 copy of X, ``convert.3``),
+    so the copy the feature layout exchanges rounds nothing new; float32
+    elsewhere."""
+    tpu = mesh.devices.flat[0].platform == "tpu"
+    return jnp.bfloat16 if tpu and jax.config.jax_default_matmul_precision is None else jnp.float32
+
+
+def _feature_layout(arr, k: int, sweeps: int):
+    """The feature layout's sharding of X, ``P(None, axis)``, where the
+    segment's ``sweeps`` should read X split by features; ``None`` for the
+    row layout, which every other operand keeps.
+
+    Only for rows lying evenly over a mesh axis of ``p > 1`` devices and at
+    least ``p`` features.  Then the bytes a chip puts on the ICI decide
+    (the common ``(p-1)/p`` left out): the row layout all-reduces the
+    ``(k, f)`` float32 sums every sweep (``2·k·f·4`` a sweep, ring); the
+    feature layout exchanges its rows of the sweep copy once (``n/p`` rows
+    of the ``f`` columns padded to ``p·w``), all-gathers the ``(k, f)``
+    centres at the end and all-reduces an ``(n, k)`` distance partial every
+    sweep.  The feature layout is taken where it puts at most half the row
+    layout's bytes on the ICI: the margin pays for the second copy of X the
+    exchange holds in HBM while it runs.  At the 4-chip cell (448 x 6 291 456,
+    k 8, 30 sweeps) that is 1.06 GB a chip against 9.1 GB; a tall operand,
+    n above about 2·k·sweeps·p (k·sweeps·p where the copy is float32), keeps
+    the rows."""
+    sh = getattr(arr, "sharding", None)
+    if not isinstance(sh, NamedSharding):
+        return None
+    spec = tuple(sh.spec) + (None, None)
+    axis = spec[0]
+    if not isinstance(axis, str) or spec[1] is not None:
+        return None
+    p = sh.mesh.shape[axis]
+    n, f = arr.shape
+    if p < 2 or n % p or f < p:
+        return None
+    fp = -(-f // p) * p
+    rows = sweeps * 2 * k * f * 4
+    cols = (n // p) * fp * jnp.dtype(_sweep_dtype(sh.mesh)).itemsize + k * fp * 4 + sweeps * 2 * n * k * 4
+    return NamedSharding(sh.mesh, PartitionSpec(None, axis)) if 2 * cols <= rows else None
+
+
+def _sweep(a, c):
+    """One Lloyd step of the centres ``c`` over the operand ``a``.  The |x|²
+    row norms are left out of the assignment: they are constant across the
+    k candidates, so ``argmin_k(|x|² + |c|² − 2x·c) == argmin_k(|c|² −
+    2x·c)`` exactly, which saves a pass over ``a``."""
+    with jax.named_scope("kmeans.sweep.assign"):
+        c2 = jnp.sum(c * c, axis=1)[None, :]  # (1, k)
+        d2 = c2 - 2.0 * jnp.matmul(a, c.T.astype(a.dtype), preferred_element_type=jnp.float32)
+        labels = jnp.argmin(d2, axis=1)
+    with jax.named_scope("kmeans.sweep.update"):
+        sel = jax.nn.one_hot(labels, c.shape[0], dtype=a.dtype)
+        sums = jnp.matmul(sel.T, a, preferred_element_type=jnp.float32)  # (k, f) masked sum on the MXU
+        counts = jnp.sum(sel, axis=0, dtype=jnp.float32)[:, None]
+        return jnp.where(counts > 0, sums / jnp.maximum(counts, 1), c)
+
+
+def _lloyd(a, tol, stop, carry):
+    """The Lloyd ``while_loop`` over the carry ``(it, centers, shift)``:
+    sweeps run while ``it < stop`` and the last shift exceeds ``tol``."""
+
+    def cond(state):
+        it, _, shift = state
+        return jnp.logical_and(it < stop, shift > tol)
+
+    def body(state):
+        it, c, _ = state
+        nc = _sweep(a, c)
+        shift = jnp.sum((nc - c) ** 2)
+        return it + 1, nc, shift
+
+    return jax.lax.while_loop(cond, body, carry)
+
+
+@partial(jax.jit, static_argnames=("cols",))
+def _fit_segment(arr, tol, stop, carry, cols=None):
+    """Lloyd iterations as ONE compiled ``lax.while_loop`` program,
+    re-enterable: the carry ``(it, centers, shift)`` comes in explicitly
+    and steps run while ``it < stop`` — the whole fit is one segment with
+    ``stop = max_iter``; checkpointed fits replay THIS program segment by
+    segment, which is what makes resume bitwise-exact.  One dispatch, zero
+    host syncs per segment — the host never sees intermediate state (the
+    reference's per-epoch convergence check, kmeans.py:106-118, costs a
+    device round trip per iteration).
+
+    One loop (:func:`_lloyd`) over either of two layouts of X; ``cols`` is
+    :func:`_feature_layout`'s answer, and GSPMD places the collectives.
+
+    * Rows (``cols=None``): the loop runs on X as it lies.  On one chip a
+      sweep is TWO passes over a bf16 copy of X: XLA emits the distance
+      matmul with its argmin and the one-hot masked-sum matmul as two
+      fusions, 160 + 169 ms of a 496.6 ms job at 300 x 6 291 456
+      (``roofline_pct`` 75.5; ledger, PR 29, ``kmeans_300_c1``; breakdown
+      in ``PERF.md`` §5); the one-pass row-blocked sweep is ROADMAP
+      Speed 2, open.  Over rows split across chips the masked sum contracts
+      the row axis, so every sweep ends in an all-reduce of the (k, f)
+      float32 sums and each chip repeats the whole update.
+    * Features (``cols = P(None, axis)``): X, cast once to the sweeps'
+      dtype (:func:`_sweep_dtype`) and its columns zero-padded to a
+      multiple of the mesh (they add nothing to any sum), goes from rows to
+      feature columns in ONE all-to-all before the loop, and so do the
+      centres (a slice of a replicated array).  Each chip sweeps all n rows
+      of its columns: the distance product contracts the features, so
+      only its (n, k) partial is all-reduced; the argmin and one-hot are
+      replicated; the masked sums, counts and update are the chip's own;
+      the shift is a scalar all-reduce.  The centres are all-gathered at
+      the end."""
+    if cols is None:
+        return _lloyd(arr, tol, stop, carry)
+    f = arr.shape[1]
+    pad = ((0, 0), (0, -f % cols.mesh.shape[cols.spec[1]]))
+
+    def by_features(x):
+        return jax.lax.with_sharding_constraint(jnp.pad(x, pad), cols)
+
+    it, c, shift = carry
+    it, c, shift = _lloyd(by_features(arr.astype(_sweep_dtype(cols.mesh))), tol, stop, (it, by_features(c), shift))
+    return it, jax.lax.with_sharding_constraint(c[:, :f], NamedSharding(cols.mesh, PartitionSpec())), shift
 
 
 def _kmeans_mb_segment(comm, mb, f, k):

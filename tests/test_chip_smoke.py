@@ -80,6 +80,7 @@ def test_kmeans_phase_and_its_64_bit_labels_are_seen(kmeans):
     assert _failed(line) == []
     assert km.n_iter_ == 5
     assert "int64" in line["device_dtypes"]  # labels_: what Motivation 8 asked to see
+    assert line["routes"] == {"layout": ["rows"]}  # 4096 x 32: tall and narrow
 
 
 @pytest.mark.parametrize(
@@ -253,6 +254,8 @@ def test_sharded_phase_matches_its_one_device_twin(sharded_moments, four, phase,
     line, _ = phase(sharded_moments[1], comm=four, **extra)
     _complete(line)
     assert _failed(line) == []
+    if phase is chip_smoke.sharded_kmeans:  # four devices and the one-device twin
+        assert line["routes"] == {"layout": ["rows", "rows"]}
 
 
 def test_sharded_ring_summa_matches_its_one_device_twin(four):

@@ -210,17 +210,41 @@ def _shape(shape, sharding, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _collectives(text):
+    """``(opcode, elements of its largest operand)`` of every collective the
+    compiled module holds (a tuple of combined operands too), async starts
+    under their collective's name."""
+    out = []
+    for shape, op in re.findall(r"^\s*(?:ROOT )?%?\S+ = (.*?) " + _COLLECTIVE + r"(?:-start)?\(", text, re.M):
+        arrays = re.findall(r"\w+\[([\d,]*)\]", shape)
+        out.append((op, max(math.prod(int(d) for d in dims.split(",") if d) for dims in arrays)))
+    return out
+
+
 def test_kmeans_fit_segment_compiles_with_its_sweep_scopes(cell):
-    from heat_tpu.cluster.kmeans import KMeans
+    from heat_tpu.cluster import kmeans
 
     rows, xs, rep, _ = cell
+    x = _shape((rows, CELL_F), xs)
     carry = (_shape((), rep, jnp.int32), _shape((CELL_K, CELL_F), rep), _shape((), rep))
-    compiled = KMeans._fit_segment.lower(
-        _shape((rows, CELL_F), xs), _shape((), rep), _shape((), rep, jnp.int32), carry
+    cols = kmeans._feature_layout(x, CELL_K, 30)  # the cells' 30 sweeps, one segment
+    assert (cols is None) == (xs is rep)  # one chip keeps its rows, four take the columns
+    compiled = kmeans._fit_segment.lower(
+        x, _shape((), rep), _shape((), rep, jnp.int32), carry, cols=cols
     ).compile()
     _assert_scopes(compiled, "jit__fit_segment", ["kmeans.sweep.assign", "kmeans.sweep.update"])
-    if xs is not rep:  # the per-sweep exchange of the centre sums exists only across chips
-        assert "all-reduce" in compiled.as_text()
+    if cols is None:
+        return
+    # across chips X goes to feature columns once, by one all-to-all (never
+    # an all-gather of X: 5.6 GB a chip, PR 26); no sweep all-reduces the
+    # (k, f) centre sums, only the (n, k) distance partial and the shift;
+    # the one all-gather is the centres' at the end
+    found = _collectives(compiled.as_text())
+    assert [op for op, _ in found].count("all-to-all") == 1, found
+    assert all(e < CELL_K * CELL_F for op, e in found if op == "all-reduce"), found
+    assert all(e <= CELL_K * CELL_F for op, e in found if op == "all-gather"), found
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes < 16 << 30
 
 
 def test_kmeans_finalize_compiles_with_its_scope(cell):
