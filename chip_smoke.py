@@ -530,16 +530,20 @@ def phase_qr_svd(seed: int, m: int, n: int):
     ht.random.seed(seed + 4)
     A = ht.random.randn(m, n, split=0)
     norm_a = float(ht.linalg.norm(A))
-    q, r = ht.linalg.qr(A)
+    with launch_spans("jitted:linalg.qr", "jitted:linalg.svd") as spans:
+        q, r = ht.linalg.qr(A)
+        u, s, v = ht.linalg.svd(A)
     qr_res = float(ht.linalg.norm(ht.matmul(q, r) - A)) / norm_a
     ortho = float(ht.linalg.norm(ht.matmul(q.T, q) - ht.eye(n)))
-    u, s, v = ht.linalg.svd(A)
     svd_res = float(ht.linalg.norm(ht.matmul(u * s, v.T) - A)) / norm_a
     s_ref = np.linalg.svd(r.numpy().astype(np.float64), compute_uv=False)
-    # linalg matmuls run at precision "highest" (f32 through six bf16 passes);
-    # Householder QR of an f32 matrix this tall: 1e-4 relative on what is
-    # reconstructed.  Q's columns are norms and dots over m f32 terms, so
-    # |Q^T Q - I|_F is held to n * sqrt(m) * u, u = 2^-24 (7.8e-3 at full size).
+    # one chip takes the blocked CholeskyQR2 route (qr.tall_route: Q = A R^-1
+    # and U = A R^-1 U_R, each one more pass over A, the products at
+    # "highest"; orthonormal to about u * kappa(A), and a normal A's kappa is
+    # near 1), elsewhere XLA's Householder QR of the whole operand: 1e-4
+    # relative on what is reconstructed either way.  Q's columns are norms and
+    # dots over m f32 terms, so |Q^T Q - I|_F is held to n * sqrt(m) * u,
+    # u = 2^-24 (7.8e-3 at full size; 1.26e-3 read on the Householder route).
     checks = {
         "qr_residual_rel": check(qr_res, 1e-4),
         "q_orthogonality": check(ortho, _orthogonality_limit(m, n)),
@@ -548,6 +552,7 @@ def phase_qr_svd(seed: int, m: int, n: int):
     }
     line = {
         "sizes": {"rows": m, "cols": n, "bytes": m * n * 4},
+        "routes": {e["site"].split(":", 1)[1]: {"route": e["route"], "a_passes": e["a_passes"]} for e in spans},
         "reference": "residuals on the device; singular values against numpy "
                      "float64 on the R factor",
         "checks": checks, "device_dtypes": device_dtypes(),

@@ -21,8 +21,26 @@ TPU-first design (per SURVEY.md §7 build plan, item 8):
   for κ(A) ≲ 1/√ε) and factored locally.  ``tiles_per_proc`` subdivides
   each mesh position's panel, matching the reference's latency/parallelism
   knob (qr.py:31-36).
-* replicated or wide (m < n) inputs use on-device ``jnp.linalg.qr`` (same
-  as reference split=None, qr.py:70-94).
+* **held whole on a device (one device, or replicated), m ≥ n: one cached
+  program** (``jitted:linalg.qr``; reference split=None, qr.py:70-94) on
+  the route :func:`tall_route` names from the shape, the dtype and the
+  backend: a float32 operand of :data:`MIN_BYTES` or more on a TPU takes
+  **blocked CholeskyQR2** — two Gram passes over blocks of rows (the second
+  over the implicit ``Q1 = A·R1⁻¹``, formed a block at a time and never
+  stored), two (n, n) Cholesky factorizations, ``R = R2·R1``; Q, where asked
+  for, is ``A·R⁻¹`` written once in a third pass.  Nothing of A's size is
+  made beside A and Q, which is what lets a chip factor an operand that
+  fills half of it.  Q made so is orthonormal to about u·κ(A), so the
+  program checks its factor: where R is not finite (the Gram's Cholesky
+  broke down: κ(A)² near 1/u, a rank-deficient A) or κ(R) passes
+  :data:`KAPPA_MAX`, the same program factors by the blocked TSQR instead
+  (Householder QRs of blocks of rows, the blocks' Q written into the
+  output's buffer), orthonormal to rounding.  Every other operand takes
+  XLA's Householder QR of the whole operand (``householder``), which needs
+  a working copy of A.  The launch span states ``route``, ``a_passes``
+  (reads of A), ``precision`` (of the tall products) and, on ``cholqr2``,
+  ``fallback``.
+* wide (m < n) inputs use on-device ``jnp.linalg.qr``.
 
 The one remaining distributed fallback — split=0 with more than
 ``m / n`` devices, where shards are wider than tall and TSQR's local QR
@@ -65,11 +83,9 @@ def _tsqr_program(comm):
     """The two-stage TSQR pipeline as a traceable ``f(x) -> (q, r)`` over
     a shard-padded row-split operand: per-shard local QR inside shard_map,
     a second QR of the small (size·n, n) R stack, and the Q-correction
-    matmul.  :func:`_tsqr` wraps it in the keyed-jit cache.  A single-device mesh degenerates to
-    one on-device QR (what :func:`qr` dispatches there)."""
-    if comm.size == 1:
-        return jnp.linalg.qr
-
+    matmul.  :func:`_tsqr` wraps it in the keyed-jit cache.  A mesh of
+    one device never comes here: :func:`qr` factors an operand held whole
+    on a device by :func:`_one_device_qr`."""
     mesh = comm.mesh
     axis = comm.axis_name
 
@@ -107,6 +123,293 @@ def _tsqr_program(comm):
     return _f
 
 
+#: bytes from which a tall float32 operand held whole by a TPU takes the
+#: blocked CholeskyQR2 route (:func:`tall_route`): from the smallest size timed
+#: on a v5e, 4 MB, it was the faster at 64 and at 300 columns, 1.44 against
+#: 2.12 ms at 16 384 x 64 and 6.7 against 6.9 ms at 4 096 x 300, 14 against
+#: 180 ms at 262 144 x 300 (``scripts/time_tall_svd.py``; PERF.md, section 6).
+#: Nothing smaller was timed, so the whole operand's Householder QR keeps it
+MIN_BYTES = 4 << 20
+#: rows of A a step of the blocked passes reads (78.6 MB at 300 columns)
+BLOCK_ROWS = 1 << 16
+#: MXU precision of the CholeskyQR2 route's tall products (the Grams, the
+#: implicit Q1 = A·R1⁻¹, and U = A·W or Q = A·R⁻¹), fixed here and not taken
+#: from ``basics.set_matmul_precision``: on the benchmark's 6 291 456 x 300
+#: operand (κ about 70) three passes leave U orthonormal only to 1.3e-4 and one
+#: pass to 2.5e-2, where six read 5e-6 (PERF.md, section 2)
+TALL_PRECISION = "highest"
+#: the largest κ(R) = S[0] / S[-1] for which the CholeskyQR2 route forms Q (or
+#: U) from A in one product.  ``A·R⁻¹`` is orthonormal only to about u·κ(A)
+#: (u = 2⁻²⁴), so this keeps the loss under 6e-5, the order of what the blocked
+#: TSQR itself reads at the benchmark's size on a v5e, 1.2e-5 to 3.0e-5
+#: (PERF.md, section 6); an operand past it, or one whose Cholesky broke down,
+#: takes the blocked TSQR
+KAPPA_MAX = 1e3
+
+
+def whole_on_each_device(a: DNDarray) -> bool:
+    """True where every device of ``a``'s mesh holds all of ``a``: one device,
+    or a replicated operand.  A program over the raw array is then the whole
+    factorization, with no collective."""
+    return a.comm.size == 1 or a.split is None
+
+
+def tall_route(shape, dtype) -> str:
+    """The factorization a tall operand held whole on a device takes:
+    ``cholqr2`` for a float32 matrix of at least :data:`MIN_BYTES` in a
+    process on a TPU, ``householder`` (XLA's QR of the whole operand, which
+    needs an (m, n) working copy beside its outputs) for anything else.
+
+    The blocked route holds nothing of A's size beside A and its output, so it
+    is what fits a chip at a size that fills it; it also reads A only two or
+    three times where the Householder QR of XLA sweeps its copy once a column,
+    which is why it is the faster on the chip from :data:`MIN_BYTES`.  It is
+    sound at any κ(A): its program checks its own factor and takes the blocked
+    TSQR where CholeskyQR2 is not (:func:`_sound`).  Off the TPU nothing was
+    timed, and the Householder form stays."""
+    m, n = shape
+    if (
+        m >= n
+        and jnp.dtype(dtype) == jnp.float32
+        and m * n * 4 >= MIN_BYTES
+        and jax.default_backend() == "tpu"
+    ):
+        return "cholqr2"
+    return "householder"
+
+
+def _tall_dot(a, b, precision):
+    """One tall product of the CholeskyQR2 route (the seam its planted faults
+    replace, ``perf/tools/limits_probe_svd.py``)."""
+    return jnp.matmul(a, b, precision=precision)
+
+
+def _gram(a, rinv, precision):
+    """``Σ_b (A_b·rinv)ᵀ(A_b·rinv)`` over blocks of :data:`BLOCK_ROWS` rows,
+    ``A_bᵀA_b`` where ``rinv`` is None: ONE read of A, nothing of its size
+    made (the block's ``A_b·rinv`` is the implicit Q1's rows, never stored)."""
+    m, n = a.shape
+    block = min(BLOCK_ROWS, m)
+    full, tail = divmod(m, block)
+
+    def part(rows):
+        q = rows if rinv is None else _tall_dot(rows, rinv, precision)
+        return _tall_dot(q.T, q, precision)
+
+    def step(i, g):
+        return g + part(jax.lax.dynamic_slice_in_dim(a, i * block, block, 0))
+
+    g = jax.lax.fori_loop(0, full, step, jnp.zeros((n, n), a.dtype))
+    if tail:
+        g = g + part(a[full * block :])
+    return g
+
+
+def _cholesky_r(g):
+    """The upper factor R of a Gram ``g = RᵀR``."""
+    return jnp.linalg.cholesky(0.5 * (g + g.T)).T
+
+
+def _upper_inverse(r):
+    return jax.scipy.linalg.solve_triangular(r, jnp.eye(r.shape[0], dtype=r.dtype), lower=False)
+
+
+def _second_pass(a, r1, r1inv, precision):
+    """CholeskyQR's second pass over ``Q1 = A·R1⁻¹``: ``(R, R⁻¹)`` of
+    ``A = Q·R`` with ``R = R2·R1``.  The first pass's Q1 is orthogonal only to
+    ``u·κ(A)²``; its own Gram has κ near 1, so R is the factor of a Q
+    orthogonal to rounding.  That Q is never formed: ``A·R⁻¹``, made in one
+    product, is orthonormal to about ``u·κ(A)`` (:data:`KAPPA_MAX`)."""
+    r2 = _cholesky_r(_gram(a, r1inv, precision))
+    r = jnp.matmul(r2, r1, precision="highest")
+    rinv = jnp.matmul(r1inv, _upper_inverse(r2), precision="highest")
+    return r, rinv
+
+
+def _cholqr2(a, precision):
+    """``(R, R⁻¹)`` of the tall ``a`` by CholeskyQR2 (Fukaya et al., 2014):
+    two blocked Gram passes over A, two (n, n) Cholesky factorizations.  Q is
+    not built: a caller that needs it forms ``A·R⁻¹`` in one more pass.  A
+    Gram whose Cholesky breaks down (κ(A)² near 1/u, a rank-deficient A)
+    leaves NaN in both."""
+    r1 = _cholesky_r(_gram(a, None, precision))
+    return _second_pass(a, r1, _upper_inverse(r1), precision)
+
+
+def _r_svd(r):
+    """``(U_R, S, V)`` of the (n, n) factor, on the device."""
+    ur, s, vt = jnp.linalg.svd(r, full_matrices=False)
+    return ur, s, vt.T
+
+
+def _sound(r, rinv, compute_uv: bool):
+    """``(sound, R, R⁻¹, (U_R, S, V))``: whether the CholeskyQR2 factor may
+    form Q or U from A — finite, and κ(R) at most :data:`KAPPA_MAX` — and
+    R's SVD (S alone, the others None, where not ``compute_uv``).  A factor
+    that is not finite is replaced by the identity first, so that the small
+    SVD runs on numbers."""
+    finite = jnp.isfinite(r).all() & jnp.isfinite(rinv).all()
+    eye = jnp.eye(r.shape[0], dtype=r.dtype)
+    r, rinv = jnp.where(finite, r, eye), jnp.where(finite, rinv, eye)
+    small = _r_svd(r) if compute_uv else (None, jnp.linalg.svd(r, compute_uv=False), None)
+    s = small[1]
+    return finite & (s[-1] * KAPPA_MAX >= s[0]), r, rinv, small
+
+
+def _tsqr_blocks(m, n):
+    """``(rows a block, full blocks, tail rows)`` of the blocked TSQR: blocks
+    of :data:`BLOCK_ROWS`, never fewer rows than columns."""
+    block = min(m, max(BLOCK_ROWS, n))
+    return (block,) + divmod(m, block)
+
+
+def _blocked_tsqr(a, keep_q: bool):
+    """``(R, Q_s, buffer)`` of the tall ``a`` by TSQR over blocks of rows, ONE
+    read of A: each block's Householder QR ``A_b = Q_b·R_b``, then the QR of
+    the stacked ``R_b``, ``Q_s·R``.  Where ``keep_q``, the blocks' ``Q_b`` are
+    written into an (m, n) buffer, the caller's output (a tail of fewer rows
+    than columns fills its first columns), and :func:`_tsqr_apply` makes Q or
+    U in it; else the buffer is None.  R's diagonal is made non-negative, as
+    CholeskyQR's is."""
+    m, n = a.shape
+    block, full, tail = _tsqr_blocks(m, n)
+    k = min(tail, n)
+    stack = jnp.zeros((full * n + k, n), a.dtype)
+    buf = jnp.zeros((m, n), a.dtype) if keep_q else None
+
+    def factor(rows):
+        return jnp.linalg.qr(rows) if keep_q else (None, jnp.linalg.qr(rows, mode="r"))
+
+    def step(i, carry):
+        stack, buf = carry
+        q, r = factor(jax.lax.dynamic_slice_in_dim(a, i * block, block, 0))
+        if keep_q:
+            buf = jax.lax.dynamic_update_slice_in_dim(buf, q, i * block, 0)
+        return jax.lax.dynamic_update_slice_in_dim(stack, r, i * n, 0), buf
+
+    stack, buf = jax.lax.fori_loop(0, full, step, (stack, buf))
+    if tail:
+        q, r = factor(a[full * block :])
+        stack = stack.at[full * n :].set(r)
+        if keep_q:
+            buf = buf.at[full * block :, :k].set(q)
+    qs, r = jnp.linalg.qr(stack) if keep_q else (None, jnp.linalg.qr(stack, mode="r"))
+    sign = jnp.where(jnp.diagonal(r) < 0, -1.0, 1.0).astype(r.dtype)
+    return sign[:, None] * r, (None if qs is None else qs * sign), buf
+
+
+def _tsqr_apply(buf, qs, right, precision):
+    """Q (``right`` None) or ``Q·right`` in place of the blocks' ``Q_b`` in
+    ``buf``: ``Q_b·(Q_s,b·right)`` a block, ONE read and one write of the
+    buffer."""
+    m, n = buf.shape
+    block, full, tail = _tsqr_blocks(m, n)
+    w = qs if right is None else jnp.matmul(qs, right, precision="highest")
+
+    def step(i, buf):
+        rows = jax.lax.dynamic_slice_in_dim(buf, i * block, block, 0)
+        wi = jax.lax.dynamic_slice_in_dim(w, i * n, n, 0)
+        return jax.lax.dynamic_update_slice_in_dim(buf, _tall_dot(rows, wi, precision), i * block, 0)
+
+    buf = jax.lax.fori_loop(0, full, step, buf)
+    if tail:
+        buf = buf.at[full * block :].set(_tall_dot(buf[full * block :, : min(tail, n)], w[full * n :], precision))
+    return buf
+
+
+def _cholqr2_qr(x, calc_q: bool):
+    """``(Q or None, R)`` of the program on the ``cholqr2`` route: Q = A·R⁻¹
+    where :func:`_sound`, else the blocked TSQR."""
+
+    def direct():
+        return (_tall_dot(x, rinv, TALL_PRECISION) if calc_q else None), r
+
+    def fallback():
+        r2, qs, buf = _blocked_tsqr(x, calc_q)
+        return (_tsqr_apply(buf, qs, None, TALL_PRECISION) if calc_q else None), r2
+
+    sound, r, rinv, _ = _sound(*_cholqr2(x, TALL_PRECISION), False)
+    return jax.lax.cond(sound, direct, fallback)
+
+
+def _cholqr2_svd(x, compute_uv: bool):
+    """``(U, S, V)`` (or S) of the program on the ``cholqr2`` route: R's SVD
+    ``R = U_R·S·Vᵀ`` on the device and ``U = A·W``, ``W = R⁻¹·U_R``, in ONE
+    pass over A, where :func:`_sound`; else the blocked TSQR and
+    ``U = Q·U_R`` in its buffer."""
+    sound, r, rinv, (ur, s, v) = _sound(*_cholqr2(x, TALL_PRECISION), compute_uv)
+    if not compute_uv:
+        return jax.lax.cond(sound, lambda: s, lambda: jnp.linalg.svd(_blocked_tsqr(x, False)[0], compute_uv=False))
+
+    def direct():
+        return _tall_dot(x, jnp.matmul(rinv, ur, precision="highest"), TALL_PRECISION), s, v
+
+    def fallback():
+        r2, qs, buf = _blocked_tsqr(x, True)
+        ur2, s2, v2 = _r_svd(r2)
+        return _tsqr_apply(buf, qs, ur2, TALL_PRECISION), s2, v2
+
+    return jax.lax.cond(sound, direct, fallback)
+
+
+#: reads of A each route's program makes, by what it returns (``q``: Q or U
+#: is formed).  The Householder form copies A once into its working buffer
+#: and sweeps that copy, never A again.  ``cholqr2`` counts its sound branch:
+#: the blocked TSQR an operand that is not :func:`_sound` takes reads A once
+#: more, and its (m, n) buffer once where Q or U is formed
+A_PASSES = {
+    ("cholqr2", False): 2,
+    ("cholqr2", True): 3,
+    ("householder", False): 1,
+    ("householder", True): 1,
+}
+
+
+def _precision_name(route: str) -> str:
+    """The precision of a route's tall products, as its launch span states it."""
+    if route == "cholqr2":
+        return TALL_PRECISION
+    from .basics import get_matmul_precision
+
+    return get_matmul_precision()
+
+
+def route_fields(route: str, formed: bool) -> dict:
+    """The launch span's fields of a one-device QR or SVD program: ``route``,
+    ``a_passes`` and ``precision``; on ``cholqr2`` also ``fallback``, the
+    branch an operand that is not :func:`_sound` takes."""
+    fields = {"route": route, "a_passes": A_PASSES[route, formed], "precision": _precision_name(route)}
+    if route == "cholqr2":
+        fields["fallback"] = "blocked_tsqr"
+    return fields
+
+
+def _one_device_qr(arr, comm, calc_q: bool):
+    """``(Q or None, R)`` of a tall operand held whole on each device, one
+    cached program launched as ``jitted:linalg.qr`` (:func:`route_fields`).
+    On ``cholqr2`` Q is written once, as ``A·R⁻¹``; ``calc_q=False`` makes
+    the two Gram passes alone.  That program holds an SVD of R, so it is
+    lowered with x64 off, as ``svd``'s is."""
+    m, n = map(int, arr.shape)
+    route = tall_route((m, n), arr.dtype)
+    calc_q = bool(calc_q)
+
+    def make():
+        if route == "cholqr2":
+            return lambda x: _cholqr2_qr(x, calc_q)
+        if calc_q:
+            return lambda x: tuple(jnp.linalg.qr(x))
+        return lambda x: (None, jnp.linalg.qr(x, mode="r"))
+
+    fields = route_fields(route, calc_q)
+    key = ("linalg.qr", comm, (m, n), str(arr.dtype), route, calc_q, fields["precision"])
+    program = jitted(key, make, fields=fields)
+    if route == "householder":
+        return program(arr)
+    with jax.enable_x64(False):
+        return program(arr)
+
+
 def _tsqr(a: DNDarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Two-stage TSQR on the mesh (replaces reference qr.py:303-816).
 
@@ -120,8 +423,6 @@ def _tsqr(a: DNDarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     size = comm.size
     arr = a.larray
 
-    if size == 1:
-        return jnp.linalg.qr(arr)
     if comm.shard_width(m) < n:
         # shards wider than tall: local QR would not reduce and the R
         # stack would match the full matrix — gather and factor once
@@ -723,7 +1024,9 @@ def qr(
         arr, a.shape, dtype, a.split, a.device, a.comm, True
     )
 
-    if a.split == 0 and a.shape[0] >= a.shape[1]:
+    if whole_on_each_device(a) and a.shape[0] >= a.shape[1]:
+        q_g, r_g = _one_device_qr(arr, comm, calc_q)
+    elif a.split == 0 and a.shape[0] >= a.shape[1]:
         q_g, r_g = _tsqr(aa)
     elif a.split == 1 and a.shape[0] >= a.shape[1] and a.comm.size > 1:
         q_g, r_g = _cgs2_split1(aa, int(tiles_per_proc))

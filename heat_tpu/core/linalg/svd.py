@@ -14,6 +14,20 @@ exception: the TPU has no f64 hardware, so their R factors on the host
 through LAPACK (one tiny transfer) and the chain runs eagerly.  Wide
 matrices factor transposed and swap U/V.
 
+An operand held whole on a device (one device, or replicated) that
+``qr.tall_route`` sends on the ``cholqr2`` route takes one cached program,
+:func:`_one_device_svd` (``jitted:linalg.svd``): R comes from two blocked
+Gram passes, its SVD ``R = U_R·S·Vᵀ`` is taken on the device, and U is
+``A·W`` with ``W = R⁻¹·U_R`` (n x n), in ONE more pass over A: **Q is never
+built**.  Q and U are each as large as A; at 6 291 456 x 300 float32 A, Q,
+U and the Householder QR's working copy of A need about 30 GB, A and U
+alone 15.3 GB, which one 16 GB chip holds.  Forming U from A also reads A
+once where ``Q·U_R`` would read Q once, after Q was written.  U made so is
+orthonormal to about u·κ(A); the program takes it only where R is finite
+and κ(R) is at most ``qr.KAPPA_MAX``, and else factors by the blocked TSQR,
+its blocks' Q written into U's own buffer (``qr._cholqr2_svd``).  Every
+other operand takes the fused chain below.
+
 The on-device chain is traced, lowered and compiled with x64 **off**
 (:func:`svd` enters ``jax.enable_x64(False)`` around the fused program):
 lowered under the package's x64-on default, ``jnp.linalg.svd`` with
@@ -32,6 +46,7 @@ the compiler kill the process.
 from __future__ import annotations
 
 import collections
+import sys
 
 import numpy as np
 import jax as _jax
@@ -55,6 +70,11 @@ from ..dndarray import DNDarray
 from ..fuse import fuse
 from ..sanitation import sanitize_in
 from .qr import qr as _qr
+
+#: the module itself (the package's ``qr`` attribute is the function): the
+#: one-device route's helpers are looked up through it when a program is
+#: traced
+_qr_mod = sys.modules[_qr.__module__]
 
 __all__ = ["svd"]
 
@@ -154,6 +174,35 @@ def _svd_pipeline(a: DNDarray, osplit, dtype, compute_uv: bool):
 
 
 _fused_svd_pipeline = fuse(_svd_pipeline)
+
+
+def _one_device_svd(a: DNDarray, dtype, compute_uv: bool):
+    """The SVD of a tall operand held whole on each device on the ``cholqr2``
+    route (``qr.tall_route``): one cached program launched as
+    ``jitted:linalg.svd`` (``qr._cholqr2_svd``), with the fields of
+    ``qr.route_fields`` and, where U is formed, ``u: direct``.
+
+    R from two blocked Gram passes, its SVD ``R = U_R·S·Vᵀ`` on the device,
+    and ``U = A·W`` with ``W = R⁻¹·U_R`` (n x n) in ONE more pass: Q is never
+    built, so A and U are the only arrays of A's size.  An operand whose
+    factor is not sound takes the blocked TSQR inside the same program."""
+    comm, device = a.comm, a.device
+    m, n = a.shape
+    arr = a.larray
+    compute_uv = bool(compute_uv)
+    fields = _qr_mod.route_fields("cholqr2", compute_uv)
+    if compute_uv:
+        fields["u"] = "direct"
+    key = ("linalg.svd", comm, (m, n), str(arr.dtype), "cholqr2", compute_uv, fields["precision"])
+    out = _jitted(key, lambda: lambda x: _qr_mod._cholqr2_svd(x, compute_uv), fields=fields)(arr)
+    if not compute_uv:
+        return DNDarray(out, (n,), dtype, None, device, comm, True)
+    u, s, v = out
+    u_split = a.split if a.split == 0 else None
+    U = DNDarray(u, (m, n), dtype, u_split, device, comm, True)
+    S = DNDarray(s, (n,), dtype, None, device, comm, True)
+    V = DNDarray(v, (n, n), dtype, None, device, comm, True)
+    return SVD(U, S, V)
 
 
 # ---------------------------------------------------------------------------
@@ -639,4 +688,6 @@ def svd(a: DNDarray, full_matrices: bool = False, compute_uv: bool = True):
     if a.dtype is not dtype:
         a = a.astype(dtype)  # an integer operand must not meet the context below
     with _jax.enable_x64(False):  # see module docstring: the LOWERING must be x64-off
+        if _qr_mod.whole_on_each_device(a) and _qr_mod.tall_route(a.shape, a.larray.dtype) == "cholqr2":
+            return _one_device_svd(a, dtype, compute_uv)
         return _fused_svd_pipeline(a, a.split, dtype, compute_uv)

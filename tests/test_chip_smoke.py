@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import functools
 import gc
+import importlib
 import json
 import os
 import subprocess
@@ -97,6 +98,27 @@ def test_phase_against_its_reference(phase, sizes):
     line, _ = phase(SEED, **sizes)
     _complete(line)
     assert _failed(line) == []
+
+
+def test_qr_svd_phase_says_which_route_its_programs_took(monkeypatch):
+    """On one device the programs of ``ht.linalg.qr`` and ``ht.linalg.svd``
+    state their route and reads of A; on the CPU mesh the operand is split
+    over every device and factors by TSQR inside one fused program, which
+    states none."""
+    qr_mod = importlib.import_module("heat_tpu.core.linalg.qr")
+    line, _ = chip_smoke.phase_qr_svd(SEED, m=4096, n=64)
+    assert line["routes"] == {}
+    # the phase's operand on one device, as on a one-chip machine
+    monkeypatch.setattr(ht.random, "randn", functools.partial(ht.random.randn, comm=ht.XlaCommunication(jax.devices()[:1])))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(qr_mod, "MIN_BYTES", 0)
+    line, _ = chip_smoke.phase_qr_svd(SEED, m=4096, n=64)
+    _complete(line)
+    assert _failed(line) == []
+    assert line["routes"] == {
+        "linalg.qr": {"route": "cholqr2", "a_passes": 3},
+        "linalg.svd": {"route": "cholqr2", "a_passes": 3},
+    }
 
 
 def test_spectral_phase_says_which_matvec_route_its_fit_took():

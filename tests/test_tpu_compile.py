@@ -172,6 +172,91 @@ def test_svd_chain_compiles_when_lowered_with_x64_off(one_chip, topo):
 
 
 # --------------------------------------------------------------------- #
+# svd_300_c1: the one-chip SVD and QR of 6 291 456 x 300                 #
+# --------------------------------------------------------------------- #
+TALL_M, TALL_N = 6_291_456, 300  # one flattened Cityscapes image a column
+
+
+def _reads_of_a(compiled, shape: str) -> set:
+    """Passes over the operand, one total for each way through the program's
+    conditionals: the fusions, convolutions and custom calls of the entry
+    computation, of every loop's body and of the branch taken that take an
+    operand of ``shape`` (a body reads its block of rows a step, so its loop
+    reads all of A once)."""
+    text = compiled.as_text()
+    comps = {head: block for head, block in re.findall(r"^(?:ENTRY )?%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S)}
+    entry = re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)
+
+    def totals(name):
+        block = comps[name]
+        holders = set(re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = " + re.escape(shape), block, re.M))
+        own = sum(
+            1
+            for operands in re.findall(r"^[^\n]* = [^\n]*? (?:fusion|convolution|custom-call)\(([^)]*)\)", block, re.M)
+            if holders & {o.strip().lstrip("%") for o in operands.split(",")}
+        )
+        out = {own}
+        for body in re.findall(r"\bwhile\([^\n]*body=%?([\w.\-]+)", block):
+            out = {t + b for t in out for b in totals(body)}
+        for line in re.findall(r"^[^\n]* conditional\([^\n]*$", block, re.M):
+            names = re.findall(r"(?:true_computation|false_computation)=%?([\w.\-]+)", line)
+            listed = re.search(r"branch_computations=\{([^}]*)\}", line)
+            names += [n.strip().lstrip("%") for n in listed.group(1).split(",")] if listed else []
+            out = {t + b for t in out for name in names for b in totals(name)}
+        return out
+
+    return totals(entry)
+
+
+@pytest.mark.parametrize(
+    "call,site,passes",
+    [("svd", "linalg.svd", 3), ("qr", "linalg.qr", 3), ("qr_r_only", "linalg.qr", 2)],
+)
+def test_the_tall_svd_and_qr_compile_at_the_cells_size_and_read_a_as_their_field_says(
+    one_chip, on_the_chip, call, site, passes
+):
+    """``ht.linalg.svd`` (and ``ht.linalg.qr``) of 6 291 456 x 300 float32 on
+    one chip, the program the cell times: A and its output and nothing of
+    their size beside them (no Q beside U, no working copy of A), within the
+    chip, and as many passes over A as the launch span's ``a_passes`` says:
+    two Gram passes, and U's (or Q's).  The other way through, the blocked
+    TSQR an operand whose factor is not sound takes, reads more (its block
+    QRs, then the output's own buffer, which has A's shape)."""
+    from heat_tpu import telemetry
+    from heat_tpu.core import _compile
+
+    qr_mod = importlib.import_module("heat_tpu.core.linalg.qr")
+    was = telemetry.is_enabled()
+    telemetry.enable()
+    try:
+        with pytest.MonkeyPatch.context() as small:
+            small.setattr(qr_mod, "MIN_BYTES", 0)
+            x = ht.array(jnp.ones((512, 8), jnp.float32) + jnp.eye(512, 8, dtype=jnp.float32), split=0,
+                         comm=ht.XlaCommunication(jax.devices()[:1]))
+            {"svd": lambda: ht.linalg.svd(x), "qr": lambda: ht.linalg.qr(x),
+             "qr_r_only": lambda: ht.linalg.qr(x, calc_q=False)}[call]()
+        span = [e for e in telemetry.events() if e.get("site") == f"jitted:{site}"][-1]
+    finally:
+        if not was:
+            telemetry.disable()
+    assert span["route"] == "cholqr2" and span["precision"] == "highest"
+    # the keys: (site, comm, shape, dtype, route, compute_uv / calc_q, precision)
+    entry = next(fn for k, fn in _compile._CACHE.items() if k[0] == site and k[2] == (512, 8) and k[5] == (call != "qr_r_only"))
+    with jax.enable_x64(False):
+        compiled = entry.lower(_shape((TALL_M, TALL_N), one_chip)).compile()
+    _fits_the_chip(compiled, site)
+    m = compiled.memory_analysis()
+    a_bytes = 4 * TALL_M * TALL_N
+    assert a_bytes <= m.argument_size_in_bytes < 1.02 * a_bytes
+    # blocks of 65 536 rows (the fallback's: a block's Householder QR and its Q), never (m, n)
+    assert m.temp_size_in_bytes < 1 << 29, m
+    assert (m.output_size_in_bytes >= a_bytes) == (call != "qr_r_only")
+    assert span["a_passes"] == passes and span["fallback"] == "blocked_tsqr"
+    sound, fallback = sorted(_reads_of_a(compiled, f"f32[{TALL_M},{TALL_N}]"))
+    assert sound == passes
+
+
+# --------------------------------------------------------------------- #
 # the benchmark cells' programs, and the names their phases carry        #
 # --------------------------------------------------------------------- #
 CELL_F, CELL_K = 6_291_456, 8  # one flattened Cityscapes image a row, 8 clusters
