@@ -552,7 +552,8 @@ def phase_qr_svd(seed: int, m: int, n: int):
     }
     line = {
         "sizes": {"rows": m, "cols": n, "bytes": m * n * 4},
-        "routes": {e["site"].split(":", 1)[1]: {"route": e["route"], "a_passes": e["a_passes"]} for e in spans},
+        "routes": {e["site"].split(":", 1)[1]: {"route": e["route"], "a_passes": e["a_passes"],
+                                                 "col_blocks": e.get("col_blocks")} for e in spans},
         "reference": "residuals on the device; singular values against numpy "
                      "float64 on the R factor",
         "checks": checks, "device_dtypes": device_dtypes(),

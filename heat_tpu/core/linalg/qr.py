@@ -28,9 +28,12 @@ TPU-first design (per SURVEY.md §7 build plan, item 8):
   **blocked CholeskyQR2** — two Gram passes over blocks of rows (the second
   over the implicit ``Q1 = A·R1⁻¹``, formed a block at a time and never
   stored), two (n, n) Cholesky factorizations, ``R = R2·R1``; Q, where asked
-  for, is ``A·R⁻¹`` written once in a third pass.  Nothing of A's size is
-  made beside A and Q, which is what lets a chip factor an operand that
-  fills half of it.  Q made so is orthonormal to about u·κ(A), so the
+  for, is ``A·R⁻¹`` written once in a third pass.  The Grams are made on
+  and below their diagonal tiles of :data:`MXU_COLS` columns and mirrored,
+  and ``A·R⁻¹`` without the zero tiles under R⁻¹'s first diagonal block, so
+  the MXU skips tiles the symmetry mirrors or the triangle makes zero.
+  Nothing of A's size is made beside A and Q, which is what lets a chip
+  factor an operand that fills half of it.  Q made so is orthonormal to about u·κ(A), so the
   program checks its factor: where R is not finite (the Gram's Cholesky
   broke down: κ(A)² near 1/u, a rank-deficient A) or κ(R) passes
   :data:`KAPPA_MAX`, the same program factors by the blocked TSQR instead
@@ -39,7 +42,7 @@ TPU-first design (per SURVEY.md §7 build plan, item 8):
   XLA's Householder QR of the whole operand (``householder``), which needs
   a working copy of A.  The launch span states ``route``, ``a_passes``
   (reads of A), ``precision`` (of the tall products) and, on ``cholqr2``,
-  ``fallback``.
+  ``fallback`` and ``col_blocks``.
 * wide (m < n) inputs use on-device ``jnp.linalg.qr``.
 
 The one remaining distributed fallback — split=0 with more than
@@ -138,6 +141,10 @@ BLOCK_ROWS = 1 << 16
 #: operand (κ about 70) three passes leave U orthonormal only to 1.3e-4 and one
 #: pass to 2.5e-2, where six read 5e-6 (PERF.md, section 2)
 TALL_PRECISION = "highest"
+#: columns of a tile of the MXU: the tall products of the CholeskyQR2 route are
+#: planned on tiles of this many columns (:func:`_tiles`) and skip tiles
+#: that the Grams' symmetry mirrors or R⁻¹'s triangle makes zero
+MXU_COLS = 128
 #: the largest κ(R) = S[0] / S[-1] for which the CholeskyQR2 route forms Q (or
 #: U) from A in one product.  ``A·R⁻¹`` is orthonormal only to about u·κ(A)
 #: (u = 2⁻²⁴), so this keeps the loss under 6e-5, the order of what the blocked
@@ -184,17 +191,78 @@ def _tall_dot(a, b, precision):
     return jnp.matmul(a, b, precision=precision)
 
 
+def _tiles(n):
+    """How many tiles of :data:`MXU_COLS` columns (the last ragged) the
+    structured tall products plan n columns on; one, the dense products,
+    where ``n <= MXU_COLS``."""
+    return -(-n // MXU_COLS)
+
+
+def _lower_gram(q, precision):
+    """``qᵀq`` on and below its diagonal tiles, in two products: the columns
+    before the last tile against all of q, the last tile against itself.  The
+    MXU skips the tiles above the last tile's diagonal block, which the
+    symmetry mirrors.  A product whose kernel is one tile wide runs the MXU at
+    about half its rate (XLA's cost model of the v5e), so the columns before
+    the last tile stay one product.  One tile: the dense ``qᵀq``."""
+    tiles = _tiles(q.shape[1])
+    if tiles == 1:
+        return _tall_dot(q.T, q, precision)
+    s = MXU_COLS * (tiles - 1)
+    last = _tall_dot(q[:, s:].T, q[:, s:], precision)
+    return jnp.concatenate([_tall_dot(q.T, q[:, :s], precision), jnp.pad(last, ((s, 0), (0, 0)))], axis=1)
+
+
+def _upper_parts(rows, rinv, precision):
+    """``rows·triu(rinv)`` as ``[(first column, product)]``, the products side
+    by side: the first tile's columns from the first tile of ``rows`` alone,
+    the other columns from all of it, so the MXU skips the zero tiles under
+    the first tile's diagonal block.  One tile: the dense product."""
+    rinv = jnp.triu(rinv)
+    if _tiles(rinv.shape[1]) == 1:
+        return [(0, _tall_dot(rows, rinv, precision))]
+    w = MXU_COLS
+    return [(0, _tall_dot(rows[:, :w], rinv[:w, :w], precision)), (w, _tall_dot(rows, rinv[:, w:], precision))]
+
+
+def _upper_product(a, rinv, precision):
+    """``A·triu(rinv)`` of the whole operand by :func:`_upper_parts`, a block
+    of :data:`BLOCK_ROWS` rows at a time, each product written into the
+    output's own buffer: products of whole columns would each make a
+    temporary of A's height.  One tile: the dense product of A."""
+    m, n = a.shape
+    if _tiles(n) == 1:
+        return _tall_dot(a, jnp.triu(rinv), precision)
+    block = min(BLOCK_ROWS, m)
+    full, tail = divmod(m, block)
+
+    def put(out, start, rows):
+        for col, part in _upper_parts(rows, rinv, precision):
+            out = jax.lax.dynamic_update_slice(out, part, (start, col))
+        return out
+
+    def step(i, out):
+        return put(out, i * block, jax.lax.dynamic_slice_in_dim(a, i * block, block, 0))
+
+    out = jax.lax.fori_loop(0, full, step, jnp.zeros((m, n), a.dtype))
+    return put(out, full * block, a[full * block :]) if tail else out
+
+
 def _gram(a, rinv, precision):
     """``Σ_b (A_b·rinv)ᵀ(A_b·rinv)`` over blocks of :data:`BLOCK_ROWS` rows,
     ``A_bᵀA_b`` where ``rinv`` is None: ONE read of A, nothing of its size
-    made (the block's ``A_b·rinv`` is the implicit Q1's rows, never stored)."""
+    made (the block's ``A_b·rinv`` is the implicit Q1's rows, never stored).
+    ``rinv`` is upper triangular; both products skip MXU tiles that the
+    triangles make zero or mirrored (:func:`_upper_parts`, :func:`_lower_gram`),
+    and the lower triangle is mirrored once at the end: the Gram is exactly
+    symmetric."""
     m, n = a.shape
     block = min(BLOCK_ROWS, m)
     full, tail = divmod(m, block)
 
     def part(rows):
-        q = rows if rinv is None else _tall_dot(rows, rinv, precision)
-        return _tall_dot(q.T, q, precision)
+        q = rows if rinv is None else jnp.concatenate([p for _, p in _upper_parts(rows, rinv, precision)], axis=1)
+        return _lower_gram(q, precision)
 
     def step(i, g):
         return g + part(jax.lax.dynamic_slice_in_dim(a, i * block, block, 0))
@@ -202,12 +270,12 @@ def _gram(a, rinv, precision):
     g = jax.lax.fori_loop(0, full, step, jnp.zeros((n, n), a.dtype))
     if tail:
         g = g + part(a[full * block :])
-    return g
+    return jnp.tril(g) + jnp.tril(g, -1).T
 
 
 def _cholesky_r(g):
     """The upper factor R of a Gram ``g = RᵀR``."""
-    return jnp.linalg.cholesky(0.5 * (g + g.T)).T
+    return jnp.linalg.cholesky(g).T
 
 
 def _upper_inverse(r):
@@ -322,7 +390,7 @@ def _cholqr2_qr(x, calc_q: bool):
     where :func:`_sound`, else the blocked TSQR."""
 
     def direct():
-        return (_tall_dot(x, rinv, TALL_PRECISION) if calc_q else None), r
+        return (_upper_product(x, rinv, TALL_PRECISION) if calc_q else None), r
 
     def fallback():
         r2, qs, buf = _blocked_tsqr(x, calc_q)
@@ -374,13 +442,16 @@ def _precision_name(route: str) -> str:
     return get_matmul_precision()
 
 
-def route_fields(route: str, formed: bool) -> dict:
-    """The launch span's fields of a one-device QR or SVD program: ``route``,
-    ``a_passes`` and ``precision``; on ``cholqr2`` also ``fallback``, the
-    branch an operand that is not :func:`_sound` takes."""
+def route_fields(route: str, formed: bool, n: int) -> dict:
+    """The launch span's fields of a one-device QR or SVD program of n columns:
+    ``route``, ``a_passes`` and ``precision``; on ``cholqr2`` also
+    ``fallback``, the branch an operand that is not :func:`_sound` takes, and
+    ``col_blocks``, the tiles of :data:`MXU_COLS` columns its structured tall
+    products are planned on (1: the dense products)."""
     fields = {"route": route, "a_passes": A_PASSES[route, formed], "precision": _precision_name(route)}
     if route == "cholqr2":
         fields["fallback"] = "blocked_tsqr"
+        fields["col_blocks"] = _tiles(n)
     return fields
 
 
@@ -401,7 +472,7 @@ def _one_device_qr(arr, comm, calc_q: bool):
             return lambda x: tuple(jnp.linalg.qr(x))
         return lambda x: (None, jnp.linalg.qr(x, mode="r"))
 
-    fields = route_fields(route, calc_q)
+    fields = route_fields(route, calc_q, n)
     key = ("linalg.qr", comm, (m, n), str(arr.dtype), route, calc_q, fields["precision"])
     program = jitted(key, make, fields=fields)
     if route == "householder":

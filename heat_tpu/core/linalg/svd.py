@@ -190,7 +190,7 @@ def _one_device_svd(a: DNDarray, dtype, compute_uv: bool):
     m, n = a.shape
     arr = a.larray
     compute_uv = bool(compute_uv)
-    fields = _qr_mod.route_fields("cholqr2", compute_uv)
+    fields = _qr_mod.route_fields("cholqr2", compute_uv, n)
     if compute_uv:
         fields["u"] = "direct"
     key = ("linalg.svd", comm, (m, n), str(arr.dtype), "cholqr2", compute_uv, fields["precision"])

@@ -102,7 +102,8 @@ def test_phase_against_its_reference(phase, sizes):
 
 def test_qr_svd_phase_says_which_route_its_programs_took(monkeypatch):
     """On one device the programs of ``ht.linalg.qr`` and ``ht.linalg.svd``
-    state their route and reads of A; on the CPU mesh the operand is split
+    state their route, reads of A and column blocks (64 columns: one, the
+    dense products); on the CPU mesh the operand is split
     over every device and factors by TSQR inside one fused program, which
     states none."""
     qr_mod = importlib.import_module("heat_tpu.core.linalg.qr")
@@ -116,8 +117,8 @@ def test_qr_svd_phase_says_which_route_its_programs_took(monkeypatch):
     _complete(line)
     assert _failed(line) == []
     assert line["routes"] == {
-        "linalg.qr": {"route": "cholqr2", "a_passes": 3},
-        "linalg.svd": {"route": "cholqr2", "a_passes": 3},
+        "linalg.qr": {"route": "cholqr2", "a_passes": 3, "col_blocks": 1},
+        "linalg.svd": {"route": "cholqr2", "a_passes": 3, "col_blocks": 1},
     }
 
 

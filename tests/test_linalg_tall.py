@@ -108,6 +108,61 @@ def test_one_chip_qr_against_float64(monkeypatch, m, n, block, kappa, calc_q):
     assert np.linalg.norm(q @ r - a) / np.linalg.norm(a) < 1e-5
 
 
+def _dots(f, *args):
+    """``(lhs shape, rhs shape, out shape)`` of every ``dot_general`` ``f``
+    traces to, nested jaxprs included."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                found.append(tuple(tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(f)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("n", [64, 128, 129, 200, 256, 300, 384])
+def test_the_structured_products_skip_the_zero_and_mirrored_tiles(monkeypatch, n):
+    """The Grams on and below their diagonal tiles, ``A·R⁻¹`` (of a block of
+    rows, and Q of the whole operand) without the zero tiles under R⁻¹'s
+    first diagonal block, planned on ``ceil(n / 128)`` tiles of columns, two
+    products each: each equals its dense product to float32 rounding, the
+    Gram exactly symmetric, and CholeskyQR2's R and R⁻¹ those of the dense
+    products; up to 128 columns the dense products themselves."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(qr_mod, "BLOCK_ROWS", 256)  # several blocks of rows and a tail
+    a, _ = _operand(n + 700, n, 10.0)
+    a64 = a.astype(np.float64)
+    blocks = -(-n // 128)
+    assert qr_mod.route_fields("cholqr2", True, n)["col_blocks"] == blocks
+    with jax.enable_x64(False):
+        x = jnp.asarray(a)
+        g = np.asarray(qr_mod._gram(x, None, "highest"), np.float64)
+        r, rinv = (np.asarray(t, np.float64) for t in qr_mod._cholqr2(x, "highest"))
+        rinv32 = jnp.asarray(rinv, jnp.float32)
+        q = np.asarray(jnp.concatenate([p for _, p in qr_mod._upper_parts(x, rinv32, "highest")], axis=1), np.float64)
+        q_whole = np.asarray(qr_mod._upper_product(x, rinv32, "highest"), np.float64)
+        gram_dots = _dots(lambda t: qr_mod._lower_gram(t, "highest"), x)
+        q_dots = _dots(lambda t, u: qr_mod._upper_parts(t, u, "highest"), x, rinv32)
+        monkeypatch.setattr(qr_mod, "MXU_COLS", 1 << 30)  # one tile: the dense products
+        r_dense, rinv_dense = (np.asarray(t, np.float64) for t in qr_mod._cholqr2(x, "highest"))
+    assert np.array_equal(g, g.T)
+    assert np.max(np.abs(g - a64.T @ a64)) < 1e-5 * np.max(np.abs(g))
+    for product in (q, q_whole):  # a block of rows; the whole operand, a block of rows at a time
+        assert np.max(np.abs(product - a64 @ np.triu(rinv))) < 1e-5 * np.max(np.abs(product))
+    assert np.max(np.abs(r - r_dense)) < 1e-5 * np.max(np.abs(r))
+    assert np.max(np.abs(rinv - rinv_dense)) < 1e-5 * np.max(np.abs(rinv))
+    assert len(gram_dots) == len(q_dots) == min(blocks, 2)
+    if n <= 128:
+        assert gram_dots == [(a.shape[::-1], a.shape, (n, n))] and q_dots == [(a.shape, (n, n), a.shape)]
+    else:  # no product has an n x n operand or result
+        assert (n, n) not in {shape for dot in gram_dots + q_dots for shape in dot}
+
+
 def _not_for_cholqr(kind, m=2000, n=48):
     """Operands CholeskyQR2 cannot factor soundly: a Gram whose Cholesky breaks
     down, or a factor past ``KAPPA_MAX``."""
@@ -238,10 +293,11 @@ def test_the_launch_span_states_route_passes_precision_and_u(monkeypatch, call, 
     (span,) = spans
     assert span["site"] == site and span["kind"] == "launch"
     if steered:
-        want = {"route": "cholqr2", "a_passes": passes, "precision": qr_mod.TALL_PRECISION, "fallback": "blocked_tsqr"}
+        want = {"route": "cholqr2", "a_passes": passes, "precision": qr_mod.TALL_PRECISION, "fallback": "blocked_tsqr",
+                "col_blocks": 1}
     else:  # the whole operand copied once into XLA's QR; the products at the linalg default
         want = {"route": "householder", "a_passes": 1, "precision": ht.linalg.get_matmul_precision()}
-    assert {k: span[k] for k in want} == want and ("fallback" in span) == steered
+    assert {k: span[k] for k in want} == want and ("fallback" in span) == ("col_blocks" in span) == steered
     assert span.get("u") == u and (u is None) == ("u" not in span)
 
 

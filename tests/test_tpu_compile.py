@@ -177,17 +177,24 @@ def test_svd_chain_compiles_when_lowered_with_x64_off(one_chip, topo):
 TALL_M, TALL_N = 6_291_456, 300  # one flattened Cityscapes image a column
 
 
+def _computations(text: str) -> dict:
+    """The compiled module's computations by name, each its block of lines."""
+    return {head: block for head, block in re.findall(r"^(?:ENTRY )?%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S)}
+
+
 def _reads_of_a(compiled, shape: str) -> set:
     """Passes over the operand, one total for each way through the program's
     conditionals: the fusions, convolutions and custom calls of the entry
     computation, of every loop's body and of the branch taken that take an
-    operand of ``shape`` (a body reads its block of rows a step, so its loop
-    reads all of A once)."""
+    operand of ``shape``.  A body reads its block of rows a step, so its loop
+    reads all of A once, however many of its fusions take A: the structured
+    products of a Gram pass (``qr._lower_gram``, ``qr._upper_parts``) are
+    several fusions that each slice the step's same block of rows."""
     text = compiled.as_text()
-    comps = {head: block for head, block in re.findall(r"^(?:ENTRY )?%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S)}
+    comps = _computations(text)
     entry = re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)
 
-    def totals(name):
+    def totals(name, body=False):
         block = comps[name]
         holders = set(re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = " + re.escape(shape), block, re.M))
         own = sum(
@@ -195,9 +202,9 @@ def _reads_of_a(compiled, shape: str) -> set:
             for operands in re.findall(r"^[^\n]* = [^\n]*? (?:fusion|convolution|custom-call)\(([^)]*)\)", block, re.M)
             if holders & {o.strip().lstrip("%") for o in operands.split(",")}
         )
-        out = {own}
-        for body in re.findall(r"\bwhile\([^\n]*body=%?([\w.\-]+)", block):
-            out = {t + b for t in out for b in totals(body)}
+        out = {min(own, 1) if body else own}
+        for loop in re.findall(r"\bwhile\([^\n]*body=%?([\w.\-]+)", block):
+            out = {t + b for t in out for b in totals(loop, body=True)}
         for line in re.findall(r"^[^\n]* conditional\([^\n]*$", block, re.M):
             names = re.findall(r"(?:true_computation|false_computation)=%?([\w.\-]+)", line)
             listed = re.search(r"branch_computations=\{([^}]*)\}", line)
@@ -206,6 +213,33 @@ def _reads_of_a(compiled, shape: str) -> set:
         return out
 
     return totals(entry)
+
+
+def _full_width_products(compiled, shape: str, n: int) -> list:
+    """The convolutions (XLA's dots) inside the entry computation's loops that
+    carry an operand of ``shape`` (the Gram passes over A's blocks of rows,
+    not the conditional's branches) with an ``(n, n)`` operand or result: a
+    Gram's whole ``qᵀq`` or a whole ``A_b·R⁻¹``, each product's every MXU
+    tile."""
+    text = compiled.as_text()
+    comps = _computations(text)
+    entry = comps[re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)]
+    todo = [body for kind, body in re.findall(r"^[^\n]* = \(([^\n]*?)\) while\([^\n]*body=%?([\w.\-]+)", entry, re.M)
+            if shape in kind]
+    seen, found = set(), []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        block = comps[name]
+        todo += re.findall(r"calls=%?([\w.\-]+)", block)
+        shapes = dict(re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]", block, re.M))
+        for out, operands in re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\][^ ]* convolution\(([^)]*)\)", block, re.M):
+            dims = [out] + [shapes.get(o.strip().lstrip("%")) for o in operands.split(",")]
+            if f"{n},{n}" in dims:
+                found.append((name, dims))
+    return found
 
 
 @pytest.mark.parametrize(
@@ -254,6 +288,45 @@ def test_the_tall_svd_and_qr_compile_at_the_cells_size_and_read_a_as_their_field
     assert span["a_passes"] == passes and span["fallback"] == "blocked_tsqr"
     sound, fallback = sorted(_reads_of_a(compiled, f"f32[{TALL_M},{TALL_N}]"))
     assert sound == passes
+    # the Gram passes run no product whole: both Grams by their upper column blocks, Q1 by R1⁻¹'s nonzero blocks
+    assert span["col_blocks"] == 1  # the traced operand's 8 columns: the dense products
+    assert not _full_width_products(compiled, f"f32[{TALL_M},{TALL_N}]", TALL_N)
+
+
+@pytest.mark.parametrize("rinv", [False, True], ids=["first_gram", "second_gram"])
+def test_the_gram_passes_skip_the_zero_and_mirrored_tiles_at_the_cells_size(one_chip, monkeypatch, rinv):
+    """``qr._gram`` at 6 291 456 x 300 compiled for the v5e, structured (three
+    tiles of columns) and dense (one): only the dense loop body holds a whole
+    300 x 300 product; the structured body's FLOPs by XLA's cost analysis are
+    at most 0.9 of the dense body's (0.88 and 0.82 by the shapes), and its
+    cycles by XLA's cost model of the chip at most 0.88 (0.83 and 0.84; the
+    model's dense first Gram, 728k cycles a block of rows, is 50.9 ms a job
+    on the chip).  Three products a pass, one for each tile, read 0.69 of the
+    FLOPs but 0.94 and 1.06 of the cycles: a product whose kernel is one tile
+    wide, or 44 columns, runs the MXU at a fraction of its rate."""
+    qr_mod = importlib.import_module("heat_tpu.core.linalg.qr")
+    shapes = [_shape((TALL_M, TALL_N), one_chip)] + ([_shape((TALL_N, TALL_N), one_chip)] if rinv else [])
+    a_shape = f"f32[{TALL_M},{TALL_N}]"
+
+    def compiled():
+        with jax.enable_x64(False):
+            return jax.jit(lambda x, *r: qr_mod._gram(x, r[0] if r else None, "highest")).lower(*shapes).compile()
+
+    def flops(c):
+        cost = c.cost_analysis()
+        return (cost[0] if isinstance(cost, list) else cost)["flops"]
+
+    def cycles(c):
+        return sum(int(n) for n in re.findall(r'"estimated_cycles":"(\d+)"', c.as_text()))
+
+    assert qr_mod._tiles(TALL_N) == 3
+    structured = compiled()
+    monkeypatch.setattr(qr_mod, "MXU_COLS", TALL_N)  # one tile: the dense products
+    dense = compiled()
+    assert flops(structured) <= 0.9 * flops(dense), (flops(structured), flops(dense))
+    assert cycles(structured) <= 0.88 * cycles(dense), (cycles(structured), cycles(dense))
+    assert not _full_width_products(structured, a_shape, TALL_N)
+    assert _full_width_products(dense, a_shape, TALL_N)
 
 
 # --------------------------------------------------------------------- #
