@@ -550,6 +550,8 @@ def phase_qr_svd(seed: int, m: int, n: int):
         "svd_residual_rel": check(svd_res, 1e-4),
         "singular_values_rel": check(np.abs(s.numpy() / s_ref - 1).max(), 1e-4),
     }
+    if A.comm.size > 1:
+        checks["sharded_route"] = _sharded_route(A, spans)
     line = {
         "sizes": {"rows": m, "cols": n, "bytes": m * n * 4},
         "routes": {e["site"].split(":", 1)[1]: {"route": e["route"], "a_passes": e["a_passes"],
@@ -559,6 +561,20 @@ def phase_qr_svd(seed: int, m: int, n: int):
         "checks": checks, "device_dtypes": device_dtypes(),
     }
     return line, None
+
+
+def _sharded_route(A, spans) -> dict:
+    """Over several chips a row-split operand takes the row-sharded
+    CholeskyQR2 where ``qr.rows_route`` admits it (float32, every shard at
+    least n rows and 4 MB, a TPU: one ``cholqr2_rows`` program a call),
+    else the TSQR chain, whose fused programs state no route: 1 where the
+    spans say the route the operand should have taken."""
+    import importlib
+
+    qr_mod = importlib.import_module("heat_tpu.core.linalg.qr")
+    want = "cholqr2_rows" if qr_mod.rows_route(A.shape, A.larray.dtype, A.split, A.comm) else None
+    took = {e.get("route") for e in spans} or {None}
+    return check(took == {want}, 1, at_least=True)
 
 
 def _reference_attention(q, k, v, causal: bool):
@@ -915,7 +931,8 @@ def sharded_qr(XX, comm):
 
     X, X1 = XX
     n = X.shape[1]
-    q, r = ht.linalg.qr(X)
+    with launch_spans("jitted:linalg.qr") as spans:
+        q, r = ht.linalg.qr(X)
     r1 = ht.linalg.qr(X1).R.numpy()
     rn = r.numpy()
     # R is unique up to the sign of each row: compare with the diagonals' signs
@@ -929,9 +946,11 @@ def sharded_qr(XX, comm):
             float(ht.linalg.norm(ht.matmul(q.T, q) - ht.eye(n, comm=comm))),
             _orthogonality_limit(X.shape[0], n)),
         "r_vs_one_device_rel_to_max": check(np.abs(rn - r1).max() / np.abs(r1).max(), 1e-4),
+        "sharded_route": _sharded_route(X, spans),
     }
     line = {
         "sizes": {"rows": X.shape[0], "cols": n},
+        "routes": sorted({e["route"] for e in spans}),
         "reference": "R of the same call on a one-device communicator; residuals on the device",
         "checks": checks, "device_dtypes": device_dtypes(),
     }

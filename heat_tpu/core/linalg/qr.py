@@ -6,11 +6,23 @@ row merges, async Q-factor shipping, and a column-cyclic split=1 loop.
 
 TPU-first design (per SURVEY.md §7 build plan, item 8):
 
-* **split=0 (row-sharded), m ≥ n: TSQR** (communication-avoiding
+* **split=0 (row-sharded) over a 1-D mesh of several devices, m ≥ n:** an
+  operand :func:`rows_route` admits (float32, every shard at least n rows
+  and :data:`MIN_BYTES`, a process on a TPU) takes the **row-sharded
+  CholeskyQR2** (``cholqr2_rows``): the one-device program below run on
+  each shard inside ``shard_map``, each of its two Grams summed over the
+  chips by one all-reduce of its (n, n) (:func:`_summed`); the Choleskys,
+  R⁻¹, the soundness check and R's SVD run replicated, and Q, where asked
+  for, is ``A_local·R⁻¹`` made shard by shard, so nothing of A's size is
+  made beside A and Q on any chip.  Its fallback, in the same program, is
+  the blocked TSQR of each shard with the shards' R factors all-gathered
+  and factored once more (``rows_tsqr``, :func:`_across`).  Every other
+  row-sharded operand takes **TSQR** (communication-avoiding
   tall-skinny QR).  Each shard computes a local QR; the stacked R factors
   are QR'd again; one all-gather replaces the reference's point-to-point
   tile choreography.  Non-divisible row counts go through the canonical
-  zero-padding (``comm.pad_to_shards``): zero rows leave R untouched and —
+  zero-padding (``comm.pad_to_shards``), on both routes: zero rows leave
+  the Grams and R untouched and —
   because the stage-2 Q's rows matching zero R-stack rows vanish — drop
   out of Q exactly, so ragged TSQR is exact for full-column-rank inputs
   (the same caveat any QR has for deficient ones).
@@ -41,8 +53,9 @@ TPU-first design (per SURVEY.md §7 build plan, item 8):
   output's buffer), orthonormal to rounding.  Every other operand takes
   XLA's Householder QR of the whole operand (``householder``), which needs
   a working copy of A.  The launch span states ``route``, ``a_passes``
-  (reads of A), ``precision`` (of the tall products) and, on ``cholqr2``,
-  ``fallback`` and ``col_blocks``.
+  (reads of A), ``precision`` (of the tall products) and, on ``cholqr2``
+  and ``cholqr2_rows``, ``fallback`` and ``col_blocks``; on
+  ``cholqr2_rows`` also ``shards`` and ``collective_bytes``.
 * wide (m < n) inputs use on-device ``jnp.linalg.qr``.
 
 The one remaining distributed fallback — split=0 with more than
@@ -55,6 +68,8 @@ choice there).
 from __future__ import annotations
 
 import collections
+import contextlib
+import threading
 import warnings
 from typing import Optional, Tuple
 
@@ -152,6 +167,33 @@ MXU_COLS = 128
 #: (PERF.md, section 6); an operand past it, or one whose Cholesky broke down,
 #: takes the blocked TSQR
 KAPPA_MAX = 1e3
+#: ``.axis``: the mesh axis over which the Grams of the program this thread is
+#: tracing are summed, set by :func:`_grams_over` (none: a program of the whole
+#: operand).  It reaches :func:`_cholqr2` and :func:`_second_pass` without a
+#: parameter, so that the seams a planted fault replaces keep their signatures
+#: on both routes (``perf/tools/limits_probe_svd.py``)
+_GRAMS = threading.local()
+
+
+@contextlib.contextmanager
+def _grams_over(axis):
+    """The Grams traced inside the block summed over the mesh axis ``axis``
+    (None: left as they are)."""
+    was = getattr(_GRAMS, "axis", None)
+    _GRAMS.axis = axis
+    try:
+        yield
+    finally:
+        _GRAMS.axis = was
+
+
+def _summed(g):
+    """A Gram of the rows this program holds made the whole operand's: one
+    all-reduce of the (n, n) over the axis :func:`_grams_over` set, inside
+    the row-sharded program; the Gram itself in a program of the whole
+    operand."""
+    axis = getattr(_GRAMS, "axis", None)
+    return g if axis is None else jax.lax.psum(g, axis)
 
 
 def whole_on_each_device(a: DNDarray) -> bool:
@@ -183,6 +225,28 @@ def tall_route(shape, dtype) -> str:
     ):
         return "cholqr2"
     return "householder"
+
+
+def rows_route(shape, dtype, split, comm) -> bool:
+    """True where a tall operand split by rows over a 1-D mesh of several
+    devices takes the row-sharded CholeskyQR2 (``cholqr2_rows``): float32,
+    m >= n, every shard at least n rows and :data:`MIN_BYTES`, a process on
+    a TPU.  It is :func:`tall_route`'s ``cholqr2`` run on each shard, the
+    Grams summed over the chips, so a shard must be an operand that route
+    takes; a shard of fewer rows than columns has no n x n R of its own for
+    the fallback to stack.  Anything else keeps the TSQR."""
+    m, n = shape
+    rows = comm.shard_width(m)
+    return (
+        split == 0
+        and comm.mesh_ndim == 1
+        and comm.size > 1
+        and m >= n
+        and jnp.dtype(dtype) == jnp.float32
+        and rows >= n
+        and rows * n * 4 >= MIN_BYTES
+        and jax.default_backend() == "tpu"
+    )
 
 
 def _tall_dot(a, b, precision):
@@ -288,7 +352,7 @@ def _second_pass(a, r1, r1inv, precision):
     ``u·κ(A)²``; its own Gram has κ near 1, so R is the factor of a Q
     orthogonal to rounding.  That Q is never formed: ``A·R⁻¹``, made in one
     product, is orthonormal to about ``u·κ(A)`` (:data:`KAPPA_MAX`)."""
-    r2 = _cholesky_r(_gram(a, r1inv, precision))
+    r2 = _cholesky_r(_summed(_gram(a, r1inv, precision)))
     r = jnp.matmul(r2, r1, precision="highest")
     rinv = jnp.matmul(r1inv, _upper_inverse(r2), precision="highest")
     return r, rinv
@@ -299,8 +363,9 @@ def _cholqr2(a, precision):
     two blocked Gram passes over A, two (n, n) Cholesky factorizations.  Q is
     not built: a caller that needs it forms ``A·R⁻¹`` in one more pass.  A
     Gram whose Cholesky breaks down (κ(A)² near 1/u, a rank-deficient A)
-    leaves NaN in both."""
-    r1 = _cholesky_r(_gram(a, None, precision))
+    leaves NaN in both.  Inside the row-sharded program ``a`` is a shard and
+    each Gram is summed over the chips (:func:`_summed`)."""
+    r1 = _cholesky_r(_summed(_gram(a, None, precision)))
     return _second_pass(a, r1, _upper_inverse(r1), precision)
 
 
@@ -361,9 +426,30 @@ def _blocked_tsqr(a, keep_q: bool):
         stack = stack.at[full * n :].set(r)
         if keep_q:
             buf = buf.at[full * block :, :k].set(q)
+    return (*_positive_qr(stack, keep_q), buf)
+
+
+def _positive_qr(stack, keep_q: bool):
+    """``(R, Q)`` of a stack of R factors, R's diagonal made non-negative (Q
+    None where not ``keep_q``)."""
     qs, r = jnp.linalg.qr(stack) if keep_q else (None, jnp.linalg.qr(stack, mode="r"))
     sign = jnp.where(jnp.diagonal(r) < 0, -1.0, 1.0).astype(r.dtype)
-    return sign[:, None] * r, (None if qs is None else qs * sign), buf
+    return sign[:, None] * r, (None if qs is None else qs * sign)
+
+
+def _across(r, qs, axis):
+    """The blocked TSQR of a shard made the whole operand's: the shards' R
+    all-gathered over ``axis`` (one (n, n) a chip) and factored once more,
+    replicated, and ``Q_s`` times this shard's block of that Q.  ``(r, qs)``
+    as they are where ``axis`` is None."""
+    if axis is None:
+        return r, qs
+    n = r.shape[0]
+    r, q = _positive_qr(jax.lax.all_gather(r, axis, tiled=True), qs is not None)
+    if qs is None:
+        return r, None
+    mine = jax.lax.dynamic_slice_in_dim(q, jax.lax.axis_index(axis) * n, n, 0)
+    return r, jnp.matmul(qs, mine, precision="highest")
 
 
 def _tsqr_apply(buf, qs, right, precision):
@@ -385,35 +471,44 @@ def _tsqr_apply(buf, qs, right, precision):
     return buf
 
 
-def _cholqr2_qr(x, calc_q: bool):
+def _cholqr2_qr(x, calc_q: bool, axis=None):
     """``(Q or None, R)`` of the program on the ``cholqr2`` route: Q = A·R⁻¹
-    where :func:`_sound`, else the blocked TSQR."""
+    where :func:`_sound`, else the blocked TSQR.  With ``axis``, of the
+    shard ``x`` on ``cholqr2_rows``: the Grams summed over it, R
+    replicated, the fallback's R factored across the shards (:func:`_across`)."""
 
     def direct():
         return (_upper_product(x, rinv, TALL_PRECISION) if calc_q else None), r
 
     def fallback():
         r2, qs, buf = _blocked_tsqr(x, calc_q)
+        r2, qs = _across(r2, qs, axis)
         return (_tsqr_apply(buf, qs, None, TALL_PRECISION) if calc_q else None), r2
 
-    sound, r, rinv, _ = _sound(*_cholqr2(x, TALL_PRECISION), False)
+    with _grams_over(axis):
+        sound, r, rinv, _ = _sound(*_cholqr2(x, TALL_PRECISION), False)
     return jax.lax.cond(sound, direct, fallback)
 
 
-def _cholqr2_svd(x, compute_uv: bool):
+def _cholqr2_svd(x, compute_uv: bool, axis=None):
     """``(U, S, V)`` (or S) of the program on the ``cholqr2`` route: R's SVD
     ``R = U_R·S·Vᵀ`` on the device and ``U = A·W``, ``W = R⁻¹·U_R``, in ONE
     pass over A, where :func:`_sound`; else the blocked TSQR and
-    ``U = Q·U_R`` in its buffer."""
-    sound, r, rinv, (ur, s, v) = _sound(*_cholqr2(x, TALL_PRECISION), compute_uv)
+    ``U = Q·U_R`` in its buffer.  With ``axis``, of the shard ``x`` on
+    ``cholqr2_rows``, as :func:`_cholqr2_qr`: U keeps the shard's rows."""
+    with _grams_over(axis):
+        sound, r, rinv, (ur, s, v) = _sound(*_cholqr2(x, TALL_PRECISION), compute_uv)
     if not compute_uv:
-        return jax.lax.cond(sound, lambda: s, lambda: jnp.linalg.svd(_blocked_tsqr(x, False)[0], compute_uv=False))
+        return jax.lax.cond(
+            sound, lambda: s, lambda: jnp.linalg.svd(_across(_blocked_tsqr(x, False)[0], None, axis)[0], compute_uv=False)
+        )
 
     def direct():
         return _tall_dot(x, jnp.matmul(rinv, ur, precision="highest"), TALL_PRECISION), s, v
 
     def fallback():
         r2, qs, buf = _blocked_tsqr(x, True)
+        r2, qs = _across(r2, qs, axis)
         ur2, s2, v2 = _r_svd(r2)
         return _tsqr_apply(buf, qs, ur2, TALL_PRECISION), s2, v2
 
@@ -422,63 +517,102 @@ def _cholqr2_svd(x, compute_uv: bool):
 
 #: reads of A each route's program makes, by what it returns (``q``: Q or U
 #: is formed).  The Householder form copies A once into its working buffer
-#: and sweeps that copy, never A again.  ``cholqr2`` counts its sound branch:
-#: the blocked TSQR an operand that is not :func:`_sound` takes reads A once
-#: more, and its (m, n) buffer once where Q or U is formed
+#: and sweeps that copy, never A again.  ``cholqr2`` (and ``cholqr2_rows``,
+#: each chip its shard) counts its sound branch: the blocked TSQR an operand
+#: that is not :func:`_sound` takes reads A once more, and its (m, n) buffer
+#: once where Q or U is formed
 A_PASSES = {
     ("cholqr2", False): 2,
     ("cholqr2", True): 3,
+    ("cholqr2_rows", False): 2,
+    ("cholqr2_rows", True): 3,
     ("householder", False): 1,
     ("householder", True): 1,
 }
+#: the branch an operand that is not :func:`_sound` takes, by route
+FALLBACK = {"cholqr2": "blocked_tsqr", "cholqr2_rows": "rows_tsqr"}
 
 
 def _precision_name(route: str) -> str:
     """The precision of a route's tall products, as its launch span states it."""
-    if route == "cholqr2":
+    if route in FALLBACK:
         return TALL_PRECISION
     from .basics import get_matmul_precision
 
     return get_matmul_precision()
 
 
-def route_fields(route: str, formed: bool, n: int) -> dict:
-    """The launch span's fields of a one-device QR or SVD program of n columns:
-    ``route``, ``a_passes`` and ``precision``; on ``cholqr2`` also
-    ``fallback``, the branch an operand that is not :func:`_sound` takes, and
-    ``col_blocks``, the tiles of :data:`MXU_COLS` columns its structured tall
-    products are planned on (1: the dense products)."""
+def route_fields(route: str, formed: bool, n: int, shards: int = 1) -> dict:
+    """The launch span's fields of a tall QR or SVD program of n columns:
+    ``route``, ``a_passes`` and ``precision``; on ``cholqr2`` and
+    ``cholqr2_rows`` also ``fallback``, the branch an operand that is not
+    :func:`_sound` takes, and ``col_blocks``, the tiles of :data:`MXU_COLS`
+    columns its structured tall products are planned on (1: the dense
+    products); on ``cholqr2_rows`` also ``shards``, the devices the rows are
+    split over, and ``collective_bytes``, what a chip hands its collectives on
+    the sound branch: the two (n, n) float32 Grams it sums (the fallback
+    all-gathers one (n, n) R more)."""
     fields = {"route": route, "a_passes": A_PASSES[route, formed], "precision": _precision_name(route)}
-    if route == "cholqr2":
-        fields["fallback"] = "blocked_tsqr"
+    if route in FALLBACK:
+        fields["fallback"] = FALLBACK[route]
         fields["col_blocks"] = _tiles(n)
+    if route == "cholqr2_rows":
+        fields["shards"] = int(shards)
+        fields["collective_bytes"] = 2 * n * n * 4
     return fields
 
 
-def _one_device_qr(arr, comm, calc_q: bool):
-    """``(Q or None, R)`` of a tall operand held whole on each device, one
-    cached program launched as ``jitted:linalg.qr`` (:func:`route_fields`).
-    On ``cholqr2`` Q is written once, as ``A·R⁻¹``; ``calc_q=False`` makes
-    the two Gram passes alone.  That program holds an SVD of R, so it is
+def _program(route: str, comm, body, row_outputs):
+    """``body(x, axis)`` as the traceable program of ``route``: of the whole
+    operand (``axis`` None), or on ``cholqr2_rows`` of each row shard inside
+    ``shard_map`` over the comm's axis, the outputs ``row_outputs`` marks
+    True split by rows as A is, the others replicated."""
+    if route != "cholqr2_rows":
+        return lambda x: body(x, None)
+    rows = comm.spec(2, 0)
+    return shard_map(
+        lambda x: body(x, comm.axis_name),
+        mesh=comm.mesh,
+        in_specs=rows,
+        out_specs=jax.tree.map(lambda t: rows if t else PartitionSpec(), row_outputs),
+        check_vma=False,
+    )
+
+
+def run_tall(program, arr, comm, route: str):
+    """Run a tall factorization's cached ``program`` (:func:`_program`,
+    under the fields of :func:`route_fields`) on ``arr``.  On
+    ``cholqr2_rows`` the rows are first zero-padded to a whole number a shard
+    (``comm.pad_to_shards``); the caller cuts what it returns of A's height
+    back.  A program on the CholeskyQR2 routes holds an SVD of R, so it is
     lowered with x64 off, as ``svd``'s is."""
-    m, n = map(int, arr.shape)
-    route = tall_route((m, n), arr.dtype)
-    calc_q = bool(calc_q)
-
-    def make():
-        if route == "cholqr2":
-            return lambda x: _cholqr2_qr(x, calc_q)
-        if calc_q:
-            return lambda x: tuple(jnp.linalg.qr(x))
-        return lambda x: (None, jnp.linalg.qr(x, mode="r"))
-
-    fields = route_fields(route, calc_q, n)
-    key = ("linalg.qr", comm, (m, n), str(arr.dtype), route, calc_q, fields["precision"])
-    program = jitted(key, make, fields=fields)
-    if route == "householder":
+    if route not in FALLBACK:
         return program(arr)
+    if route == "cholqr2_rows":
+        arr = comm.pad_to_shards(arr, axis=0)
     with jax.enable_x64(False):
         return program(arr)
+
+
+def _tall_qr(arr, comm, calc_q: bool, route: str):
+    """``(Q or None, R)`` of a tall operand on ``route`` (``tall_route``'s, for
+    one held whole on each device, or ``cholqr2_rows``), one cached program
+    launched as ``jitted:linalg.qr``.  On the CholeskyQR2 routes Q is written
+    once, as ``A·R⁻¹``, split as A is; ``calc_q=False`` makes the two Gram
+    passes alone."""
+    m, n = map(int, arr.shape)
+    calc_q = bool(calc_q)
+
+    def body(x, axis):
+        if route in FALLBACK:
+            return _cholqr2_qr(x, calc_q, axis)
+        return tuple(jnp.linalg.qr(x)) if calc_q else (None, jnp.linalg.qr(x, mode="r"))
+
+    fields = route_fields(route, calc_q, n, comm.size)
+    key = ("linalg.qr", comm, (m, n), str(arr.dtype), route, calc_q, fields["precision"])
+    program = jitted(key, lambda: _program(route, comm, body, (calc_q or None, False)), fields=fields)
+    q, r = run_tall(program, arr, comm, route)
+    return (None if q is None else comm.unpad(q, m, 0)), r
 
 
 def _tsqr(a: DNDarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -1096,7 +1230,9 @@ def qr(
     )
 
     if whole_on_each_device(a) and a.shape[0] >= a.shape[1]:
-        q_g, r_g = _one_device_qr(arr, comm, calc_q)
+        q_g, r_g = _tall_qr(arr, comm, calc_q, tall_route(a.shape, arr.dtype))
+    elif rows_route(a.shape, arr.dtype, a.split, comm):
+        q_g, r_g = _tall_qr(arr, comm, calc_q, "cholqr2_rows")
     elif a.split == 0 and a.shape[0] >= a.shape[1]:
         q_g, r_g = _tsqr(aa)
     elif a.split == 1 and a.shape[0] >= a.shape[1] and a.comm.size > 1:

@@ -5,28 +5,34 @@ does not exist in HeAT 0.5.1.  Implemented here because the rebuild's
 baseline configs exercise it (BASELINE.md target 5: "linalg.qr + SVD on
 tall-skinny split DNDarray").
 
-Algorithm: always reduce via QR first (TSQR when row-split — see qr.py),
-then factor the small triangular R **on device** — the standard
-communication-avoiding SVD.  Only the tiny (n, n) R ever reaches the SVD
-kernel, so the MXU carries all the real work (QR + the Q·Ur matmul) and
-the decomposition adds zero host syncs.  float64 operands are the one
-exception: the TPU has no f64 hardware, so their R factors on the host
-through LAPACK (one tiny transfer) and the chain runs eagerly.  Wide
-matrices factor transposed and swap U/V.
+Algorithm: reduce via QR first, then factor the small triangular R **on
+device** — the standard communication-avoiding SVD.  Only the (n, n) R ever
+reaches the SVD kernel, so the MXU carries the real work (the factor and
+U's product) and the decomposition adds zero host syncs.  float64 operands
+are the one exception: the TPU has no f64 hardware, so their R factors on
+the host through LAPACK (one tiny transfer) and the chain runs eagerly.
+Wide matrices factor transposed and swap U/V.
 
-An operand held whole on a device (one device, or replicated) that
-``qr.tall_route`` sends on the ``cholqr2`` route takes one cached program,
-:func:`_one_device_svd` (``jitted:linalg.svd``): R comes from two blocked
-Gram passes, its SVD ``R = U_R·S·Vᵀ`` is taken on the device, and U is
-``A·W`` with ``W = R⁻¹·U_R`` (n x n), in ONE more pass over A: **Q is never
-built**.  Q and U are each as large as A; at 6 291 456 x 300 float32 A, Q,
-U and the Householder QR's working copy of A need about 30 GB, A and U
-alone 15.3 GB, which one 16 GB chip holds.  Forming U from A also reads A
-once where ``Q·U_R`` would read Q once, after Q was written.  U made so is
+A float32 operand on a TPU takes one cached program,
+:func:`_tall_svd` (``jitted:linalg.svd``), on one of two routes: held whole
+on a device (one device, or replicated) that ``qr.tall_route`` sends on the
+``cholqr2`` route, or split by rows over a 1-D mesh of several devices with
+every shard at least n rows and ``qr.MIN_BYTES`` (``qr.rows_route``,
+``cholqr2_rows``: the same program on each shard inside ``shard_map``, the
+two Grams summed over the chips by one all-reduce of their (n, n) each, the
+n x n work replicated, U made shard by shard and split as A is).  R comes
+from two blocked Gram passes, its SVD ``R = U_R·S·Vᵀ`` is taken on the
+device, and U is ``A·W`` with ``W = R⁻¹·U_R`` (n x n), in ONE more pass over
+A: **Q is never built**.  Q and U are each as large as A; at 6 291 456 x 300
+float32 A on one chip (or 6 291 456 x 1 200 over four), Q, U and the
+Householder QR's working copy of A need about 30 GB a chip, A and U alone
+15.3 GB, which one 16 GB chip holds.  Forming U from A also reads A once
+where ``Q·U_R`` would read Q once, after Q was written.  U made so is
 orthonormal to about u·κ(A); the program takes it only where R is finite
-and κ(R) is at most ``qr.KAPPA_MAX``, and else factors by the blocked TSQR,
-its blocks' Q written into U's own buffer (``qr._cholqr2_svd``).  Every
-other operand takes the fused chain below.
+and κ(R) is at most ``qr.KAPPA_MAX``, and else factors by the blocked TSQR
+(of each shard, the shards' R factored once more), its blocks' Q written
+into U's own buffer (``qr._cholqr2_svd``).  Every other operand takes the
+fused chain below: the TSQR of ``qr.py`` where row-split, then ``Q·U_R``.
 
 The on-device chain is traced, lowered and compiled with x64 **off**
 (:func:`svd` enters ``jax.enable_x64(False)`` around the fused program):
@@ -176,30 +182,39 @@ def _svd_pipeline(a: DNDarray, osplit, dtype, compute_uv: bool):
 _fused_svd_pipeline = fuse(_svd_pipeline)
 
 
-def _one_device_svd(a: DNDarray, dtype, compute_uv: bool):
-    """The SVD of a tall operand held whole on each device on the ``cholqr2``
-    route (``qr.tall_route``): one cached program launched as
-    ``jitted:linalg.svd`` (``qr._cholqr2_svd``), with the fields of
-    ``qr.route_fields`` and, where U is formed, ``u: direct``.
+def _tall_svd(a: DNDarray, dtype, compute_uv: bool, route: str):
+    """The SVD of a tall operand on a CholeskyQR2 route: ``cholqr2``
+    (``qr.tall_route``, held whole on each device) or ``cholqr2_rows``
+    (``qr.rows_route``, split by rows over several devices), one cached
+    program launched as ``jitted:linalg.svd`` (``qr._cholqr2_svd``), with the
+    fields of ``qr.route_fields`` and, where U is formed, ``u: direct``.
 
-    R from two blocked Gram passes, its SVD ``R = U_R·S·Vᵀ`` on the device,
-    and ``U = A·W`` with ``W = R⁻¹·U_R`` (n x n) in ONE more pass: Q is never
-    built, so A and U are the only arrays of A's size.  An operand whose
-    factor is not sound takes the blocked TSQR inside the same program."""
+    R from two blocked Gram passes (on ``cholqr2_rows`` each shard's, summed
+    over the chips), its SVD ``R = U_R·S·Vᵀ`` on the device, and ``U = A·W``
+    with ``W = R⁻¹·U_R`` (n x n) in ONE more pass, shard by shard: Q is never
+    built, so A and U are the only arrays of A's size on a chip, and U is
+    split as A is.  An operand whose factor is not sound takes the blocked
+    TSQR inside the same program."""
     comm, device = a.comm, a.device
     m, n = a.shape
     arr = a.larray
     compute_uv = bool(compute_uv)
-    fields = _qr_mod.route_fields("cholqr2", compute_uv, n)
+    fields = _qr_mod.route_fields(route, compute_uv, n, comm.size)
     if compute_uv:
         fields["u"] = "direct"
-    key = ("linalg.svd", comm, (m, n), str(arr.dtype), "cholqr2", compute_uv, fields["precision"])
-    out = _jitted(key, lambda: lambda x: _qr_mod._cholqr2_svd(x, compute_uv), fields=fields)(arr)
+    key = ("linalg.svd", comm, (m, n), str(arr.dtype), route, compute_uv, fields["precision"])
+
+    def body(x, axis):
+        return _qr_mod._cholqr2_svd(x, compute_uv, axis)
+
+    outputs = (True, False, False) if compute_uv else False  # which are split by rows as A
+    program = _jitted(key, lambda: _qr_mod._program(route, comm, body, outputs), fields=fields)
+    out = _qr_mod.run_tall(program, arr, comm, route)
     if not compute_uv:
         return DNDarray(out, (n,), dtype, None, device, comm, True)
     u, s, v = out
     u_split = a.split if a.split == 0 else None
-    U = DNDarray(u, (m, n), dtype, u_split, device, comm, True)
+    U = DNDarray(comm.unpad(u, m, 0), (m, n), dtype, u_split, device, comm, True)
     S = DNDarray(s, (n,), dtype, None, device, comm, True)
     V = DNDarray(v, (n, n), dtype, None, device, comm, True)
     return SVD(U, S, V)
@@ -689,5 +704,7 @@ def svd(a: DNDarray, full_matrices: bool = False, compute_uv: bool = True):
         a = a.astype(dtype)  # an integer operand must not meet the context below
     with _jax.enable_x64(False):  # see module docstring: the LOWERING must be x64-off
         if _qr_mod.whole_on_each_device(a) and _qr_mod.tall_route(a.shape, a.larray.dtype) == "cholqr2":
-            return _one_device_svd(a, dtype, compute_uv)
+            return _tall_svd(a, dtype, compute_uv, "cholqr2")
+        if _qr_mod.rows_route(a.shape, a.larray.dtype, a.split, comm):
+            return _tall_svd(a, dtype, compute_uv, "cholqr2_rows")
         return _fused_svd_pipeline(a, a.split, dtype, compute_uv)

@@ -122,6 +122,20 @@ def test_qr_svd_phase_says_which_route_its_programs_took(monkeypatch):
     }
 
 
+def test_qr_svd_phase_over_the_mesh_checks_the_row_sharded_route(monkeypatch):
+    """Over several devices, steered onto the chip's route: the phase's
+    operand is split by rows over the mesh and takes ``cholqr2_rows``, and
+    its line checks that it did."""
+    qr_mod = importlib.import_module("heat_tpu.core.linalg.qr")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(qr_mod, "MIN_BYTES", 0)
+    line, _ = chip_smoke.phase_qr_svd(SEED, m=4096, n=64)
+    _complete(line)
+    assert _failed(line) == []
+    assert line["checks"]["sharded_route"]["value"] == 1
+    assert {v["route"] for v in line["routes"].values()} == {"cholqr2_rows"}
+
+
 def test_spectral_phase_says_which_matvec_route_its_fit_took():
     """Off the chip, and under the kernel's threshold: the dense product, and
     the line says both."""

@@ -330,6 +330,85 @@ def test_the_gram_passes_skip_the_zero_and_mirrored_tiles_at_the_cells_size(one_
 
 
 # --------------------------------------------------------------------- #
+# svd_1200_c4: the SVD of 6 291 456 x 1 200 split by rows over four chips #
+# --------------------------------------------------------------------- #
+ROWS_M, ROWS_N = 6_291_456, 1200  # 1 200 flattened Cityscapes images, 1 572 864 rows a chip
+
+
+@pytest.fixture(scope="module")
+def rows_svd(four_chips):
+    """``ht.linalg.svd``'s cached program on the route ``cholqr2_rows``,
+    compiled at the cell's size for the described 2x2, and the fields its
+    launch span states there.  The program is made by tracing the public
+    entry over a described operand (no array can lie on a described device),
+    and compiled once for the tests below (four to five minutes on the CPU:
+    R's 1 200 x 1 200 SVD and the fallback's block QRs)."""
+    from heat_tpu.core import _compile
+    from heat_tpu.core.dndarray import DNDarray
+
+    qr_mod = importlib.import_module("heat_tpu.core.linalg.qr")
+    comm, rows = four_chips, four_chips.sharding(2, 0)
+
+    def call(a):
+        u, s, v = ht.linalg.svd(DNDarray(a, tuple(a.shape), ht.float32, 0, ht.get_device(), comm, True))
+        return u.larray, s.larray, v.larray
+
+    with pytest.MonkeyPatch.context() as chip:
+        chip.setattr(jax, "default_backend", lambda: "tpu")
+        assert qr_mod.rows_route((ROWS_M, ROWS_N), jnp.float32, 0, comm)
+        chip.setattr(qr_mod, "MIN_BYTES", 0)
+        with jax.enable_x64(False):
+            jax.eval_shape(call, _shape((4 * 64, 8), rows))
+            # the key: (site, comm, shape, dtype, route, compute_uv, precision)
+            entry = next(fn for k, fn in _compile._CACHE.items() if k[0] == "linalg.svd" and k[1] == comm and k[4] == "cholqr2_rows")
+            compiled = entry.lower(_shape((ROWS_M, ROWS_N), rows)).compile()
+    return compiled, qr_mod.route_fields("cholqr2_rows", True, ROWS_N, comm.size)
+
+
+def test_the_row_sharded_svd_holds_a_and_u_and_fits_the_chip(rows_svd):
+    """A chip holds its 1 572 864 rows of A and of U and the program's
+    temporaries (the fallback's block QRs of 65 536 x 1 200 and their stack,
+    1.1 GB; the sound branch's Gram passes 0.3 GB) and nothing of A's size
+    beside them: 16.25 GB of the 16.9 GB a v5e gives its programs
+    (``memory_stats()["bytes_limit"]``), which leaves the harness the room it
+    needs between the jobs (nothing: the last job's U is released first)."""
+    compiled, _ = rows_svd
+    m = compiled.memory_analysis()
+    shard = 4 * (ROWS_M // 4) * ROWS_N
+    assert shard <= m.argument_size_in_bytes < 1.01 * shard
+    assert shard <= m.output_size_in_bytes < 1.01 * shard  # U's rows, S and V
+    assert m.temp_size_in_bytes < 1.25e9, m
+    total = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
+    assert total < 16.5e9, m
+
+
+def test_the_row_sharded_svd_moves_the_grams_alone_and_reads_a_as_its_field_says(rows_svd):
+    """The collectives: on the sound branch the two all-reduces of the
+    1 200 x 1 200 Grams, ``collective_bytes`` (11.52 MB a chip); on the
+    fallback one all-gather of each chip's 1 200 x 1 200 R more.  No
+    collective moves a shard of A or U.  Reads of A: the two Gram passes and
+    U's on the sound branch, as ``a_passes`` says; the fallback reads A once
+    more and U's buffer once."""
+    compiled, fields = rows_svd
+    text = compiled.as_text()
+    n2 = ROWS_N * ROWS_N
+    assert fields["collective_bytes"] == 2 * 4 * n2 and fields["shards"] == 4
+    assert max(elements for _, elements in _collectives(text)) <= 4 * n2  # the gathered R stack, 4 800 x 1 200
+    moved = {"sound": 0, "fallback": 0}
+    for line in text.splitlines():
+        found = re.search(r"= (\w+)\[([\d,]*)\]\S* " + _COLLECTIVE + r"(?:-start)?\(", line)
+        if not found:
+            continue
+        out = 4 * math.prod(int(d) for d in found.group(2).split(",") if d)
+        group = len(re.search(r"replica_groups=\{\{([\d,]*)\}", line).group(1).split(","))
+        operand = out // group if found.group(3) == "all-gather" else out
+        moved["fallback" if "shard_map/cond/" in line else "sound"] += operand
+    assert moved == {"sound": fields["collective_bytes"], "fallback": 4 * n2}
+    sound, fallback = sorted(_reads_of_a(compiled, f"f32[{ROWS_M // 4},{ROWS_N}]"))
+    assert sound == fields["a_passes"] == 3 and fallback == 4
+
+
+# --------------------------------------------------------------------- #
 # the benchmark cells' programs, and the names their phases carry        #
 # --------------------------------------------------------------------- #
 CELL_F, CELL_K = 6_291_456, 8  # one flattened Cityscapes image a row, 8 clusters
